@@ -26,7 +26,9 @@ index on the same dataset across a beam sweep, in two workload regimes:
     0.223 baseline) at iso-recall.
 
 Byte gates (both regimes share the index): ``nbytes_by_component`` sums
-exact, packed labels exactly 8 B/edge slot, int8 resident rows exactly 4x
+exact, the device label table exactly one packed label row per node
+(``repro.kernels.layout.label_row_bytes``: 8 B/edge of payload in
+10.67 B/edge slot at E = 96), int8 resident rows exactly 4x
 smaller than the f32 copies, and segmented resident bytes within
 ``BYTES_FACTOR`` x the monolithic f32 index (the factor buys the uniform
 per-segment node padding that keeps every segment on ONE compiled
@@ -70,6 +72,7 @@ from repro.scale import (
     merge_fold_cache_size,
     worklist_capacity,
 )
+from repro.kernels.layout import label_row_bytes
 from repro.search import export_device_graph
 
 JSON_PATH = Path(__file__).resolve().parent.parent / "BENCH_scale.json"
@@ -267,10 +270,10 @@ def main(tiny: bool = False, huge: bool = False) -> None:
     mono_comp = dg.nbytes_by_component()
     sums_exact = (sum(seg_comp.values()) == seg.nbytes()
                   and sum(mono_comp.values()) == dg.nbytes())
-    packed_8b = all(
+    packed_rows = all(
         sg.dg.plabels is not None
-        and sg.dg.plabels.nbytes
-        == seg.node_capacity * seg.edge_capacity * 8
+        and sg.dg.device().labels.nbytes
+        == seg.node_capacity * label_row_bytes(seg.edge_capacity)
         for sg in seg.segments
     )
     int8_4x = seg_comp["vec_q"] * 4 == seg_comp["vectors"]
@@ -303,7 +306,7 @@ def main(tiny: bool = False, huge: bool = False) -> None:
             "segmented_resident": int(seg_resident),
             "monolithic_resident": int(mono_resident),
             "sums_exact": bool(sums_exact),
-            "packed_label_8B_per_edge": bool(packed_8b),
+            "packed_label_rows_per_node": bool(packed_rows),
             "int8_vec_4x_smaller": bool(int8_4x),
         },
     }
@@ -333,7 +336,7 @@ def main(tiny: bool = False, huge: bool = False) -> None:
     assert no_recompile, "segment-mix change recompiled a program"
     assert valid_ok, "segmented search returned a predicate-invalid id"
     assert sums_exact, "nbytes_by_component does not sum to nbytes()"
-    assert packed_8b, "packed labels are not 8 bytes per edge slot"
+    assert packed_rows, "device labels are not one packed row per node"
     assert int8_4x, "int8 resident rows are not 4x smaller than f32"
     assert seg_resident <= BYTES_FACTOR * mono_resident, (
         f"segmented resident bytes {seg_resident} exceed "

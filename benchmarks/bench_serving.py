@@ -67,6 +67,7 @@ for shards in (2, 4, 8):
 def _scaling_subprocess() -> None:
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
         PYTHONPATH=os.path.join(REPO, "src"),
     )

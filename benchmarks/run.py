@@ -49,6 +49,9 @@ ALL = [
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = os.environ.get("REPRO_BENCH_ONLY", "")
     print("name,us_per_call,derived")
     failures = 0

@@ -173,7 +173,7 @@ def build_udg(
     batched: bool | None = None,
     wave: int = 256,
     pad_nodes: int | None = None,
-    use_ref: bool = True,
+    use_ref: bool | None = None,
 ) -> Tuple[LabeledGraph, BuildReport]:
     """Practical UDG constructor (paper §V-A + §V-B).
 

@@ -105,7 +105,7 @@ class _WaveBuildState:
         patch: str = "full",
         wave: int = 256,
         pad_nodes: int | None = None,
-        use_ref: bool = True,
+        use_ref: bool | None = None,
     ):
         # Deferred so `repro.core` stays importable (and the sequential
         # path usable) without jax — the device stack is only pulled in
@@ -121,7 +121,7 @@ class _WaveBuildState:
         self.K_p = int(K_p)
         self.leap = leap
         self.patch = patch
-        self.use_ref = bool(use_ref)
+        self.use_ref = use_ref
 
         g = LabeledGraph(vectors, s, t, relation)
         self.g = g
@@ -312,16 +312,17 @@ def build_udg_batched(
     patch: str = "full",
     wave: int = 256,
     pad_nodes: int | None = None,
-    use_ref: bool = True,
+    use_ref: bool | None = None,
 ) -> Tuple[LabeledGraph, "BuildReport"]:
     """Wave-pipelined practical constructor; same contract as ``build_udg``.
 
     ``wave`` is the insertion-wave width (1 degenerates to per-object device
     searches). ``pad_nodes`` pads the device table to a fixed row count —
     pass the streaming tier's ``node_capacity`` so every epoch rebuild hits
-    the same compiled wave search. ``use_ref`` selects the jnp oracle for
-    the in-wave search (the right choice on CPU; on TPU pass False for the
-    gather-fused Pallas kernel). Wall-clock in the returned ``BuildReport``
+    the same compiled wave search. ``use_ref`` selects the in-wave search's
+    kernels (``None``: the backend's choice, ``repro.kernels.ops.
+    use_reference`` — the Pallas kernels on TPU, the jnp oracle on CPU).
+    Wall-clock in the returned ``BuildReport``
     is one perf_counter window around the whole pipeline (device searches,
     host sweeps, patching — no per-insert accumulation), ``waves`` counts
     insertion waves, and ``broad_searches`` counts *device search launches*,
@@ -349,7 +350,7 @@ def build_graphs_concurrent(
     patch: str = "full",
     wave: int = 256,
     pad_nodes: int | None = None,
-    use_ref: bool = True,
+    use_ref: bool | None = None,
 ) -> List[Tuple[LabeledGraph, "BuildReport"]]:
     """Build several UDGs concurrently through one wave pipeline.
 

@@ -1,28 +1,17 @@
-"""Version shims for JAX APIs that moved between releases."""
+"""The two mesh APIs the repo calls, in the form JAX 0.9 offers them."""
 from __future__ import annotations
 
 import jax
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (>= 0.6, ``check_vma``) vs
-    ``jax.experimental.shard_map`` (older, ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with the varying-manual-axes check off (the
+    serving steps mix per-shard and replicated values freely)."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def abstract_mesh(shape: dict):
-    """``AbstractMesh`` across the signature split: 0.4/0.5 take a tuple of
-    (name, size) pairs; newer JAX takes (axis_sizes, axis_names)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape.items()))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(shape.values()), tuple(shape.keys())
-        )
+    """``AbstractMesh`` from an ordered ``{axis_name: size}`` dict."""
+    return jax.sharding.AbstractMesh(tuple(shape.values()), tuple(shape.keys()))

@@ -22,15 +22,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.kernels.layout import gather_vectors
 
 _INF = jnp.inf
 
 
-def effective_norms(vectors, scales=None, norms=None):
-    """Cached ‖row‖² of the rows the kernel scores (dequantized if int8)."""
+def effective_norms(vectors, dim: int, scales=None, norms=None):
+    """Cached ‖row‖² of the rows the kernel scores (dequantized if int8);
+    ``vectors`` is a logical ``[n, dim]`` table or its kernel rows."""
     if norms is not None:
         return norms.astype(jnp.float32)
-    v32 = vectors.astype(jnp.float32)
+    v32 = gather_vectors(vectors, slice(None), dim)
     out = jnp.sum(v32 * v32, axis=1)
     if scales is not None:
         out = out * scales * scales
@@ -38,13 +40,13 @@ def effective_norms(vectors, scales=None, norms=None):
 
 
 def brute_topk_impl(
-    table: jnp.ndarray,     # [n, D] f32 (or int8 with scales)
+    table: jnp.ndarray,     # [n, D] f32 (or int8 with scales), or its rows
     norms: jnp.ndarray,     # [n] f32 cached ‖row‖²
     q: jnp.ndarray,         # [B, D]
     bf_ids: jnp.ndarray,    # [B, V] int32 valid ids (-1 padded)
     *,
     k: int,
-    use_ref: bool,
+    use_ref: bool | None,
     scales: jnp.ndarray | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Traceable core: gather-scan the id lists, return ascending top-k.

@@ -55,10 +55,10 @@ PLANS = ("auto", "graph", "wide", "brute")
     ),
 )
 def planned_exec_core(
-    vectors: jnp.ndarray,    # [n, D] f32 (or int8 with scales)
+    vectors: jnp.ndarray,    # [n, D] f32 (or int8 with scales), or its rows
     nbr: jnp.ndarray,        # [n, E] int32
-    labels: jnp.ndarray,     # [n, E, 2] uint32 packed or [n, E, 4] int32 —
-                             # both graph strategies dispatch on the layout
+    labels: jnp.ndarray,     # packed words / label rows or [n, E, 4] int32
+                             # — both graph strategies dispatch on the layout
     q: jnp.ndarray,          # [B, D]
     states: jnp.ndarray,     # [B, 2] int32
     ep_graph: jnp.ndarray,   # [B] int32 entry ids, -1 unless plan==GRAPH
@@ -71,7 +71,7 @@ def planned_exec_core(
     wide_beam: int,
     max_iters: int,
     wide_max_iters: int,
-    use_ref: bool,
+    use_ref: bool | None,
     fused: bool = True,
     expand: int = 1,
     wide_expand: int = 1,
@@ -103,7 +103,7 @@ def _planned_exec_impl(
     wide_beam: int,
     max_iters: int,
     wide_max_iters: int,
-    use_ref: bool,
+    use_ref: bool | None,
     fused: bool,
     expand: int,
     wide_expand: int,
@@ -128,7 +128,7 @@ def _planned_exec_impl(
     )
     ids_g, d_g = out_g[0], out_g[1]
     ids_w, d_w = out_w[0], out_w[1]
-    nrm = effective_norms(vectors, scales, norms)
+    nrm = effective_norms(vectors, q.shape[1], scales, norms)
     ids_b, d_b = brute_topk_impl(
         vectors, nrm, q.astype(jnp.float32), bf_ids,
         k=k, use_ref=use_ref, scales=scales,
@@ -167,7 +167,7 @@ def worklist_exec_core(
                              # base (repro.search.device_graph.SegmentStack),
                              # so traversal is segment-closed with no per-row
                              # arithmetic in the search loop
-    labels: jnp.ndarray,     # [S*node_cap, E, 2|4] segment-local rectangles
+    labels: jnp.ndarray,     # [S*node_cap, ...] segment-local label layout
     gid_table: jnp.ndarray,  # [S*node_cap] int32 flat node -> global object
                              # id (-1 on capacity padding rows)
     q: jnp.ndarray,          # [B, D] the ORIGINAL query batch
@@ -185,7 +185,7 @@ def worklist_exec_core(
     wide_beam: int,
     max_iters: int,
     wide_max_iters: int,
-    use_ref: bool,
+    use_ref: bool | None,
     fused: bool = True,
     expand: int = 1,
     wide_expand: int = 1,

@@ -23,10 +23,12 @@ This module replaces both with one primitive, ``beam_merge``:
        ``(distance-key, index)`` then a single bitonic *merge network* with
        the already-sorted beam — ``O(ME·log²(ME) + (L+ME)·log(L+ME))``
        compare-exchange stages, all vectorized, no data-dependent control
-       flow. Distances are compared via an order-isomorphic uint32 key
+       flow. Distances are compared via an order-isomorphic int32 key
        (sign-fixed float bits) with the concat index as tie-break, so the
        network's output is the unique total order that the stable sort
-       produces.
+       produces. The kernel puts 128 queries on the lanes and sequence
+       positions on the sublanes, so every stage is whole-vreg rolls and
+       selects (no per-query reshapes, reversals or zero-size arrays).
 
 ``ref.beam_merge_ref`` keeps the stable-``lax.sort`` formulation as the
 semantic oracle; ``tests/test_kernels.py`` pins both implementations to it
@@ -49,9 +51,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _INF = jnp.inf
-_U32_MAX = np.uint32(0xFFFFFFFF)
 _I32_MAX = np.int32(np.iinfo(np.int32).max)
 
 
@@ -61,16 +63,6 @@ def next_pow2(x: int) -> int:
     while p < x:
         p *= 2
     return p
-
-
-def mono_key_u32(d: jnp.ndarray) -> jnp.ndarray:
-    """Order-isomorphic uint32 key for f32: a < b (IEEE, no NaN) iff
-    key(a) < key(b). ``-0.0`` is normalized to ``+0.0`` first so exact
-    float equality and key equality coincide."""
-    d = d + 0.0  # -0.0 -> +0.0
-    bits = jax.lax.bitcast_convert_type(d.astype(jnp.float32), jnp.uint32)
-    neg = bits >> 31 == jnp.uint32(1)
-    return jnp.where(neg, ~bits, bits | jnp.uint32(0x80000000))
 
 
 def dedup_mask(cand_d: jnp.ndarray, cand_ids: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -127,67 +119,69 @@ def beam_merge_jnp(
 
 
 # --- Pallas bitonic kernel ------------------------------------------------------
+#
+# Lanes are queries: every sequence is a ``[P, 128]`` array whose rows are
+# sequence positions and whose lanes are 128 queries of the batch, so each
+# compare-exchange stage is a few whole-vreg rolls and selects along the
+# sublane axis, the same for every query.
 
 
-def _ce_stage(arrs, j: int, k: int | None):
-    """One compare-exchange stage at stride ``j`` over the last axis.
+def _sort_key(d: jnp.ndarray) -> jnp.ndarray:
+    """Order-isomorphic int32 key for f32 (no NaN): a < b iff key(a) <
+    key(b). ``-0.0`` is normalized to ``+0.0`` first so exact float
+    equality and key equality coincide."""
+    bits = jax.lax.bitcast_convert_type(d + 0.0, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
-    ``arrs = (mk, ix, *values)``: uint32 primary key, int32 tie-break, and
-    any number of carried value arrays, all ``[P]``-shaped (P a power of
-    two, a multiple of 2j). ``k`` is the enclosing bitonic block size —
-    pair blocks whose base index has bit ``k`` clear sort ascending, the
-    rest descending; ``k=None`` means all-ascending (the merge pass). The
-    direction flags are derived from an in-kernel iota, never a captured
-    constant (Pallas kernels must close over no array consts). Keys are
-    unique (ix is a permutation), so the network output is the one total
-    order.
+
+def _ce_stage(arrs, j: int, asc):
+    """One compare-exchange stage at stride ``j`` along axis 0.
+
+    ``arrs = (key, ix, *values)``, all ``[P, lanes]``: int32 primary key,
+    int32 tie-break (unique per lane, so the order is total) and carried
+    values. Position ``i`` pairs with ``i ^ j``; the pair sorts ascending
+    where ``asc`` (bool, broadcastable) is true, else descending. Each
+    position fetches its partner with two sublane rolls and keeps either
+    itself or the partner — both ends of a pair make complementary
+    choices, so the stage is a permutation.
     """
-    mk, ix = arrs[0], arrs[1]
-    P = mk.shape[-1]
-    G = P // (2 * j)
+    key, ix = arrs[0], arrs[1]
+    P = key.shape[0]
+    lo = (jax.lax.broadcasted_iota(jnp.int32, key.shape, 0) & j) == 0
 
-    def split(x):
-        x2 = x.reshape(G, 2, j)
-        return x2[:, 0, :], x2[:, 1, :]
+    def partner(x):
+        # roll by P - j brings x[i + j] to i; roll by j brings x[i - j]
+        return jnp.where(lo, pltpu.roll(x, P - j, 0), pltpu.roll(x, j, 0))
 
-    a_m, b_m = split(mk)
-    a_i, b_i = split(ix)
-    b_less = (b_m < a_m) | ((b_m == a_m) & (b_i < a_i))
-    if k is None:
-        swap = b_less
-    else:
-        base = jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0) * (2 * j)
-        asc = (base & k) == 0
-        swap = jnp.where(asc, b_less, ~b_less)
-
-    def exchange(x):
-        a, b = split(x)
-        na = jnp.where(swap, b, a)
-        nb = jnp.where(swap, a, b)
-        return jnp.stack([na, nb], axis=1).reshape(P)
-
-    return tuple(exchange(x) for x in arrs)
+    pk, pi = partner(key), partner(ix)
+    p_less = (pk < key) | ((pk == key) & (pi < ix))
+    take = lo ^ p_less ^ asc      # lo takes the smaller when ascending
+    return (jnp.where(take, pk, key), jnp.where(take, pi, ix)) + tuple(
+        jnp.where(take, partner(x), x) for x in arrs[2:])
 
 
-def _bitonic_sort(arrs):
-    """Ascending bitonic sort of ``arrs = (mk, ix, *values)`` by (mk, ix)."""
-    P = arrs[0].shape[-1]
+def _bitonic_sort_desc(arrs):
+    """Descending bitonic sort of ``arrs = (key, ix, *values)`` along
+    axis 0 by (key, ix)."""
+    P = arrs[0].shape[0]
+    i = jax.lax.broadcasted_iota(jnp.int32, arrs[0].shape, 0)
     k = 2
     while k <= P:
+        asc = (i & k) != 0 if k < P else False
         j = k // 2
         while j >= 1:
-            arrs = _ce_stage(arrs, j, k if k < P else None)
+            arrs = _ce_stage(arrs, j, asc)
             j //= 2
         k *= 2
     return arrs
 
 
 def _bitonic_merge(arrs):
-    """Merge one bitonic sequence (e.g. [asc | desc]) into ascending order."""
-    P = arrs[0].shape[-1]
-    j = P // 2
+    """Merge one bitonic sequence (ascending, then descending) of
+    power-of-two length into ascending order along axis 0."""
+    j = arrs[0].shape[0] // 2
     while j >= 1:
-        arrs = _ce_stage(arrs, j, None)
+        arrs = _ce_stage(arrs, j, True)
         j //= 2
     return arrs
 
@@ -195,59 +189,59 @@ def _bitonic_merge(arrs):
 def _beam_merge_kernel(
     bd_ref, bi_ref, be_ref, cd_ref, ci_ref,
     oi_ref, od_ref, oe_ref, ok_ref,
-    *, n: int, L: int, C: int, Pc: int, Pm: int,
+    key_scr,
+    *, n: int, L: int, C: int,
 ):
-    """One query row per grid step: dedup, candidate bitonic sort, merge
-    network with the (already ascending) beam, emit the best L.
+    """128 queries per grid step: dedup, candidate bitonic sort
+    (descending), one merge network with the ascending beam, emit the best
+    L. Inputs arrive padded: the beam to ``Pb`` rows, candidates to ``Pc``
+    rows (``Pb + Pc`` a power of two, ``Pb >= L``)."""
+    cd = cd_ref[...]                               # [Pc, lanes] f32
+    ci = ci_ref[...]                               # [Pc, lanes] int32
+    rc = jax.lax.broadcasted_iota(jnp.int32, cd.shape, 0)
+    # keep-first duplicate suppression: the rule of dedup_mask, one
+    # candidate row at a time against every later row
+    fin = jnp.abs(cd) < _INF
+    id_key = jnp.where(fin, ci, jnp.int32(n))
+    key_scr[...] = id_key
 
-    Everything is carried through the network as flat ``[P]`` vectors; the
-    compare-exchange reshapes are static. (A production TPU layout would
-    tile a batch of rows onto the lane dimension and run the network on the
-    sublane axis; kept row-per-step here for clarity — the stage structure
-    is identical.)
-    """
-    cd = cd_ref[0, :]                              # [C] f32
-    ci = ci_ref[0, :]                              # [C] int32
-    # keep-first duplicate suppression — the same helper the jnp path and
-    # the ref oracle use (one definition of the dedup rule)
-    dup = dedup_mask(cd.reshape(1, C), ci.reshape(1, C), n)[0]
-    d_dd = jnp.where(dup, _INF, cd)
-    keep = jnp.isfinite(d_dd)
-    ok_ref[0, :] = keep.astype(jnp.int32)
+    def dedup(j, dup):
+        same = (id_key == key_scr[pl.ds(j, 1), :]) & (rc > j)
+        return dup | same.astype(jnp.int32)
+    dup = jax.lax.fori_loop(0, C, dedup, jnp.zeros(cd.shape, jnp.int32))
+    d_dd = jnp.where((dup > 0) & fin, _INF, cd)
+    keep = jnp.abs(d_dd) < _INF
+    ok_ref[...] = keep.astype(jnp.int32)
 
-    pad_c = Pc - C
-    mono = mono_key_u32(d_dd)
-    mk_c = jnp.concatenate([mono, jnp.full((pad_c,), _U32_MAX, jnp.uint32)])
-    ix_c = jnp.concatenate([
-        jnp.arange(C, dtype=jnp.int32) + L,
-        jnp.full((pad_c,), _I32_MAX, jnp.int32),
-    ])
-    vd_c = jnp.concatenate([d_dd, jnp.full((pad_c,), _INF, jnp.float32)])
-    vi_c = jnp.concatenate([ci, jnp.full((pad_c,), -1, jnp.int32)])
-    ve_c = jnp.concatenate([
-        (~keep).astype(jnp.int32), jnp.ones((pad_c,), jnp.int32)])
-    mk_c, ix_c, vd_c, vi_c, ve_c = _bitonic_sort((mk_c, ix_c, vd_c, vi_c, ve_c))
+    # candidates: rows >= C are padding, ordered after every real entry
+    pad_c = rc >= C
+    cand = _bitonic_sort_desc((
+        jnp.where(pad_c, _I32_MAX, _sort_key(d_dd)),
+        jnp.where(pad_c, _I32_MAX, rc + L),
+        d_dd,
+        ci,
+        (~keep).astype(jnp.int32),
+    ))
+    # beam: rows >= L form a +inf plateau, so [beam asc | candidates desc]
+    # is bitonic and one merge network yields the full ascending order
+    bd = bd_ref[...]
+    rb = jax.lax.broadcasted_iota(jnp.int32, bd.shape, 0)
+    plateau = rb >= L
+    beam = (
+        jnp.where(plateau, _I32_MAX, _sort_key(bd)),
+        jnp.where(plateau, _I32_MAX - 1, rb),
+        bd,
+        bi_ref[...],
+        be_ref[...],
+    )
+    merged = _bitonic_merge(tuple(
+        jnp.concatenate([b, c], axis=0) for b, c in zip(beam, cand)))
+    oi_ref[...] = merged[3][:L]
+    od_ref[...] = merged[2][:L]
+    oe_ref[...] = merged[4][:L]
 
-    bd = bd_ref[0, :]
-    mk_b = mono_key_u32(bd)
-    ix_b = jnp.arange(L, dtype=jnp.int32)
-    vi_b = bi_ref[0, :]
-    ve_b = be_ref[0, :]
-    mid = Pm - L - Pc
-    # [beam asc | +inf plateau | candidates desc] is bitonic: one merge
-    # network pass yields the full ascending order; the first L survive.
-    def seq(b, m, c_rev):
-        return jnp.concatenate([b, m, c_rev[::-1]])
 
-    mk = seq(mk_b, jnp.full((mid,), _U32_MAX, jnp.uint32), mk_c)
-    ix = seq(ix_b, jnp.full((mid,), _I32_MAX - 1, jnp.int32), ix_c)
-    vd = seq(bd, jnp.full((mid,), _INF, jnp.float32), vd_c)
-    vi = seq(vi_b, jnp.full((mid,), -1, jnp.int32), vi_c)
-    ve = seq(ve_b, jnp.ones((mid,), jnp.int32), ve_c)
-    mk, ix, vd, vi, ve = _bitonic_merge((mk, ix, vd, vi, ve))
-    oi_ref[0, :] = vi[:L]
-    od_ref[0, :] = vd[:L]
-    oe_ref[0, :] = ve[:L]
+_LANES = 128
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))
@@ -265,34 +259,36 @@ def beam_merge_pallas(
     :func:`beam_merge_jnp` (bitwise, incl. ties — pinned in tests)."""
     B, L = beam_d.shape
     C = cand_d.shape[1]
-    Pc = next_pow2(max(C, 2))
-    Pm = next_pow2(L + Pc)
-    kernel = functools.partial(
-        _beam_merge_kernel, n=n, L=L, C=C, Pc=Pc, Pm=Pm)
-    row = lambda i: (i, 0)
+    Pc = next_pow2(max(C, 8))
+    Pb = next_pow2(L + Pc) - Pc
+    Bp = -(-B // _LANES) * _LANES
+
+    def lanes(x, rows, fill):
+        """[B, m] -> [rows, Bp]: positions on sublanes, queries on lanes."""
+        x = jnp.pad(x, ((0, Bp - B), (0, rows - x.shape[1])),
+                    constant_values=fill)
+        return x.T
+
+    blk = lambda rows: pl.BlockSpec((rows, _LANES), lambda g: (0, g))  # noqa: E731
     oi, od, oe, ok = pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, L), row),
-            pl.BlockSpec((1, L), row),
-            pl.BlockSpec((1, L), row),
-            pl.BlockSpec((1, C), row),
-            pl.BlockSpec((1, C), row),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, L), row),
-            pl.BlockSpec((1, L), row),
-            pl.BlockSpec((1, L), row),
-            pl.BlockSpec((1, C), row),
-        ],
+        functools.partial(_beam_merge_kernel, n=n, L=L, C=C),
+        grid=(Bp // _LANES,),
+        in_specs=[blk(Pb)] * 3 + [blk(Pc)] * 2,
+        out_specs=[blk(L)] * 3 + [blk(Pc)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, L), jnp.int32),
-            jax.ShapeDtypeStruct((B, L), jnp.float32),
-            jax.ShapeDtypeStruct((B, L), jnp.int32),
-            jax.ShapeDtypeStruct((B, C), jnp.int32),
+            jax.ShapeDtypeStruct((L, Bp), jnp.int32),
+            jax.ShapeDtypeStruct((L, Bp), jnp.float32),
+            jax.ShapeDtypeStruct((L, Bp), jnp.int32),
+            jax.ShapeDtypeStruct((Pc, Bp), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((Pc, _LANES), jnp.int32)],
         interpret=interpret,
-    )(beam_d.astype(jnp.float32), beam_ids,
-      beam_exp.astype(jnp.int32), cand_d.astype(jnp.float32), cand_ids)
-    return oi, od, oe.astype(bool), ok.astype(bool)
+    )(
+        lanes(beam_d.astype(jnp.float32), Pb, _INF),
+        lanes(beam_ids, Pb, -1),
+        lanes(beam_exp.astype(jnp.int32), Pb, 1),
+        lanes(cand_d.astype(jnp.float32), Pc, _INF),
+        lanes(cand_ids, Pc, -1),
+    )
+    return (oi.T[:B], od.T[:B], oe.T[:B].astype(bool),
+            ok.T[:B, :C].astype(bool))

@@ -1,50 +1,44 @@
 """Pallas TPU kernels: fused edge-label validity test + squared distance.
 
-Two variants share the label-test semantics (paper Alg. 2 lines 8-9, turned
-from a per-edge branch into a predication mask so invalid neighbors come
-back +inf and are annihilated by the subsequent top-k):
+Three variants share the label-test semantics (paper Alg. 2 lines 8-9,
+turned from a per-edge branch into a predication mask so invalid
+neighbors come back +inf and are annihilated by the subsequent top-k):
 
 ``filter_dist_pallas`` — the original *pre-gathered* form. The caller hands
 the kernel a dense ``[B, E, D]`` candidate tensor that XLA gathered into HBM
-beforehand. Block layout: grid ``(B, E/TE)``; per step one ``(1, D)`` query
-row, a ``(TE, D)`` candidate tile, ``(TE, 4)`` label rectangles, the
-``(1, 2)`` state and the ``(TE,)`` ids. Kept as the simple baseline (delta
-scans with pre-broadcast candidates, parity tests).
+beforehand. Kept as the simple baseline (the unfused search loop and the
+unfused delta scan).
 
-``filter_dist_gather_pallas`` — the *gather-fused* path (PR 2). The
-kernel receives the full HBM-resident vector table (``memory_space=ANY``,
-never blocked into VMEM) plus scalar-prefetched candidate row ids
-(``PrefetchScalarGridSpec``), and DMAs exactly the ``TE`` needed rows per
-tile into a double-buffered VMEM scratch — tile ``j+1``'s row fetches are
-issued before tile ``j``'s compute, so the gather overlaps the MXU matvec.
-The dense ``[B, E, D]`` intermediate never exists. Squared distance uses
-cached per-row norms (``‖c‖² − 2·q·c + ‖q‖²``; the ``‖c‖²`` vector is
-precomputed once at graph export, so per-candidate traffic beyond the row
-itself is 12 bytes: norm + visited word + label offset). The visited test
-reads a bit-packed ``[B, ceil(n/32)]`` uint32 bitmap: per candidate the
-32-bit word (gathered alongside the norm) is shifted by ``id & 31`` inside
-the kernel, so visited suppression costs one VPU shift instead of a dense
-``[B, n]`` bool round-trip. int8 tables are dequantized in VMEM right after
-the DMA via per-candidate scales.
+``filter_dist_gather_pallas`` — the *gather-fused* path. The kernel
+receives the full HBM-resident vector table (``memory_space=ANY``, never
+blocked into VMEM) in the row layout of :mod:`repro.kernels.layout` plus
+scalar-prefetched candidate row ids (``PrefetchScalarGridSpec``), and DMAs
+exactly the ``TE`` needed rows per tile into a double-buffered VMEM
+scratch — tile ``r+1``'s row fetches are issued before tile ``r``'s
+compute, so the gather overlaps the MXU matvec. The dense ``[B, E, D]``
+intermediate never exists. Squared distance uses cached per-row norms
+(``‖c‖² − 2·q·c + ‖q‖²``), the visited test shifts the candidate's word of
+the bit-packed ``[B, ceil(n/32)]`` bitmap, and int8 rows arrive as int32
+words whose bytes the kernel unpacks with shifts (dequantized by the
+per-candidate scale after the MXU).
 
 ``filter_dist_gather_packed_pallas`` — the *packed-metadata superkernel*
-(the serving hot path). Same vector-row DMA pipeline, but the per-edge
-label rectangles never cross the XLA boundary at all: the ``[n, E, 2]``
-uint32 *bit-packed* label table (two 16-bit ranks per word — see
-``repro.search.device_graph.pack_labels``) stays HBM-resident
-(``memory_space=ANY``) and the kernel DMAs the ``M`` expanded nodes' label
-rows into a VMEM scratch at each query's first tile, driven by a second
-scalar-prefetch operand carrying the expanded-node ids. The dominance test
-unpacks the 16-bit ranks with a mask-and-shift and compares in-register —
-8 bytes of label traffic per edge instead of 16, and no ``[B, M·E, 4]``
-label gather in the surrounding program (asserted structurally by
+(the serving hot path). One grid step per (query, expanded node): the tile
+is that node's ``E`` neighbors, and the node's packed label row (two 16-bit
+ranks per word, ``[n, 1, 2·Ep]`` — see ``repro.kernels.layout``) is DMA'd
+alongside the vector rows, driven by a second scalar-prefetch operand
+carrying the expanded-node ids. The dominance test unpacks the ranks with a
+mask-and-shift and compares in-register; no ``[B, M·E, 4]`` label gather
+exists in the surrounding program (asserted structurally by
 ``benchmarks/bench_batched.py``).
 
-VMEM at defaults (TE=128, D<=2048 f32): 2 x 1 MiB double-buffered candidate
-scratch + 8 KiB query + ~7 KiB of per-candidate metadata tiles (+ up to
-8 KiB of packed label rows for the superkernel) — well under the ~16 MiB
-budget, with headroom for the pipeline's own double-buffering of the
-blocked operands.
+Layout rules the TPU lowering imposes, and how the kernels meet them:
+every per-candidate operand is a ``[R, 1, te]`` array blocked ``(1, 1,
+te)`` (candidates on lanes, one tile per grid step, ``R = B·tiles``); the
+per-query rank states ride in SMEM as a scalar-prefetch operand; tables
+are DMA'd one ``(1, W)`` tile per row. VMEM at d = 768 f32, TE = 128: two
+``(TE, 1, 768)`` row buffers (≤ 6 MiB with sublane padding) plus a few KiB
+of metadata tiles.
 """
 from __future__ import annotations
 
@@ -55,31 +49,64 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-TE = 128  # candidate-tile rows
+from repro.kernels.layout import label_rows, query_planes, table_rows
+
+TE = 128  # candidate-tile width
+_NT = (((1,), (1,)), ((), ()))   # dot_general: contract both last dims
 
 
-def _filter_dist_kernel(q_ref, cand_ref, lab_ref, state_ref, ids_ref, out_ref):
-    q = q_ref[0].astype(jnp.float32)                  # [D]
-    cand = cand_ref[0].astype(jnp.float32)            # [TE, D]
-    lab = lab_ref[0]                                  # [TE, 4] int32
-    a = state_ref[0, 0]
-    c = state_ref[0, 1]
-    ids = ids_ref[0]                                  # [TE]
+def _dot(a, b):
+    """``a @ b.T`` in full f32 precision. The MXU's default f32 matmul on
+    TPU rounds its inputs to bf16, which reorders near-tied neighbours
+    against the exact f32 ground truth and makes the brute-valid scan
+    inexact."""
+    return jax.lax.dot_general(a, b, _NT, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
-    cross = jax.lax.dot_general(
-        cand, q[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]                                           # [TE] via MXU matvec
-    cs = jnp.sum(cand * cand, axis=1)
-    qs = jnp.sum(q * q)
-    dist = cs - 2.0 * cross + qs
 
-    ok = (
-        (lab[:, 0] <= a) & (a <= lab[:, 1])
-        & (lab[:, 2] <= c) & (c <= lab[:, 3])
-        & (ids >= 0)
-    )
-    out_ref[0, :] = jnp.where(ok, dist, jnp.inf)
+def _shr(x, s):
+    return jax.lax.shift_right_logical(x, jnp.asarray(s, x.dtype))
+
+
+def _cross(q_ref, rows):
+    """``[1, te]`` inner products of the query with ``rows [te, W]`` (f32
+    values, or int32 words of four int8 values each, one MXU pass per byte
+    plane against the matching plane of ``q_ref``)."""
+    if rows.dtype != jnp.int32:
+        return _dot(q_ref[0], rows)
+    cross = None
+    for k in range(4):
+        plane = jax.lax.shift_right_arithmetic(
+            jax.lax.shift_left(rows, jnp.int32(24 - 8 * k)), jnp.int32(24)
+        ).astype(jnp.float32)
+        part = _dot(q_ref[0, k:k + 1, :], plane)
+        cross = part if cross is None else cross + part
+    return cross
+
+
+def _rank_ok(lx, hx, ly, hy, a, c):
+    return (lx <= a) & (a <= hx) & (ly <= c) & (c <= hy)
+
+
+def _filter_dist_kernel(st_ref, q_ref, cand_ref, lab_ref, ids_ref, out_ref,
+                        *, tiles):
+    b = pl.program_id(0) // tiles
+    q = q_ref[0]                                      # [1, Dp]
+    cand = cand_ref[0].astype(jnp.float32)            # [te, Dp]
+    lab = lab_ref[0]                                  # [4, te]
+    ids = ids_ref[0]                                  # [1, te]
+    cross = _dot(q, cand)
+    cs = _dot(jnp.ones_like(q), cand * cand)
+    dist = cs - 2.0 * cross + jnp.sum(q * q)
+    ok = _rank_ok(lab[0:1], lab[1:2], lab[2:3], lab[3:4],
+                  st_ref[b, 0], st_ref[b, 1]) & (ids >= 0)
+    out_ref[0] = jnp.where(ok, dist, jnp.inf)
+
+
+def _pad_tiles(c: int, te: int):
+    te = min(te, c)
+    tiles = -(-c // te)
+    return te, tiles, tiles * te - c
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "te"))
@@ -94,130 +121,149 @@ def filter_dist_pallas(
     te: int = TE,
 ) -> jnp.ndarray:
     b, e, d = cand.shape
-    pe = (-e) % te
-    if pe:
-        cand = jnp.pad(cand, ((0, 0), (0, pe), (0, 0)))
-        labels = jnp.pad(labels, ((0, 0), (0, pe), (0, 0)))
-        cand_ids = jnp.pad(cand_ids, ((0, 0), (0, pe)), constant_values=-1)
-    ep = cand.shape[1]
-    grid = (b, ep // te)
-    out = pl.pallas_call(
-        _filter_dist_kernel,
-        grid=grid,
+    te, tiles, pe = _pad_tiles(e, te)
+    dp = -(-d // 128) * 128
+    cand = jnp.pad(cand.astype(jnp.float32), ((0, 0), (0, pe), (0, dp - d)))
+    labels = jnp.pad(labels, ((0, 0), (0, pe), (0, 0)))
+    cand_ids = jnp.pad(cand_ids, ((0, 0), (0, pe)), constant_values=-1)
+    R = b * tiles
+    qp = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, dp - d)))
+    lab = labels.reshape(b, tiles, te, 4).transpose(0, 1, 3, 2)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(R,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, te, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, te, 4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, te), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, dp), lambda r, st: (r // tiles, 0, 0)),
+            pl.BlockSpec((1, te, dp), lambda r, st: (r, 0, 0)),
+            pl.BlockSpec((1, 4, te), lambda r, st: (r, 0, 0)),
+            pl.BlockSpec((1, 1, te), lambda r, st: (r, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, te), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, ep), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, te), lambda r, st: (r, 0, 0)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_filter_dist_kernel, tiles=tiles),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, 1, te), jnp.float32),
         interpret=interpret,
-    )(q, cand, labels, state, cand_ids)
-    return out[:, :e]
+    )(state.astype(jnp.int32), qp.reshape(b, 1, dp),
+      cand.reshape(R, te, dp), lab.reshape(R, 4, te),
+      cand_ids.reshape(R, 1, te))
+    return out.reshape(b, tiles * te)[:, :e]
 
 
-def _row_fetch_pipeline(sids_ref, table_ref, vec_scratch, sem,
-                        *, pos, total, tiles, te):
-    """Double-buffered per-row HBM→VMEM fetch, shared by both gather
-    kernels: warm tile 0 up, issue tile ``pos+1``'s fetches before tile
-    ``pos``'s compute, await tile ``pos``. Returns the scratch slot now
-    holding tile ``pos``'s rows."""
-    slot = jax.lax.rem(pos, 2)
-    nslot = jax.lax.rem(pos + 1, 2)
+def _gather_kernel(*refs, te: int, tiles: int, packed: bool):
+    """One grid step = one tile of ``te`` candidates of query
+    ``r // tiles``. Double-buffered per-row HBM→VMEM fetch: warm tile 0
+    up, issue tile ``r+1``'s fetches before tile ``r``'s compute, await
+    tile ``r``. The packed kernel also fetches the tile's label row."""
+    if packed:
+        (st_ref, cur_ref, table_ref, plab_ref, sids_ref, nsids_ref, q_ref,
+         ids_ref, norm_ref, word_ref, scale_ref, out_ref,
+         vec_scr, lab_scr, sem, lab_sem) = refs
+    else:
+        (st_ref, table_ref, sids_ref, nsids_ref, q_ref, lab_ref, ids_ref,
+         norm_ref, word_ref, scale_ref, out_ref, vec_scr, sem) = refs
+    r = pl.program_id(0)
+    slot = jax.lax.rem(r, 2)
 
-    def row_dma(p, s, r):
-        """DMA descriptor for row r of flat tile p into scratch slot s."""
-        ti = p // tiles
-        tj = jax.lax.rem(p, tiles)
-        idx = sids_ref[ti, tj * te + r]
+    def row_copy(p, s, i):
+        # the row ids of tile r and of tile r+1 arrive as SMEM blocks
+        src = jnp.where(p == r, sids_ref[0, 0, i], nsids_ref[0, 0, i])
         return pltpu.make_async_copy(
-            table_ref.at[idx], vec_scratch.at[s, r], sem.at[s, r]
-        )
+            table_ref.at[src], vec_scr.at[s, i], sem.at[s, i])
 
-    @pl.when(pos == 0)
-    def _warmup():          # first tile has no predecessor to prefetch it
-        def go(r, _):
-            row_dma(0, 0, r).start()
-            return 0
+    def label_copy(p, s):
+        return pltpu.make_async_copy(
+            plab_ref.at[cur_ref[p]], lab_scr.at[s], lab_sem.at[s])
+
+    def start(p, s):
+        def go(i, carry):
+            row_copy(p, s, i).start()
+            return carry
         jax.lax.fori_loop(0, te, go, 0)
+        if packed:
+            label_copy(p, s).start()
 
-    @pl.when(pos + 1 < total)
-    def _prefetch():        # issue tile j+1's fetches before tile j's compute
-        def go(r, _):
-            row_dma(pos + 1, nslot, r).start()
-            return 0
-        jax.lax.fori_loop(0, te, go, 0)
+    @pl.when(r == 0)
+    def _warmup():          # the first tile has no predecessor to prefetch it
+        start(0, 0)
 
-    def wait(r, _):
-        row_dma(pos, slot, r).wait()
-        return 0
+    @pl.when(r + 1 < pl.num_programs(0))
+    def _prefetch():        # issue tile r+1's fetches before tile r's compute
+        start(r + 1, 1 - slot)
+
+    def wait(i, carry):
+        row_copy(r, slot, i).wait()
+        return carry
     jax.lax.fori_loop(0, te, wait, 0)
-    return slot
 
-
-def _masked_distance(q_ref, cand, norm_ref, scale_ref, word_ref, ids,
-                     label_ok):
-    """Shared compute epilogue: cached-norm distance off the MXU matvec,
-    in-register visited test, predication to +inf. ``label_ok`` is the
-    layout-specific dominance mask (int32 rectangles or packed words)."""
-    q = q_ref[0].astype(jnp.float32)                  # [D]
-    scale = scale_ref[0]
-    cross = jax.lax.dot_general(
-        cand, q[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0] * scale                                   # dequant after the MXU
-    qs = jnp.sum(q * q)
-    dist = norm_ref[0] - 2.0 * cross + qs
-    shift = (jnp.maximum(ids, 0) & 31).astype(jnp.uint32)
-    seen = (jax.lax.shift_right_logical(word_ref[0], shift)
-            & jnp.uint32(1)) == jnp.uint32(1)
+    b = r // tiles
+    a, c = st_ref[2 * b], st_ref[2 * b + 1]
+    if packed:
+        label_copy(r, slot).wait()
+        lab = lab_scr[slot]                           # [1, 2·Ep] int32
+        ep = lab.shape[-1] // 2
+        w0, w1 = lab[:, :te], lab[:, ep:ep + te]
+        label_ok = _rank_ok(w0 & 0xFFFF, _shr(w0, 16),
+                            w1 & 0xFFFF, _shr(w1, 16), a, c)
+    else:
+        lab = lab_ref[0]                              # [4, te] int32
+        label_ok = _rank_ok(lab[0:1], lab[1:2], lab[2:3], lab[3:4], a, c)
+    rows = vec_scr[slot]                              # [te, 1, W]
+    rows = rows.reshape(rows.shape[0], rows.shape[2])
+    q = q_ref[0]                                      # [P, W]
+    cross = _cross(q_ref, rows) * scale_ref[0]        # dequant after the MXU
+    dist = norm_ref[0] - 2.0 * cross + jnp.sum(q * q)
+    ids = ids_ref[0]                                  # [1, te]
+    seen = (_shr(word_ref[0], jnp.maximum(ids, 0) & 31) & 1) == 1
     ok = label_ok & (ids >= 0) & ~seen
-    return jnp.where(ok, dist, jnp.inf)
+    out_ref[0] = jnp.where(ok, dist, jnp.inf)
 
 
-def _gather_kernel_body(
-    sids_ref,    # scalar prefetch: [B, Cp] int32 safe (clipped) row ids
-    table_ref,   # [n, D] HBM (ANY) — full vector table, never blocked
-    q_ref,       # (1, D)
-    lab_ref,     # (1, TE, 4) int32
-    state_ref,   # (1, 2) int32
-    ids_ref,     # (1, TE) int32 raw ids (-1 = padding/inactive)
-    norm_ref,    # (1, TE) f32 cached ‖c‖² per candidate
-    word_ref,    # (1, TE) uint32 visited word per candidate
-    scale_ref,   # (1, TE) f32 dequant scale per candidate (1.0 for f32)
-    out_ref,     # (1, TE) f32
-    vec_scratch,  # VMEM (2, TE, D) table.dtype — double-buffered row tiles
-    sem,          # DMA (2, TE)
-    *,
-    te: int,
-    tiles: int,
-):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    pos = i * tiles + j          # flat tile index in grid iteration order
-    total = pl.num_programs(0) * tiles
-    slot = _row_fetch_pipeline(
-        sids_ref, table_ref, vec_scratch, sem,
-        pos=pos, total=total, tiles=tiles, te=te,
+def _gather_call(kernel_refs_prefix, *, rows, qp, tiled, R, te, tiles,
+                 packed, extra_in_specs, extra_scratch, interpret):
+    """Shared ``pallas_call`` plumbing of the two gather kernels."""
+    n, _, W = rows.shape
+    P = qp.shape[1]
+    nsp = len(kernel_refs_prefix)
+    tile = lambda r, *_: (r, 0, 0)                    # noqa: E731
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]           # table (HBM)
+    in_specs += extra_in_specs
+    # DMA source rows of this tile and of the next one (SMEM, per step)
+    in_specs += [
+        pl.BlockSpec((1, 1, te), tile, memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, 1, te),
+                     lambda r, *_: (jnp.minimum(r + 1, R - 1), 0, 0),
+                     memory_space=pltpu.SMEM),
+    ]
+    in_specs += [pl.BlockSpec((1, P, W), lambda r, *_: (r // tiles, 0, 0))]
+    if not packed:
+        in_specs += [pl.BlockSpec((1, 4, te), tile)]              # labels
+    in_specs += [pl.BlockSpec((1, 1, te), tile)] * 4   # ids, norms, words, scales
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=nsp,
+        grid=(R,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, te), tile),
+        scratch_shapes=[pltpu.VMEM((2, te, 1, W), rows.dtype)]
+        + extra_scratch + [pltpu.SemaphoreType.DMA((2, te))]
+        + ([pltpu.SemaphoreType.DMA((2,))] if packed else []),
     )
-    cand = vec_scratch[slot].astype(jnp.float32)      # [TE, D]
-    lab = lab_ref[0]
-    a = state_ref[0, 0]
-    c = state_ref[0, 1]
-    label_ok = (
-        (lab[:, 0] <= a) & (a <= lab[:, 1])
-        & (lab[:, 2] <= c) & (c <= lab[:, 3])
-    )
-    out_ref[0, :] = _masked_distance(
-        q_ref, cand, norm_ref, scale_ref, word_ref, ids_ref[0], label_ok
-    )
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, te=te, tiles=tiles, packed=packed),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, 1, te), jnp.float32),
+        interpret=interpret,
+    )(*kernel_refs_prefix, *tiled)
+
+
+def _tile_meta(x, R, te):
+    return x.reshape(R, 1, te)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "te"))
 def filter_dist_gather_pallas(
-    table: jnp.ndarray,      # [n, D] f32/bf16/int8 — full HBM table
+    table: jnp.ndarray,      # [n, D] f32/int8, or its [n, 1, W] rows
     q: jnp.ndarray,          # [B, D]
     cand_ids: jnp.ndarray,   # [B, C] int32, -1 = padding/inactive
     labels: jnp.ndarray,     # [B, C, 4] int32
@@ -230,121 +276,38 @@ def filter_dist_gather_pallas(
     te: int = TE,
 ) -> jnp.ndarray:
     b, c = cand_ids.shape
-    n, d = table.shape
-    te = min(te, max(8, -(-c // 8) * 8))    # small fan-outs: shrink the tile
-    pc = (-c) % te
+    rows = table_rows(table)
+    n = rows.shape[0]
+    te, tiles, pc = _pad_tiles(c, te)
     if pc:
         cand_ids = jnp.pad(cand_ids, ((0, 0), (0, pc)), constant_values=-1)
         labels = jnp.pad(labels, ((0, 0), (0, pc), (0, 0)))
         norms = jnp.pad(norms, ((0, 0), (0, pc)))
         words = jnp.pad(words, ((0, 0), (0, pc)))
         scales = jnp.pad(scales, ((0, 0), (0, pc)), constant_values=1.0)
-    cp = cand_ids.shape[1]
-    tiles = cp // te
-    safe_ids = jnp.clip(cand_ids, 0, n - 1)   # DMA source rows (pad -> row 0)
-    grid = (b, tiles)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),               # table (HBM)
-            pl.BlockSpec((1, d), lambda i, j, s: (i, 0)),       # q
-            pl.BlockSpec((1, te, 4), lambda i, j, s: (i, j, 0)),  # labels
-            pl.BlockSpec((1, 2), lambda i, j, s: (i, 0)),       # state
-            pl.BlockSpec((1, te), lambda i, j, s: (i, j)),      # raw ids
-            pl.BlockSpec((1, te), lambda i, j, s: (i, j)),      # norms
-            pl.BlockSpec((1, te), lambda i, j, s: (i, j)),      # visited words
-            pl.BlockSpec((1, te), lambda i, j, s: (i, j)),      # scales
-        ],
-        out_specs=pl.BlockSpec((1, te), lambda i, j, s: (i, j)),
-        scratch_shapes=[
-            pltpu.VMEM((2, te, d), table.dtype),
-            pltpu.SemaphoreType.DMA((2, te)),
-        ],
-    )
-    kernel = functools.partial(_gather_kernel_body, te=te, tiles=tiles)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, cp), jnp.float32),
+    R = b * tiles
+    safe_ids = jnp.clip(cand_ids, 0, n - 1).reshape(R, 1, te)  # DMA rows
+    lab = labels.reshape(b, tiles, te, 4).transpose(0, 1, 3, 2)
+    qp = query_planes(q, rows)
+    out = _gather_call(
+        (state.astype(jnp.int32).reshape(-1),),
+        rows=rows, qp=qp, R=R, te=te, tiles=tiles,
+        packed=False, extra_in_specs=[], extra_scratch=[],
         interpret=interpret,
-    )(safe_ids, table, q, labels, state, cand_ids, norms, words, scales)
-    return out[:, :c]
-
-
-def _gather_packed_kernel_body(
-    sids_ref,    # scalar prefetch: [B, Cp] int32 safe (clipped) row ids
-    cur_ref,     # scalar prefetch: [B, M] int32 safe expanded-node ids
-    table_ref,   # [n, D] HBM (ANY) — full vector table, never blocked
-    plab_ref,    # [n, E, 2] HBM (ANY) — bit-packed label rectangles
-    q_ref,       # (1, D)
-    state_ref,   # (1, 2) int32
-    ids_ref,     # (1, TE) int32 raw ids (-1 = padding/inactive)
-    norm_ref,    # (1, TE) f32 cached ‖c‖² per candidate
-    word_ref,    # (1, TE) uint32 visited word per candidate
-    scale_ref,   # (1, TE) f32 dequant scale per candidate (1.0 for f32)
-    out_ref,     # (1, TE) f32
-    vec_scratch,  # VMEM (2, TE, D) table.dtype — double-buffered row tiles
-    lab_scratch,  # VMEM (Cp, 2) uint32 — the query's M·E packed label rows
-    sem,          # DMA (2, TE)
-    lab_sem,      # DMA (M,)
-    *,
-    te: int,
-    tiles: int,
-    E: int,
-    M: int,
-):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    pos = i * tiles + j          # flat tile index in grid iteration order
-    total = pl.num_programs(0) * tiles
-
-    def lab_dma(m):
-        """DMA descriptor for expanded node m's packed label row [E, 2]."""
-        idx = cur_ref[i, m]
-        return pltpu.make_async_copy(
-            plab_ref.at[idx], lab_scratch.at[pl.ds(m * E, E)], lab_sem.at[m]
-        )
-
-    @pl.when(j == 0)
-    def _labels():
-        # the query's whole [M, E, 2] metadata block lands at its first
-        # tile and persists in scratch for the remaining tiles — ~8 B/edge,
-        # so issue-and-wait (tile 0 needs the first rows immediately)
-        def start(m, _):
-            lab_dma(m).start()
-            return 0
-        jax.lax.fori_loop(0, M, start, 0)
-
-        def wait(m, _):
-            lab_dma(m).wait()
-            return 0
-        jax.lax.fori_loop(0, M, wait, 0)
-
-    slot = _row_fetch_pipeline(
-        sids_ref, table_ref, vec_scratch, sem,
-        pos=pos, total=total, tiles=tiles, te=te,
+        tiled=(rows, safe_ids, safe_ids, qp, lab.reshape(R, 4, te),
+               _tile_meta(cand_ids, R, te),
+               _tile_meta(norms.astype(jnp.float32), R, te),
+               _tile_meta(jax.lax.bitcast_convert_type(words, jnp.int32),
+                          R, te),
+               _tile_meta(scales.astype(jnp.float32), R, te)),
     )
-    cand = vec_scratch[slot].astype(jnp.float32)      # [TE, D]
-    a = state_ref[0, 0]
-    c = state_ref[0, 1]
-    # dominance test on packed words: mask-and-shift out the 16-bit ranks
-    lab = lab_scratch[pl.ds(j * te, te), :]           # [TE, 2] uint32
-    mask16 = jnp.uint32(0xFFFF)
-    lo_x = (lab[:, 0] & mask16).astype(jnp.int32)
-    hi_x = (lab[:, 0] >> 16).astype(jnp.int32)
-    lo_y = (lab[:, 1] & mask16).astype(jnp.int32)
-    hi_y = (lab[:, 1] >> 16).astype(jnp.int32)
-    label_ok = (lo_x <= a) & (a <= hi_x) & (lo_y <= c) & (c <= hi_y)
-    out_ref[0, :] = _masked_distance(
-        q_ref, cand, norm_ref, scale_ref, word_ref, ids_ref[0], label_ok
-    )
+    return out.reshape(b, tiles * te)[:, :c]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "te"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def filter_dist_gather_packed_pallas(
-    table: jnp.ndarray,      # [n, D] f32/bf16/int8 — full HBM table
-    plabels: jnp.ndarray,    # [n, E, 2] uint32 — full HBM packed label table
+    table: jnp.ndarray,      # [n, D] f32/int8, or its [n, 1, W] rows
+    plabels: jnp.ndarray,    # [n, E, 2] uint32 packed words, or their rows
     q: jnp.ndarray,          # [B, D]
     cur_ids: jnp.ndarray,    # [B, M] int32 expanded beam nodes
     cand_ids: jnp.ndarray,   # [B, M*E] int32, -1 = padding/inactive
@@ -354,58 +317,36 @@ def filter_dist_gather_packed_pallas(
     scales: jnp.ndarray,     # [B, M*E] f32 gathered dequant scales
     *,
     interpret: bool = False,
-    te: int = TE,
 ) -> jnp.ndarray:
-    """Packed-metadata superkernel: per-tile vector-row DMA (double
-    buffered, as in :func:`filter_dist_gather_pallas`) plus a per-query DMA
-    of the ``M`` expanded nodes' packed ``[E, 2]`` label rows — the label
-    metadata never exists as an XLA-side gathered intermediate."""
+    """Packed-metadata superkernel: one tile per (query, expanded node),
+    the node's ``E`` neighbor rows and its packed label row DMA'd from the
+    HBM tables — the label metadata never exists as an XLA-side gathered
+    intermediate."""
     b, c = cand_ids.shape
-    n, d = table.shape
-    E = plabels.shape[1]
     M = cur_ids.shape[1]
-    if M * E != c:
-        raise ValueError(f"cand_ids width {c} != M*E = {M}*{E}")
-    te = min(te, max(8, -(-c // 8) * 8))    # small fan-outs: shrink the tile
-    pc = (-c) % te
-    if pc:
-        cand_ids = jnp.pad(cand_ids, ((0, 0), (0, pc)), constant_values=-1)
-        norms = jnp.pad(norms, ((0, 0), (0, pc)))
-        words = jnp.pad(words, ((0, 0), (0, pc)))
-        scales = jnp.pad(scales, ((0, 0), (0, pc)), constant_values=1.0)
-    cp = cand_ids.shape[1]
-    tiles = cp // te
-    safe_ids = jnp.clip(cand_ids, 0, n - 1)   # DMA source rows (pad -> row 0)
-    safe_cur = jnp.clip(cur_ids, 0, n - 1)
-    grid = (b, tiles)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),                 # table
-            pl.BlockSpec(memory_space=pltpu.ANY),                 # plabels
-            pl.BlockSpec((1, d), lambda i, j, s, u: (i, 0)),      # q
-            pl.BlockSpec((1, 2), lambda i, j, s, u: (i, 0)),      # state
-            pl.BlockSpec((1, te), lambda i, j, s, u: (i, j)),     # raw ids
-            pl.BlockSpec((1, te), lambda i, j, s, u: (i, j)),     # norms
-            pl.BlockSpec((1, te), lambda i, j, s, u: (i, j)),     # words
-            pl.BlockSpec((1, te), lambda i, j, s, u: (i, j)),     # scales
-        ],
-        out_specs=pl.BlockSpec((1, te), lambda i, j, s, u: (i, j)),
-        scratch_shapes=[
-            pltpu.VMEM((2, te, d), table.dtype),
-            pltpu.VMEM((cp, 2), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2, te)),
-            pltpu.SemaphoreType.DMA((M,)),
-        ],
-    )
-    kernel = functools.partial(
-        _gather_packed_kernel_body, te=te, tiles=tiles, E=E, M=M)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, cp), jnp.float32),
+    rows = table_rows(table)
+    lrows = label_rows(plabels)
+    n = rows.shape[0]
+    E = c // M
+    if M * E != c or 2 * E > lrows.shape[-1]:
+        raise ValueError(
+            f"cand_ids width {c} is not M*E for M={M} and the label rows "
+            f"of width {lrows.shape[-1]}")
+    R = b * M
+    safe_ids = jnp.clip(cand_ids, 0, n - 1).reshape(R, 1, E)
+    safe_cur = jnp.clip(cur_ids, 0, n - 1).reshape(R)
+    qp = query_planes(q, rows)
+    out = _gather_call(
+        (state.astype(jnp.int32).reshape(-1), safe_cur),
+        rows=rows, qp=qp, R=R, te=E, tiles=M, packed=True,
+        extra_in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # label rows
+        extra_scratch=[pltpu.VMEM((2, 1, lrows.shape[-1]), jnp.int32)],
         interpret=interpret,
-    )(safe_ids, safe_cur, table, plabels, q, state, cand_ids, norms, words,
-      scales)
-    return out[:, :c]
+        tiled=(rows, lrows, safe_ids, safe_ids, qp,
+               _tile_meta(cand_ids, R, E),
+               _tile_meta(norms.astype(jnp.float32), R, E),
+               _tile_meta(jax.lax.bitcast_convert_type(words, jnp.int32),
+                          R, E),
+               _tile_meta(scales.astype(jnp.float32), R, E)),
+    )
+    return out.reshape(b, c)

@@ -1,10 +1,20 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Jit'd public wrappers around the Pallas kernels, and the one place that
+decides between a kernel and its jnp oracle.
 
-On TPU the compiled kernels run natively; on CPU (this container) they run
-under ``interpret=True``, which executes the kernel body in Python for
-correctness validation. ``use_ref=True`` routes to the pure-jnp oracle —
-used both as a fallback and by the benchmark harness to quantify kernel
-speedups.
+``use_ref`` on every entry point of the search, serve and build paths
+means: ``True`` the pure-jnp oracle (``repro.kernels.ref``), ``False`` the
+Pallas kernel, ``None`` (their default) the backend's choice —
+:func:`use_reference`: the compiled kernels on TPU, the oracle everywhere
+else. Tests run on the CPU, where a kernel asked for explicitly runs under
+``interpret=True`` (the kernel body executed by the Pallas interpreter,
+for correctness only); on TPU a kernel is always compiled, never
+interpreted, and nothing falls back to the oracle unless a caller asks for
+it.
+
+The gather kernels read tables in the row layout of
+:mod:`repro.kernels.layout`. The wrappers accept a logical ``[n, d]``
+table too and convert it in-graph; serving bundles store the row layout
+once per export so no call pays that copy.
 """
 from __future__ import annotations
 
@@ -22,15 +32,29 @@ from repro.kernels.int8dist import int8_l2dist_pallas, quantize_int8
 from repro.kernels.l2dist import l2dist_pallas
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
-def l2dist(q: jnp.ndarray, c: jnp.ndarray, *, use_ref: bool = False) -> jnp.ndarray:
+def use_reference(use_ref: bool | None = None) -> bool:
+    """Resolve a caller's ``use_ref``: an explicit ``True``/``False`` is
+    kept; ``None`` selects the kernels on TPU and the jnp oracle on any
+    other backend."""
+    if use_ref is None:
+        return not _on_tpu()
+    return bool(use_ref)
+
+
+def _interpret() -> bool:
+    """Kernels run compiled on TPU and interpreted anywhere else."""
+    return not _on_tpu()
+
+
+def l2dist(q: jnp.ndarray, c: jnp.ndarray, *, use_ref: bool | None = False) -> jnp.ndarray:
     """Squared-L2 distance matrix [Bq, Bc]."""
-    if use_ref:
+    if use_reference(use_ref):
         return ref.l2dist_ref(q, c)
-    return l2dist_pallas(q, c, interpret=_on_cpu())
+    return l2dist_pallas(q, c, interpret=_interpret())
 
 
 def filter_dist(
@@ -40,16 +64,16 @@ def filter_dist(
     state: jnp.ndarray,
     cand_ids: jnp.ndarray,
     *,
-    use_ref: bool = False,
+    use_ref: bool | None = False,
 ) -> jnp.ndarray:
     """Fused label-validity + squared distance [B, E] (+inf = inactive)."""
-    if use_ref:
+    if use_reference(use_ref):
         return ref.filter_dist_ref(q, cand, labels, state, cand_ids)
-    return filter_dist_pallas(q, cand, labels, state, cand_ids, interpret=_on_cpu())
+    return filter_dist_pallas(q, cand, labels, state, cand_ids, interpret=_interpret())
 
 
 def filter_dist_gather(
-    table: jnp.ndarray,      # [n, D] full vector table (f32 or int8)
+    table: jnp.ndarray,      # [n, D] vector table (f32 or int8) or its rows
     norms: jnp.ndarray,      # [n] f32 cached ‖c‖² of the (dequantized) rows
     q: jnp.ndarray,          # [B, D]
     cand_ids: jnp.ndarray,   # [B, C] int32 candidate row ids (-1 = padding)
@@ -58,7 +82,7 @@ def filter_dist_gather(
     visited: jnp.ndarray,    # [B, ceil(n/32)] uint32 bit-packed visited set
     *,
     scales: jnp.ndarray | None = None,   # [n] f32 int8 dequant scales
-    use_ref: bool = False,
+    use_ref: bool | None = False,
 ) -> jnp.ndarray:
     """Gather-fused label-validity + visited test + squared distance [B, C].
 
@@ -67,7 +91,7 @@ def filter_dist_gather(
     Only the 4-byte per-candidate metadata (cached norm, visited word,
     dequant scale) is gathered here on the XLA side before the call.
     """
-    if use_ref:
+    if use_reference(use_ref):
         return ref.filter_dist_gather_ref(
             table, norms, q, cand_ids, labels, state, visited, scales
         )
@@ -81,13 +105,13 @@ def filter_dist_gather(
         g_scales = jnp.ones_like(g_norms)
     return filter_dist_gather_pallas(
         table, q, cand_ids, labels, state, g_norms, g_words, g_scales,
-        interpret=_on_cpu(),
+        interpret=_interpret(),
     )
 
 
 def filter_dist_gather_packed(
-    table: jnp.ndarray,      # [n, D] full vector table (f32 or int8)
-    plabels: jnp.ndarray,    # [n, E, 2] uint32 bit-packed label rectangles
+    table: jnp.ndarray,      # [n, D] vector table (f32 or int8) or its rows
+    plabels: jnp.ndarray,    # [n, E, 2] uint32 packed words, or their rows
     norms: jnp.ndarray,      # [n] f32 cached ‖c‖² of the (dequantized) rows
     q: jnp.ndarray,          # [B, D]
     cur_ids: jnp.ndarray,    # [B, M] int32 expanded beam nodes
@@ -96,14 +120,14 @@ def filter_dist_gather_packed(
     visited: jnp.ndarray,    # [B, ceil(n/32)] uint32 bit-packed visited set
     *,
     scales: jnp.ndarray | None = None,   # [n] f32 int8 dequant scales
-    use_ref: bool = False,
+    use_ref: bool | None = False,
 ) -> jnp.ndarray:
     """Packed-metadata superkernel: gather-fused label + visited test +
     squared distance ``[B, M·E]`` where the label metadata is DMA'd
     in-kernel from the packed ``[n, E, 2]`` uint32 table — no XLA-side
-    label gather at all. Per-candidate host-side traffic is the same
+    label gather at all. Per-candidate XLA-side traffic is the same
     12 bytes of (norm, visited word, scale) as ``filter_dist_gather``."""
-    if use_ref:
+    if use_reference(use_ref):
         return ref.filter_dist_gather_packed_ref(
             table, plabels, norms, q, cur_ids, cand_ids, state, visited,
             scales,
@@ -118,7 +142,7 @@ def filter_dist_gather_packed(
         g_scales = jnp.ones_like(g_norms)
     return filter_dist_gather_packed_pallas(
         table, plabels, q, cur_ids, cand_ids, state, g_norms, g_words,
-        g_scales, interpret=_on_cpu(),
+        g_scales, interpret=_interpret(),
     )
 
 
@@ -130,17 +154,17 @@ def beam_merge(
     cand_ids: jnp.ndarray,   # [B, C] int32
     *,
     n: int,
-    use_ref: bool = False,
+    use_ref: bool | None = False,
 ):
     """Deduplicating top-L beam merge — ``(new_ids, new_d, new_exp, keep)``.
 
-    ``use_ref=True`` (and the CPU backend) run the pure-jnp formulation
-    (matrix dedup + ``lax.top_k``); TPU runs the Pallas bitonic
-    sort-and-merge network. Both are pinned bitwise — including exact
+    The reference (and any backend but TPU) runs the pure-jnp formulation
+    (matrix dedup + ``lax.top_k``); the kernel path on TPU runs the Pallas
+    bitonic sort-and-merge network. Both are pinned bitwise — including exact
     distance ties — to the stable-``lax.sort`` oracle
     ``ref.beam_merge_ref`` in ``tests/test_kernels.py``, so path choice
     never changes results."""
-    if use_ref or _on_cpu():
+    if use_reference(use_ref) or not _on_tpu():
         return beam_merge_jnp(
             beam_d, beam_ids, beam_exp, cand_d, cand_ids, n=n)
     return beam_merge_pallas(
@@ -154,7 +178,7 @@ def topk_merge(
     cand_ids: jnp.ndarray,   # [B, C] int32
     *,
     n: int,
-    use_ref: bool = False,
+    use_ref: bool | None = False,
 ):
     """Fold a candidate block into a running ascending top-L — ``(ids, d)``.
 
@@ -176,16 +200,17 @@ def topk_merge(
 
 
 def int8_l2dist(
-    q: jnp.ndarray, c_q: jnp.ndarray, c_scale: jnp.ndarray, *, use_ref: bool = False
+    q: jnp.ndarray, c_q: jnp.ndarray, c_scale: jnp.ndarray, *, use_ref: bool | None = False
 ) -> jnp.ndarray:
     """Squared-L2 against int8-quantized candidates [Bq, Bc]."""
-    if use_ref:
+    if use_reference(use_ref):
         return ref.int8_l2dist_ref(q, c_q, c_scale)
-    return int8_l2dist_pallas(q, c_q, c_scale, interpret=_on_cpu())
+    return int8_l2dist_pallas(q, c_q, c_scale, interpret=_interpret())
 
 
 __all__ = [
     "beam_merge",
+    "use_reference",
     "filter_dist",
     "filter_dist_gather",
     "filter_dist_gather_packed",

@@ -1,13 +1,17 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
-These are the ground truth for the per-kernel allclose sweeps in
-``tests/test_kernels.py`` and the fallback implementation on backends
-without Pallas support.
+These are the ground truth for the per-kernel sweeps in
+``tests/test_kernels.py`` and the search path on every backend but TPU
+(``repro.kernels.ops.use_reference``). The table arguments take the
+logical ``[n, d]`` layout or the kernels' row layout
+(``repro.kernels.layout``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.layout import gather_vectors, label_words
 
 INF = jnp.float32(jnp.inf)
 
@@ -50,7 +54,7 @@ def filter_dist_ref(
 
 
 def filter_dist_gather_ref(
-    table: jnp.ndarray,       # [n, D] full vector table (f32 or int8)
+    table: jnp.ndarray,       # [n, D] vector table (f32 or int8) or its rows
     norms: jnp.ndarray,       # [n] f32 cached ‖c‖² (of the dequantized rows)
     q: jnp.ndarray,           # [B, D] query vectors
     cand_ids: jnp.ndarray,    # [B, C] int32 candidate row ids (-1 = padding)
@@ -70,7 +74,7 @@ def filter_dist_gather_ref(
     n = table.shape[0]
     q = q.astype(jnp.float32)
     safe = jnp.clip(cand_ids, 0, n - 1)
-    cand = table[safe].astype(jnp.float32)            # [B, C, D]
+    cand = gather_vectors(table, safe, q.shape[-1])   # [B, C, D]
     cross = jnp.einsum("bd,bcd->bc", q, cand)
     if scales is not None:
         cross = cross * scales[safe]
@@ -113,8 +117,8 @@ def unpack_labels_jnp(plabels: jnp.ndarray) -> jnp.ndarray:
 
 
 def filter_dist_gather_packed_ref(
-    table: jnp.ndarray,       # [n, D] full vector table (f32 or int8)
-    plabels: jnp.ndarray,     # [n, E, 2] uint32 bit-packed label rectangles
+    table: jnp.ndarray,       # [n, D] vector table (f32 or int8) or its rows
+    plabels: jnp.ndarray,     # [n, E, 2] uint32 packed words, or their rows
     norms: jnp.ndarray,       # [n] f32 cached ‖c‖²
     q: jnp.ndarray,           # [B, D] query vectors
     cur_ids: jnp.ndarray,     # [B, M] int32 expanded beam nodes (label rows)
@@ -130,8 +134,8 @@ def filter_dist_gather_packed_ref(
     distance / visited arithmetic is bit-identical to the int32 path."""
     n = table.shape[0]
     B, M = cur_ids.shape
-    E = plabels.shape[1]
-    rows = plabels[jnp.clip(cur_ids, 0, n - 1)]       # [B, M, E, 2]
+    E = cand_ids.shape[1] // M
+    rows = label_words(plabels, jnp.clip(cur_ids, 0, n - 1), E)  # [B, M, E, 2]
     labels = unpack_labels_jnp(rows.reshape(B, M * E, 2))
     return filter_dist_gather_ref(
         table, norms, q, cand_ids, labels, state, visited, scales

@@ -26,7 +26,10 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over whatever devices exist (tests / CPU examples)."""
     n = len(jax.devices())
-    assert n % model_parallel == 0, (n, model_parallel)
+    if model_parallel < 1 or n % model_parallel:
+        raise ValueError(
+            f"{n} device(s) cannot be split into {model_parallel} shards: "
+            f"the shard count must divide the device count")
     return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 

@@ -20,6 +20,7 @@ from repro.data import (
     make_queries_vectors,
     recall_at_k,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.serve import RequestBatcher, build_sharded_index, serve_batch
 
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--Z", type=int, default=96)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"building sharded UDG: n={args.n} shards={args.shards} ...")
     vecs, s, t = make_dataset(args.n, args.dim, seed=args.seed)

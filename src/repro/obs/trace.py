@@ -8,9 +8,10 @@ and ALWAYS times the span into the ``repro_span_seconds`` histogram, so the
 same call sites feed Prometheus whether or not a trace is being captured.
 
 ``capture_trace`` wraps ``jax.profiler.start_trace``/``stop_trace`` for an
-on-demand capture window (benchmarks, incident debugging) and degrades to a
-timed no-op when the profiler backend is unavailable — callers never need
-to guard on platform.
+on-demand capture window (benchmarks, incident debugging). Off the TPU it
+degrades to a timed no-op when the profiler cannot start; on the TPU a
+trace that was asked for and did not start raises, so no caller reads an
+empty trace as a measurement.
 """
 from __future__ import annotations
 
@@ -62,28 +63,26 @@ def capture_trace(
 ) -> Iterator[bool]:
     """Capture a device trace window into ``logdir`` (view with perfetto /
     tensorboard). Yields True when a real profiler trace is running, False
-    on the degraded (timing-only) path. Either way the window's duration
-    lands in ``repro_span_seconds{span="capture_trace"}``."""
-    reg = resolve(registry)
-    started = False
-    try:
-        import jax
+    on the degraded (timing-only) path, which only a backend other than
+    the TPU may take: there a failed ``start_trace`` propagates. Either way
+    the window's duration lands in
+    ``repro_span_seconds{span="capture_trace"}``."""
+    import jax
 
+    reg = resolve(registry)
+    try:
         jax.profiler.start_trace(str(logdir))
         started = True
     except Exception:
+        if jax.default_backend() == "tpu":
+            raise
         started = False
     t0 = time.perf_counter()
     try:
         yield started
     finally:
         if started:
-            try:
-                import jax
-
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            jax.profiler.stop_trace()
         reg.histogram(
             SPAN_METRIC, "host-side span wall-clock duration"
         ).observe(time.perf_counter() - t0, span="capture_trace")

@@ -82,7 +82,7 @@ class PartialSearchInfo:
 
 
 @functools.partial(jax.jit, static_argnames=("n", "use_ref"))
-def _fold_topk(acc_d, acc_ids, cand_d, cand_ids, *, n: int, use_ref: bool):
+def _fold_topk(acc_d, acc_ids, cand_d, cand_ids, *, n: int, use_ref: bool | None):
     from repro.kernels import ops
 
     return ops.topk_merge(acc_d, acc_ids, cand_d, cand_ids,
@@ -622,7 +622,7 @@ def build_segmented_index(
     lane: int = 8,
     quantize_int8: bool = True,
     planner_buckets: int = 64,
-    use_ref: bool = True,
+    use_ref: bool | None = None,
 ) -> SegmentedIndex:
     """Partition, build all segment subgraphs concurrently, export.
 

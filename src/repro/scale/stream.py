@@ -552,7 +552,7 @@ class SegmentedStreamingIndex:
         k: int = 10,
         beam: int = 64,
         max_iters: Optional[int] = None,
-        use_ref: bool = True,
+        use_ref: bool | None = None,
         fused: bool = True,
         plan: str = "auto",
         return_partial: bool = False,

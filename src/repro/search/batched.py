@@ -64,6 +64,7 @@ import numpy as np
 
 from repro.core.predicates import get_relation
 from repro.kernels import ops
+from repro.kernels.layout import gather_vectors
 from repro.obs.stats import (
     accumulate_iteration,
     finalize_stats,
@@ -116,9 +117,10 @@ def prepare_states(
     ),
 )
 def _batched_search_core(
-    vectors: jnp.ndarray,   # [n, D] f32 (or int8 with scales)
+    vectors: jnp.ndarray,   # [n, D] f32 (or int8 with scales), or its rows
     nbr: jnp.ndarray,       # [n, E] int32
-    labels: jnp.ndarray | None,  # [n, E, 4] int32; None = label-ignoring (broad)
+    labels: jnp.ndarray | None,  # [n, E, 4] int32, packed words or label
+                                 # rows; None = label-ignoring (broad)
     q: jnp.ndarray,         # [B, D]
     states: jnp.ndarray,    # [B, 2] int32
     ep: jnp.ndarray,        # [B] int32
@@ -126,7 +128,7 @@ def _batched_search_core(
     k: int,
     beam: int,
     max_iters: int,
-    use_ref: bool,
+    use_ref: bool | None,
     fused: bool = True,
     expand: int = 1,
     unroll_iters: int = 0,
@@ -134,8 +136,8 @@ def _batched_search_core(
     norms: jnp.ndarray | None = None,    # [n] f32: cached ‖c‖² (fused path)
     stats: bool = False,  # also return a SearchStats traversal-counter pytree
 ) -> Tuple[jnp.ndarray, ...]:
-    n, D = vectors.shape
-    B = q.shape[0]
+    n = vectors.shape[0]
+    B, D = q.shape
     E = nbr.shape[1]
     L = beam
     q = q.astype(jnp.float32)
@@ -143,22 +145,22 @@ def _batched_search_core(
         raise ValueError("multi-expand (expand > 1) requires fused=True")
     if not 1 <= expand <= beam:
         raise ValueError(f"expand={expand} must be in [1, beam={beam}]")
-    if not fused and labels is not None and labels.shape[-1] == 2:
+    if not fused and labels is not None and labels.shape[-1] != 4:
         raise ValueError(
             "the unfused baseline needs the int32 [n, E, 4] label layout "
             "(pass DeviceGraph.labels_i32(), not the packed words)"
         )
 
-    def deq(rows, idx):
-        """Gathered candidate rows in f32 (dequantizing int8 storage)."""
-        out = rows.astype(jnp.float32)
+    def deq(idx):
+        """Rows ``idx`` in f32 (dequantizing int8 storage)."""
+        out = gather_vectors(vectors, idx, D)
         if scales is not None:
             out = out * scales[idx][..., None]
         return out
 
     has_ep = ep >= 0
     ep_safe = jnp.where(has_ep, ep, 0)
-    d_ep = jnp.sum((q - deq(vectors[ep_safe], ep_safe)) ** 2, axis=-1)
+    d_ep = jnp.sum((q - deq(ep_safe)) ** 2, axis=-1)
 
     beam_ids = jnp.full((B, L), -1, dtype=jnp.int32)
     beam_d = jnp.full((B, L), _INF, dtype=jnp.float32)
@@ -171,16 +173,16 @@ def _batched_search_core(
         active = jnp.any(~beam_exp_ & jnp.isfinite(beam_d_))
         return jnp.logical_and(it < max_iters, active)
 
-    # label layout is static at trace time: [n, E, 2] uint32 = bit-packed
-    # (superkernel + beam_merge pipeline), [n, E, 4] int32 = legacy layout
-    # (the parity oracle), None = broad/label-ignoring mode
-    packed = labels is not None and labels.shape[-1] == 2
+    # label layout is static at trace time: [n, E, 4] int32 = legacy layout
+    # (the parity oracle), anything else = bit-packed words or their rows
+    # (superkernel + beam_merge pipeline), None = broad/label-ignoring mode
+    packed = labels is not None and labels.shape[-1] != 4
 
     if fused:
         M = expand
         ME = M * E
         if norms is None:
-            v32 = vectors.astype(jnp.float32)
+            v32 = gather_vectors(vectors, slice(None), D)
             norms_ = jnp.sum(v32 * v32, axis=1)
             if scales is not None:
                 norms_ = norms_ * scales * scales
@@ -315,7 +317,7 @@ def _batched_search_core(
                 lb = labels[cur_safe]                   # [B, E, 4]
             nb = jnp.where(live[:, None], nb, -1)
             nb_safe = jnp.clip(nb, 0, n - 1)
-            cand_vecs = deq(vectors[nb_safe], nb_safe)   # [B, E, D] f32
+            cand_vecs = deq(nb_safe)                     # [B, E, D] f32
             # 3. fused label test + distance
             d_new = ops.filter_dist(q, cand_vecs, lb, states, nb, use_ref=use_ref)
             # 4. visited + duplicate suppression
@@ -451,7 +453,7 @@ def broad_batched_search(
     k: int,
     beam: int | None = None,
     max_iters: int | None = None,
-    use_ref: bool = True,
+    use_ref: bool | None = None,
     fused: bool = True,
     expand: int = 1,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:

@@ -90,36 +90,41 @@ def unpack_labels(plabels: np.ndarray) -> np.ndarray:
     return out
 
 
-def unpack_labels_device(plabels):
+def unpack_labels_device(plabels, edges: int | None = None):
     """jnp twin of :func:`unpack_labels` for traced/device arrays — used by
     jitted serving steps that must serve the ``fused=False`` parity
-    baseline (int32 layout) from a packed label stack. One definition of
-    the word layout, shared with the kernel oracle (lazy import keeps this
-    module importable without JAX)."""
+    baseline (int32 layout) from a packed label stack. Takes the packed
+    words ``[..., E, 2]`` or the kernels' label rows
+    (``repro.kernels.layout``), ``edges`` = E. One definition of the word
+    layout, shared with the kernel oracle (lazy import keeps this module
+    importable without JAX)."""
+    from repro.kernels.layout import label_words
     from repro.kernels.ref import unpack_labels_jnp
 
-    return unpack_labels_jnp(plabels)
+    return unpack_labels_jnp(label_words(plabels, slice(None), edges))
 
 
 @dataclasses.dataclass(frozen=True)
 class DeviceIndex:
-    """Memoized jnp views of a ``DeviceGraph``'s search-visible arrays.
+    """Memoized jnp views of a ``DeviceGraph``'s search-visible arrays,
+    in the layouts the gather kernels DMA from (``repro.kernels.layout``).
 
     ``table`` is the storage the distance kernels score (int8 ``vec_q``
-    when quantized, else f32 ``vectors``); ``labels`` is the packed
-    ``[n, E, 2]`` uint32 table when the export packed, else the int32
-    ``[n, E, 4]`` layout — the search core dispatches on the trailing dim.
+    when quantized, else f32 ``vectors``) as ``[n, 1, W]`` rows; ``labels``
+    is the packed table as ``[n, 1, 2·Ep]`` int32 label rows when the
+    export packed, else the int32 ``[n, E, 4]`` layout — the search core
+    dispatches on the trailing dim.
     """
 
-    table: object             # jnp [n, d] f32 or int8
+    table: object             # jnp [n, 1, W] f32 rows or int32 int8-words
     scales: object | None     # jnp [n] f32 (int8 storage only)
     norms: object | None      # jnp [n] f32 cached ‖v‖²
     nbr: object               # jnp [n, E] int32
-    labels: object            # jnp [n, E, 2] uint32 or [n, E, 4] int32
+    labels: object            # jnp [n, 1, 2·Ep] int32 or [n, E, 4] int32
 
     @property
     def packed(self) -> bool:
-        return self.labels.shape[-1] == 2
+        return self.labels.shape[-1] != 4
 
 
 @dataclasses.dataclass
@@ -185,18 +190,21 @@ class DeviceGraph:
         the old epoch object; ``invalidate_device()`` drops it early."""
         import jax.numpy as jnp
 
+        from repro.kernels.layout import label_rows, table_rows
+
         cache = self._cache if self._cache is not None else {}
         dev = cache.get("device")
         if dev is None:
             if self.vec_q is not None:
-                table = jnp.asarray(self.vec_q)
+                table = table_rows(self.vec_q)
                 scales = jnp.asarray(self.scales)
             else:
-                table = jnp.asarray(self.vectors)
+                table = table_rows(np.asarray(self.vectors, np.float32))
                 scales = None
-            lab = self.plabels if self.plabels is not None else self.labels
+            lab = (label_rows(self.plabels) if self.plabels is not None
+                   else self.labels)
             dev = DeviceIndex(
-                table=table,
+                table=jnp.asarray(table),
                 scales=scales,
                 norms=jnp.asarray(self.norms) if self.norms is not None else None,
                 nbr=jnp.asarray(self.nbr),
@@ -427,7 +435,7 @@ class SegmentStack:
 
     @property
     def packed(self) -> bool:
-        return bool(self._parts) and self._parts[0]["labels"].shape[-1] == 2
+        return bool(self._parts) and self._parts[0]["labels"].shape[-1] != 4
 
     @property
     def quantized(self) -> bool:
@@ -516,8 +524,8 @@ class SegmentStack:
 
             if key == "labels_i32":
                 parts = [
-                    unpack_labels_device(p["labels"])
-                    if p["labels"].shape[-1] == 2 else p["labels"]
+                    unpack_labels_device(p["labels"], self.edge_capacity)
+                    if p["labels"].shape[-1] != 4 else p["labels"]
                     for p in self._parts
                 ]
             else:

@@ -345,7 +345,7 @@ class StreamingServer:
         batch_size: int = 8,
         k: int = 10,
         beam: int = 64,
-        use_ref: bool = True,
+        use_ref: bool | None = None,
         fused: bool = True,
         plan: str = "auto",
         timeout_s: float = 0.01,

@@ -40,18 +40,30 @@ from repro.exec.executor import planned_exec_core
 from repro.obs.stats import PER_QUERY_FIELDS as _PER_QUERY_STAT_FIELDS
 from repro.obs.stats import per_query_dict
 from repro.search.batched import _batched_search_core
+from repro.kernels.layout import label_rows, table_rows
 from repro.search.device_graph import export_device_graph, unpack_labels_device
 from repro.serve.admission import validate_query
 from repro.distributed.compat import shard_map as _shard_map
 
 
-def _oracle_labels(lab, fused: bool):
+def _oracle_labels(lab, nbr, fused: bool):
     """The fused paths dispatch on the label layout; the unfused parity
     baseline needs int32 rectangles, so a packed stack is unpacked
     device-side (trace-time branch — `fused` and the layout are static)."""
-    if not fused and lab.shape[-1] == 2:
-        return unpack_labels_device(lab)
+    if not fused and lab.shape[-1] != 4:
+        return unpack_labels_device(lab, nbr.shape[-1])
     return lab
+
+
+_STACKED = ("vectors", "nbr", "labels", "norms", "U_X", "U_Y", "num_y",
+            "entry_node", "entry_y_rank")
+
+
+def _per_shard(fn, a):
+    """Apply a per-table layout function to every shard of a stack."""
+    S, n = a.shape[:2]
+    out = fn(a.reshape((S * n,) + a.shape[2:]))
+    return out.reshape((S, n) + out.shape[1:])
 
 
 @dataclasses.dataclass
@@ -84,19 +96,39 @@ class ShardedIndex:
     def num_shards(self) -> int:
         return int(self.vectors.shape[0])
 
-    def device(self) -> dict:
-        """Memoized jnp views of the stacked database arrays — the serving
-        step's inputs are staged once per index build instead of once per
-        ``serve_batch`` call (the same fix as ``DeviceGraph.device()``)."""
+    def kernel_layout(self, name: str) -> np.ndarray:
+        """Host array ``name`` in the layout the serving step consumes:
+        vectors as ``[S, n_l, 1, W]`` rows and packed labels as
+        ``[S, n_l, 1, 2·Ep]`` label rows (``repro.kernels.layout``);
+        every other array as stacked."""
+        a = getattr(self, name)
+        if name == "vectors":
+            return _per_shard(table_rows, a)
+        if name == "labels" and a.shape[-1] == 2:
+            return _per_shard(label_rows, a)
+        return a
+
+    def device(self, mesh=None) -> dict:
+        """Memoized device copies of the stacked database arrays — the
+        serving step's inputs are staged once per index build instead of
+        once per ``serve_batch`` call (the same fix as
+        ``DeviceGraph.device()``). With a ``mesh`` every array is placed
+        shard-per-device along its ``model`` axis, so each device holds its
+        own shard and nothing else; without one they go to the default
+        device."""
         cache = self._cache if self._cache is not None else {}
-        dev = cache.get("device")
+        key = ("device", None if mesh is None else id(mesh))
+        dev = cache.get(key)
         if dev is None:
+            staged = cache.get("staged", {})
+            put = (jnp.asarray if mesh is None else functools.partial(
+                jax.device_put, device=NamedSharding(mesh, P("model"))))
             dev = {
-                name: jnp.asarray(getattr(self, name))
-                for name in ("vectors", "nbr", "labels", "norms", "U_X",
-                             "U_Y", "num_y", "entry_node", "entry_y_rank")
+                name: put(staged[name] if name in staged
+                          else self.kernel_layout(name))
+                for name in _STACKED
             }
-            cache["device"] = dev
+            cache[key] = dev
             self._cache = cache
         return dev
 
@@ -249,10 +281,10 @@ def segments_to_sharded_index(segidx) -> tuple:
 
 
 def _prime_device_from_stack(sharded: ShardedIndex, segidx, *, E, lab_shape):
-    """Pre-populate the sharded device bundle from the segmented tier's
+    """Pre-stage the sharded device bundle from the segmented tier's
     flat ``SegmentStack`` — the graph topology and label table (the two
     largest components) are DERIVED from the scheduler's stacked buffers
-    on device (un-offsetting the flat adjacency, reshaping the labels)
+    on device (un-offsetting the flat adjacency, reshaping the label rows)
     instead of re-staging independent host copies. Vectors and norms still
     stage from the host stack: the sharded contract is f32 rows + f32-row
     norms, which an int8-resident stack does not carry. Skipped when the
@@ -268,20 +300,16 @@ def _prime_device_from_stack(sharded: ShardedIndex, segidx, *, E, lab_shape):
             or ncap != sharded.n_local):
         return
     flat_lab = stack.flat("labels")
-    if flat_lab.shape[-1] != lab_shape[-1]:
+    if (flat_lab.shape[-1] == 4) != (lab_shape[-1] == 4):
         return
     flat_nbr = stack.flat("nbr")
     base = (jnp.arange(S, dtype=jnp.int32) * ncap)[:, None, None]
     nbr_dev = flat_nbr.reshape(S, ncap, E)
     nbr_dev = jnp.where(nbr_dev >= 0, nbr_dev - base, jnp.int32(-1))
-    dev = {
+    sharded._cache = {"staged": {
         "nbr": nbr_dev,
-        "labels": flat_lab.reshape(lab_shape),
-    }
-    for name in ("vectors", "norms", "U_X", "U_Y", "num_y",
-                 "entry_node", "entry_y_rank"):
-        dev[name] = jnp.asarray(getattr(sharded, name))
-    sharded._cache = {"device": dev}
+        "labels": flat_lab.reshape((S, ncap) + flat_lab.shape[1:]),
+    }}
 
 
 def remap_shard_ids(id_map: np.ndarray, gids: np.ndarray) -> np.ndarray:
@@ -387,7 +415,7 @@ def make_serving_step(
     beam: int = 64,
     max_iters: int | None = None,
     merge: str = "all_gather",     # all_gather | tournament
-    use_ref_kernel: bool = True,
+    use_ref_kernel: bool | None = None,
     unroll_iters: int = 0,
     int8_vectors: bool = False,
     fused: bool = True,
@@ -417,7 +445,8 @@ def make_serving_step(
     def shard_fn(vec, nbr, lab, nrm, UX, UY, num_y, ent, enty, q, xq, yq,
                  scales=None):
         # leading shard dim is 1 on-device
-        vec, nbr, lab, nrm = vec[0], nbr[0], _oracle_labels(lab[0], fused), nrm[0]
+        vec, nbr, lab, nrm = (
+            vec[0], nbr[0], _oracle_labels(lab[0], nbr[0], fused), nrm[0])
         UX, UY, ent, enty = UX[0], UY[0], ent[0], enty[0]
         states, ep = _canonicalize_local(UX, UY, num_y[0], ent, enty, xq, yq)
         # cached norms must match the rows the kernel scores: ShardedIndex
@@ -468,7 +497,7 @@ def make_planned_serving_step(
     beam: int = 64,
     max_iters: int | None = None,
     merge: str = "all_gather",     # all_gather | tournament
-    use_ref_kernel: bool = True,
+    use_ref_kernel: bool | None = None,
     fused: bool = True,
     expand: int = 1,
     config: PlannerConfig | None = None,
@@ -494,7 +523,8 @@ def make_planned_serving_step(
 
     def shard_fn(vec, nbr, lab, nrm, UX, UY, num_y, ent, enty, q, xq, yq,
                  plans, bf_ids):
-        vec, nbr, lab, nrm = vec[0], nbr[0], _oracle_labels(lab[0], fused), nrm[0]
+        vec, nbr, lab, nrm = (
+            vec[0], nbr[0], _oracle_labels(lab[0], nbr[0], fused), nrm[0])
         UX, UY, ent, enty = UX[0], UY[0], ent[0], enty[0]
         plans, bf_ids = plans[0], bf_ids[0]
         states, ep = _canonicalize_local(UX, UY, num_y[0], ent, enty, xq, yq)
@@ -602,7 +632,7 @@ def serve_batch(
                 mesh, idx.relation, k=k, beam=beam, merge=merge, config=config
             ),
         )
-        dev = idx.device()
+        dev = idx.device(mesh)
         gids, d = step(
             dev["vectors"], dev["nbr"], dev["labels"], dev["norms"],
             dev["U_X"], dev["U_Y"], dev["num_y"], dev["entry_node"],
@@ -619,7 +649,7 @@ def serve_batch(
                 mesh, idx.relation, k=k, beam=beam, merge=merge
             ),
         )
-        dev = idx.device()
+        dev = idx.device(mesh)
         gids, d = step(
             dev["vectors"], dev["nbr"], dev["labels"], dev["norms"],
             dev["U_X"], dev["U_Y"], dev["num_y"], dev["entry_node"],
@@ -778,7 +808,7 @@ class ShardedStreamingIndex:
 
     def search(
         self, q, s_q, t_q, *, k: int = 10, beam: int = 64,
-        use_ref: bool = True, fused: bool = True, plan: str = "auto",
+        use_ref: bool | None = None, fused: bool = True, plan: str = "auto",
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Query every shard (one shared jit trace) and merge per-shard
         top-k by distance. Top-k over a union = merge of per-shard top-k.
@@ -889,7 +919,7 @@ def make_streaming_serving_step(
     k: int = 10,
     beam: int = 64,
     max_iters: int | None = None,
-    use_ref_kernel: bool = True,
+    use_ref_kernel: bool | None = None,
     fused: bool = True,
     expand: int = 1,
     stats: bool = False,
@@ -916,7 +946,8 @@ def make_streaming_serving_step(
 
     def shard_fn(vec, nbr, lab, nrm, live, ext, dvec, dlab, dids, dext,
                  UX, UY, num_y, ent, enty, q, xq, yq, dstate):
-        vec, nbr, lab, nrm = vec[0], nbr[0], _oracle_labels(lab[0], fused), nrm[0]
+        vec, nbr, lab, nrm = (
+            vec[0], nbr[0], _oracle_labels(lab[0], nbr[0], fused), nrm[0])
         live, ext = live[0], ext[0]
         dvec, dlab, dids, dext = dvec[0], dlab[0], dids[0], dext[0]
         UX, UY, ent, enty = UX[0], UY[0], ent[0], enty[0]

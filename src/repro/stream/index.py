@@ -314,6 +314,35 @@ class StreamingIndex:
             dtype=np.int64,
         )
 
+    def bulk_load(self, vecs: np.ndarray, s: np.ndarray, t: np.ndarray) -> np.ndarray:
+        """Load a batch of objects with ONE rebuild: they join the live set
+        of the next epoch directly, where ``insert_batch`` would pass them
+        through the delta tier and flush-compact every ``delta_capacity``
+        inserts. Returns their external ids. The load is not logged, so it
+        refuses an index with a WAL attached (load, snapshot, then attach).
+        """
+        vecs = np.asarray(vecs, dtype=np.float32)
+        with self._lock:
+            if self._wal is not None:
+                raise RuntimeError("bulk_load is not logged; attach the WAL "
+                                   "after loading and snapshotting")
+            job = self.begin_compaction()
+            ext = self._next_id + self._id_stride * np.arange(
+                len(vecs), dtype=np.int64)
+            self._next_id += self._id_stride * len(vecs)
+            job.vectors = np.concatenate([job.vectors, vecs], axis=0)
+            job.s = np.concatenate([job.s, np.asarray(s, np.float64)])
+            job.t = np.concatenate([job.t, np.asarray(t, np.float64)])
+            job.ext = np.concatenate([job.ext, ext])
+            try:
+                self.build_epoch(job)
+            except BaseException:
+                self._next_id -= self._id_stride * len(vecs)
+                self.abort_compaction()
+                raise
+            self.finish_compaction(job)
+        return ext
+
     def delete(self, ext_id: int) -> bool:
         """Tombstone one object. Returns False for unknown/already-deleted
         (no-op deletes are not logged)."""
@@ -714,7 +743,7 @@ class StreamingIndex:
         k: int = 10,
         beam: int = 64,
         max_iters: Optional[int] = None,
-        use_ref: bool = True,
+        use_ref: bool | None = None,
         fused: bool = True,
         plan: str = "auto",
         planner_config: Optional[PlannerConfig] = None,

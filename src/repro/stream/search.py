@@ -49,7 +49,7 @@ def two_tier_merge(
     dstate: jnp.ndarray,       # [B, 2] int32
     *,
     k: int,
-    use_ref: bool,
+    use_ref: bool | None,
     fused: bool = True,
     st: SearchStats | None = None,   # graph-tier stats to annotate
 ) -> Tuple[jnp.ndarray, ...]:
@@ -115,7 +115,7 @@ def streaming_search_core(
     k: int,
     beam: int,
     max_iters: int,
-    use_ref: bool,
+    use_ref: bool | None,
     fused: bool = True,
     norms: jnp.ndarray | None = None,   # [N] f32 cached graph-tier norms
     stats: bool = False,
@@ -164,7 +164,7 @@ def planned_streaming_search_core(
     wide_beam: int,
     max_iters: int,
     wide_max_iters: int,
-    use_ref: bool,
+    use_ref: bool | None,
     fused: bool = True,
     expand: int = 1,
     wide_expand: int = 1,

@@ -25,6 +25,19 @@ def _run(code: str, devices: int = 8, timeout: int = 600) -> str:
     return out.stdout
 
 
+@pytest.mark.parametrize("shards", [0, 2, 3])
+def test_host_mesh_rejects_shards_that_do_not_divide(shards):
+    """A shard count the devices cannot be split into is a clear error
+    (this process sees one device)."""
+    import jax
+
+    from repro.launch.mesh import make_host_mesh
+
+    assert len(jax.devices()) == 1
+    with pytest.raises(ValueError, match="cannot be split"):
+        make_host_mesh(model_parallel=shards)
+
+
 @pytest.mark.slow
 def test_sharded_serving_recall_and_id_mapping():
     out = _run(

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.layout import label_rows, table_rows
 
 RNG = np.random.default_rng(0)
 
@@ -84,10 +85,16 @@ def _gather_case(n, b, c, d, seed=0):
     (200, 4, 130, 16),    # C not a multiple of the tile
     (513, 2, 260, 32),    # multi-tile with n % 32 != 0
 ])
-def test_filter_dist_gather_matches_ref(n, b, c, d):
+@pytest.mark.parametrize("rows", [False, True])
+def test_filter_dist_gather_matches_ref(n, b, c, d, rows):
+    """Kernel vs oracle, given the logical ``[n, d]`` table or the
+    ``[n, 1, W]`` rows the kernel DMAs from (``repro.kernels.layout``)."""
     table, norms, q, ids, labels, state, vis = _gather_case(n, b, c, d)
+    if rows:
+        table = table_rows(table)
     got = np.asarray(
-        ops.filter_dist_gather(table, norms, q, ids, labels, state, vis)
+        ops.filter_dist_gather(table, norms, q, ids, labels, state, vis,
+                               use_ref=False)
     )
     want = np.asarray(
         ops.filter_dist_gather(table, norms, q, ids, labels, state, vis,
@@ -185,14 +192,19 @@ def test_filter_dist_gather_exhaustive_sweep():
                                    err_msg=str((n, b, c, d)))
 
 
-def test_filter_dist_gather_int8_scales():
+@pytest.mark.parametrize("rows", [False, True])
+def test_filter_dist_gather_int8_scales(rows):
+    """int8 storage: as a logical table, or as the int32 words (four int8
+    values each) the kernel unpacks."""
     n, b, c, d = 90, 3, 33, 16
     table, _, q, ids, labels, state, vis = _gather_case(n, b, c, d, seed=9)
     tq, sc = ops.quantize_int8(table)
     deq = tq.astype(jnp.float32) * sc[:, None]
     norms = jnp.sum(deq * deq, axis=1)
+    if rows:
+        tq = table_rows(tq)
     got = np.asarray(ops.filter_dist_gather(
-        tq, norms, q, ids, labels, state, vis, scales=sc,
+        tq, norms, q, ids, labels, state, vis, scales=sc, use_ref=False,
     ))
     want = np.asarray(ops.filter_dist_gather(
         tq, norms, q, ids, labels, state, vis, scales=sc, use_ref=True,
@@ -226,11 +238,15 @@ def _packed_case(n, b, m, e, d, seed=0, rank_hi=12):
     (200, 4, 1, 130, 16),   # M*E not a multiple of the tile
     (257, 2, 4, 65, 32),    # wide multi-expand straddling tiles
 ])
-def test_filter_dist_gather_packed_matches_ref(n, b, m, e, d):
+@pytest.mark.parametrize("rows", [False, True])
+def test_filter_dist_gather_packed_matches_ref(n, b, m, e, d, rows):
     """The packed superkernel (in-kernel label-row DMA + mask-and-shift
-    dominance test) matches its jnp oracle across tile/expand shapes."""
+    dominance test) matches its jnp oracle across tile/expand shapes,
+    given the logical tables or the row layouts the kernel DMAs from."""
     args = _packed_case(n, b, m, e, d)
-    got = np.asarray(ops.filter_dist_gather_packed(*args))
+    if rows:
+        args = (table_rows(args[0]), label_rows(args[1])) + args[2:]
+    got = np.asarray(ops.filter_dist_gather_packed(*args, use_ref=False))
     want = np.asarray(ops.filter_dist_gather_packed(*args, use_ref=True))
     fin = np.isfinite(want)
     np.testing.assert_array_equal(np.isfinite(got), fin)
@@ -314,6 +330,8 @@ def _merge_case(b, l, c, n, seed=0, tie_heavy=False, all_inf=False):
     (2, 32, 40, 40, True, False),      # heavy duplicate ids + tied dists
     (2, 16, 8, 50, False, True),       # all-inf candidate set
     (2, 96, 352, 65000, False, False), # wide-beam / multi-expand scale
+    (2, 64, 128, 1000, False, False),  # power-of-two C (no padding rows)
+    (1, 64, 96, 65536, True, False),   # B=1 at the deployment's L and E
 ])
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_beam_merge_matches_stable_sort_oracle(b, l, c, n, tie, all_inf, seed):

@@ -162,6 +162,28 @@ def test_capture_trace_degrades_gracefully(tmp_path):
     )["count"] == 1
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_capture_trace_fails_loudly_only_on_tpu(tmp_path, monkeypatch, backend):
+    """A trace that cannot start degrades to timing off the TPU; asked for
+    on the TPU it raises — no caller mistakes an empty trace for a
+    measurement."""
+    def refuse(logdir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    reg = MetricsRegistry()
+    if backend == "tpu":
+        with pytest.raises(RuntimeError, match="profiler unavailable"):
+            with capture_trace(tmp_path / "trace", reg):
+                pass
+    else:
+        with capture_trace(tmp_path / "trace", reg) as started:
+            assert started is False
+        assert reg.histogram("repro_span_seconds").summary(
+            span="capture_trace")["count"] == 1
+
+
 # --- device-side traversal counters ------------------------------------------
 
 

@@ -133,14 +133,28 @@ def test_packed_vs_int32_parity_all_relations(tiny_dataset, relation):
 
 
 def test_device_bundle_memoized(tiny_index):
+    """The bundle holds the kernels' row layouts: ``[n, 1, 2·Ep]`` int32
+    label rows and ``[n, 1, W]`` vector rows, each decoding bitwise to the
+    export's host arrays."""
+    import jax.numpy as jnp
+
+    from repro.kernels.layout import gather_vectors, label_words
+
     vecs, s, t, g, et = tiny_index
     dg = export_device_graph(g, et)
     dev = dg.device()
     assert dg.device() is dev                      # memoized
-    assert dev.packed and dev.labels.shape[-1] == 2
+    E = dg.max_degree
+    assert dev.packed and dev.labels.shape == (dg.n, 1, 2 * 128)
+    assert dev.table.shape == (dg.n, 1, 128)
     assert dev.scales is None
     np.testing.assert_array_equal(np.asarray(dev.nbr), dg.nbr)
-    np.testing.assert_array_equal(np.asarray(dev.labels), dg.plabels)
+    ids = jnp.arange(dg.n)
+    np.testing.assert_array_equal(
+        np.asarray(label_words(dev.labels, ids, E)), dg.plabels)
+    np.testing.assert_array_equal(
+        np.asarray(gather_vectors(dev.table, ids, dg.vectors.shape[1])),
+        dg.vectors)
     lab32 = dg.device_labels_i32()
     assert dg.device_labels_i32() is lab32         # memoized
     np.testing.assert_array_equal(np.asarray(lab32), dg.labels_i32())
@@ -181,8 +195,15 @@ def test_unpack_labels_device_matches_host():
 
 
 def test_device_bundle_int8_storage(tiny_index):
+    """int8 storage rides as int32 words, four values per word; the words
+    decode bitwise to the export's int8 rows."""
+    import jax.numpy as jnp
+
+    from repro.kernels.layout import gather_vectors
+
     vecs, s, t, g, et = tiny_index
     dg = export_device_graph(g, et, quantize_int8=True)
     dev = dg.device()
-    assert dev.table.dtype == np.int8 and dev.scales is not None
-    np.testing.assert_array_equal(np.asarray(dev.table), dg.vec_q)
+    assert dev.table.dtype == np.int32 and dev.scales is not None
+    got = gather_vectors(dev.table, jnp.arange(dg.n), dg.vec_q.shape[1])
+    np.testing.assert_array_equal(np.asarray(got), dg.vec_q)

@@ -668,8 +668,10 @@ def test_sharded_device_bundle_reuses_segment_stack():
     assert sharded._cache is not None  # primed at build, not first use
     dev = sharded.device()
     np.testing.assert_array_equal(np.asarray(dev["nbr"]), sharded.nbr)
-    np.testing.assert_array_equal(np.asarray(dev["labels"]), sharded.labels)
-    np.testing.assert_array_equal(np.asarray(dev["vectors"]), sharded.vectors)
+    np.testing.assert_array_equal(np.asarray(dev["labels"]),
+                                  sharded.kernel_layout("labels"))
+    np.testing.assert_array_equal(np.asarray(dev["vectors"]),
+                                  sharded.kernel_layout("vectors"))
     # id_map agrees with the stack's device-resident global-id table
     gids = np.asarray(idx.device_stack().flat("gids")).reshape(
         sharded.num_shards, sharded.n_local)

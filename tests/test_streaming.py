@@ -121,6 +121,41 @@ def test_streamed_recall_matches_rebuild_oracle(relation):
     assert r_stream >= r_rebuild - 0.01, (r_stream, r_rebuild)
 
 
+def test_bulk_load_is_one_rebuild():
+    """``bulk_load`` puts a batch past the delta capacity into ONE new epoch
+    (the delta tier stays empty), keeps what was live before, and hands
+    out ids that the index then serves and deletes like any other."""
+    vecs, s, t = _workload(n=300, seed=4)
+    idx = StreamingIndex(
+        DIM, "containment", node_capacity=512, delta_capacity=32,
+        edge_capacity=96, M=8, Z=32,
+    )
+    early = idx.insert_batch(vecs[:20], s[:20], t[:20])
+    ext = idx.bulk_load(vecs[20:], s[20:], t[20:])
+    assert idx.epoch == 1 and idx.live_count == 300
+    assert idx.delta_fraction == 0.0
+    assert len(set(ext) | set(early)) == 300
+    qv = vecs[20:24]
+    broad = np.full(4, float(s.min()) - 1.0), np.full(4, float(t.max()) + 1.0)
+    ids, _ = idx.search(qv, *broad, k=1, beam=BEAM)
+    np.testing.assert_array_equal(ids[:, 0], ext[:4])   # each finds itself
+    assert idx.delete(int(ext[0]))
+    ids, _ = idx.search(qv, *broad, k=K, beam=BEAM)
+    assert int(ext[0]) not in set(ids.ravel().tolist())
+
+
+def test_bulk_load_refuses_a_wal(tmp_path):
+    from repro.stream.wal import WriteAheadLog
+
+    vecs, s, t = _workload(n=40, seed=5)
+    idx = StreamingIndex(DIM, "containment", node_capacity=64,
+                         delta_capacity=16, edge_capacity=32, M=8, Z=32,
+                         wal=WriteAheadLog(str(tmp_path / "wal")))
+    with pytest.raises(RuntimeError, match="not logged"):
+        idx.bulk_load(vecs, s, t)
+    assert idx.live_count == 0 and idx.epoch == 0
+
+
 def test_deletes_never_resurface_across_compaction():
     vecs, s, t = _workload(n=300, seed=2)
     idx = StreamingIndex(
