@@ -24,7 +24,8 @@ Regression gates (asserted on every run, including the CI ``--tiny``
 smoke): packed recall@10 is bit-identical to the ``fused=False`` parity
 oracle at every sweep point, packed label bytes/iter are exactly one
 packed label row per expanded node (``repro.kernels.layout
-.label_row_bytes``: 10.67 B/edge at E = 96 against the int32 layout's 16),
+.label_row_bytes``: 10.67 B/edge at E = 96 or 48 against the int32
+layout's 16),
 and packed QPS >= the unpacked fused path. The full-scale run
 additionally gates the tentpole acceptance: packed ``xla_bytes_per_iter``
 <= 0.6x the fused path and packed QPS >= 1.15x fused at sigma = 0.1, and

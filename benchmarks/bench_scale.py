@@ -27,8 +27,8 @@ index on the same dataset across a beam sweep, in two workload regimes:
 
 Byte gates (both regimes share the index): ``nbytes_by_component`` sums
 exact, the device label table exactly one packed label row per node
-(``repro.kernels.layout.label_row_bytes``: 8 B/edge of payload in
-10.67 B/edge slot at E = 96), int8 resident rows exactly 4x
+(``repro.kernels.layout.label_row_bytes``: 8 B/edge of payload in a
+10.67 B/edge slot at E = 96 or 48), int8 resident rows exactly 4x
 smaller than the f32 copies, and segmented resident bytes within
 ``BYTES_FACTOR`` x the monolithic f32 index (the factor buys the uniform
 per-segment node padding that keeps every segment on ONE compiled

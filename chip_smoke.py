@@ -336,9 +336,14 @@ def four_chips(n_local: int, seed: int) -> None:
     t0 = time.perf_counter()
     idx = build_sharded_index(
         vecs, s, t, CONFIG.relation, shards,
-        build_kwargs=CONFIG.build_kwargs(pad_nodes=n_local))
+        build_kwargs=CONFIG.build_kwargs(pad_nodes=n_local),
+        edge_capacity=CONFIG.degree)
     log(phase="build", build_seconds=time.perf_counter() - t0,
-        degree=idx.nbr.shape[-1])
+        degree=idx.nbr.shape[-1], label_row_shape=idx.kernel_layout(
+            "labels").shape)
+    if idx.nbr.shape[-1] != CONFIG.degree:
+        raise AssertionError(f"shards hold degree {idx.nbr.shape[-1]}, "
+                             f"not the deployment's {CONFIG.degree}")
     mesh = make_host_mesh(model_parallel=shards)
     rec, sels = [], (0.01, 0.1, 0.5)    # 0.5: graph plans on every shard
     for i, sel in enumerate(sels):

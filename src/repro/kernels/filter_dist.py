@@ -24,8 +24,9 @@ per-candidate scale after the MXU).
 
 ``filter_dist_gather_packed_pallas`` — the *packed-metadata superkernel*
 (the serving hot path). One grid step per (query, expanded node): the tile
-is that node's ``E`` neighbors, and the node's packed label row (two 16-bit
-ranks per word, ``[n, 1, 2·Ep]`` — see ``repro.kernels.layout``) is DMA'd
+is that node's ``E`` neighbors, and the node's packed label row (two
+16-bit ranks per word, ``[n, 1, ⌈2E/128⌉·128]`` — see
+``repro.kernels.layout``) is DMA'd
 alongside the vector rows, driven by a second scalar-prefetch operand
 carrying the expanded-node ids. The dominance test unpacks the ranks with a
 mask-and-shift and compares in-register; no ``[B, M·E, 4]`` label gather
@@ -201,9 +202,10 @@ def _gather_kernel(*refs, te: int, tiles: int, packed: bool):
     a, c = st_ref[2 * b], st_ref[2 * b + 1]
     if packed:
         label_copy(r, slot).wait()
-        lab = lab_scr[slot]                           # [1, 2·Ep] int32
-        ep = lab.shape[-1] // 2
-        w0, w1 = lab[:, :te], lab[:, ep:ep + te]
+        lab = lab_scr[slot]                           # [1, W] int32
+        # word 1 sits at lanes [te, 2·te): rotate it down to lane 0
+        w1 = pltpu.roll(lab, lab.shape[-1] - te, 1)[:, :te]
+        w0 = lab[:, :te]
         label_ok = _rank_ok(w0 & 0xFFFF, _shr(w0, 16),
                             w1 & 0xFFFF, _shr(w1, 16), a, c)
     else:

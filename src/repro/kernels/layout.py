@@ -12,11 +12,14 @@ copy ``table.at[idx]`` is then one aligned ``(1, W)`` tile.
   zero-padded to ``W = ⌈d/512⌉·128`` words; the kernel unpacks the bytes
   with shifts.
 * **Packed label rows.** The ``[n, E, 2]`` uint32 rank words of
-  ``repro.search.device_graph.pack_labels`` become ``[n, 1, 2·Ep]`` int32
-  with ``Ep = ⌈E/128⌉·128``: word 0 of every edge in lanes ``[0, E)``,
-  word 1 in lanes ``[Ep, Ep + E)``. At E = 96 that is 1024 B per node,
-  10.67 B per edge (8 B of payload); :func:`label_row_bytes` gives it for
-  any E.
+  ``repro.search.device_graph.pack_labels`` become ``[n, 1, W]`` int32
+  with ``W = ⌈2E/128⌉·128``: word 0 of every edge in lanes ``[0, E)``,
+  word 1 in lanes ``[E, 2E)``, zeros after (the kernel rotates word 1
+  down to lane 0). At E = 96 that is 1,024 B per node, 10.67 B per edge
+  (8 B of payload); at E = 48 it is 512 B, again 10.67 B per edge.
+  Below E = 32 the row is larger than the 16 B per edge of the int32
+  ``[n, E, 4]`` layout, whose rows a TPU pads as well.
+  :func:`label_row_bytes` gives it for any E.
 
 Every helper takes numpy or jnp arrays (the host export builds the rows
 once; jitted callers convert a logical table in-graph) and passes an
@@ -90,32 +93,40 @@ def query_planes(q, table):
     return jnp.pad(q, ((0, 0), (0, W - d))).reshape(B, 1, W)
 
 
+def is_int32_rects(labels) -> bool:
+    """True for the unpacked int32 ``[..., E, 4]`` rank rectangles, the
+    parity-oracle layout; False for the packed words ``[..., E, 2]`` and
+    their label rows. The one test every caller dispatches on."""
+    return labels.shape[-1] == 4
+
+
 def is_label_rows(labels) -> bool:
-    """True for packed labels already in the ``[n, 1, 2·Ep]`` row layout
+    """True for packed labels already in the ``[n, 1, W]`` row layout
     (the word layout ``[n, E, 2]`` and the int32 ``[n, E, 4]`` rectangles
     have a trailing 2 or 4)."""
     return labels.shape[-1] % LANE == 0
 
 
 def label_rows(plabels):
-    """``[n, E, 2]`` uint32 packed words -> ``[n, 1, 2·Ep]`` int32 rows."""
+    """``[n, E, 2]`` uint32 packed words -> ``[n, 1, ⌈2E/128⌉·128]`` int32
+    rows, word 0 of the edges first, then word 1."""
     if is_label_rows(plabels):
         return plabels
     xp = _xp(plabels)
     n, E, _ = plabels.shape
-    ep = _round_up(E, LANE)
     if xp is np:
         w = np.ascontiguousarray(plabels, dtype=np.uint32).view(np.int32)
     else:
         w = jax.lax.bitcast_convert_type(plabels, jnp.int32)
-    w = xp.pad(w, ((0, 0), (0, ep - E), (0, 0)))        # [n, Ep, 2]
-    return xp.transpose(w, (0, 2, 1)).reshape(n, 1, 2 * ep)
+    w = xp.transpose(w, (0, 2, 1)).reshape(n, 2 * E)    # [w0 | w1]
+    W = _round_up(2 * E, LANE)
+    return xp.pad(w, ((0, 0), (0, W - 2 * E))).reshape(n, 1, W)
 
 
 def label_row_bytes(E: int) -> int:
     """Bytes of one node's packed label row for degree ``E`` (what the
     kernel DMAs per expanded node, and what each node holds in HBM)."""
-    return 2 * _round_up(E, LANE) * 4
+    return _round_up(2 * E, LANE) * 4
 
 
 def label_words(plabels, idx, E: int):
@@ -124,7 +135,6 @@ def label_words(plabels, idx, E: int):
     rows = plabels[idx]
     if not is_label_rows(plabels):
         return rows
-    ep = plabels.shape[-1] // 2
     r = rows[..., 0, :]
-    w = jnp.stack([r[..., :E], r[..., ep:ep + E]], axis=-1)
+    w = jnp.stack([r[..., :E], r[..., E:2 * E]], axis=-1)
     return jax.lax.bitcast_convert_type(w, jnp.uint32)

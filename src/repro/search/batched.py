@@ -64,7 +64,7 @@ import numpy as np
 
 from repro.core.predicates import get_relation
 from repro.kernels import ops
-from repro.kernels.layout import gather_vectors
+from repro.kernels.layout import gather_vectors, is_int32_rects
 from repro.obs.stats import (
     accumulate_iteration,
     finalize_stats,
@@ -145,7 +145,7 @@ def _batched_search_core(
         raise ValueError("multi-expand (expand > 1) requires fused=True")
     if not 1 <= expand <= beam:
         raise ValueError(f"expand={expand} must be in [1, beam={beam}]")
-    if not fused and labels is not None and labels.shape[-1] != 4:
+    if not fused and labels is not None and not is_int32_rects(labels):
         raise ValueError(
             "the unfused baseline needs the int32 [n, E, 4] label layout "
             "(pass DeviceGraph.labels_i32(), not the packed words)"
@@ -176,7 +176,7 @@ def _batched_search_core(
     # label layout is static at trace time: [n, E, 4] int32 = legacy layout
     # (the parity oracle), anything else = bit-packed words or their rows
     # (superkernel + beam_merge pipeline), None = broad/label-ignoring mode
-    packed = labels is not None and labels.shape[-1] != 4
+    packed = labels is not None and not is_int32_rects(labels)
 
     if fused:
         M = expand

@@ -111,20 +111,22 @@ class DeviceIndex:
 
     ``table`` is the storage the distance kernels score (int8 ``vec_q``
     when quantized, else f32 ``vectors``) as ``[n, 1, W]`` rows; ``labels``
-    is the packed table as ``[n, 1, 2·Ep]`` int32 label rows when the
-    export packed, else the int32 ``[n, E, 4]`` layout — the search core
-    dispatches on the trailing dim.
+    is the packed table as ``[n, 1, ⌈2E/128⌉·128]`` int32 label rows when
+    the export packed, else the int32 ``[n, E, 4]`` layout — the search
+    core dispatches on ``repro.kernels.layout.is_int32_rects``.
     """
 
     table: object             # jnp [n, 1, W] f32 rows or int32 int8-words
     scales: object | None     # jnp [n] f32 (int8 storage only)
     norms: object | None      # jnp [n] f32 cached ‖v‖²
     nbr: object               # jnp [n, E] int32
-    labels: object            # jnp [n, 1, 2·Ep] int32 or [n, E, 4] int32
+    labels: object            # jnp [n, 1, W] int32 or [n, E, 4] int32
 
     @property
     def packed(self) -> bool:
-        return self.labels.shape[-1] != 4
+        from repro.kernels.layout import is_int32_rects
+
+        return not is_int32_rects(self.labels)
 
 
 @dataclasses.dataclass
@@ -435,7 +437,10 @@ class SegmentStack:
 
     @property
     def packed(self) -> bool:
-        return bool(self._parts) and self._parts[0]["labels"].shape[-1] != 4
+        from repro.kernels.layout import is_int32_rects
+
+        return bool(self._parts) and not is_int32_rects(
+            self._parts[0]["labels"])
 
     @property
     def quantized(self) -> bool:
@@ -522,10 +527,12 @@ class SegmentStack:
         if out is None:
             import jax.numpy as jnp
 
+            from repro.kernels.layout import is_int32_rects
+
             if key == "labels_i32":
                 parts = [
-                    unpack_labels_device(p["labels"], self.edge_capacity)
-                    if p["labels"].shape[-1] != 4 else p["labels"]
+                    p["labels"] if is_int32_rects(p["labels"])
+                    else unpack_labels_device(p["labels"], self.edge_capacity)
                     for p in self._parts
                 ]
             else:

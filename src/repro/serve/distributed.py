@@ -40,7 +40,7 @@ from repro.exec.executor import planned_exec_core
 from repro.obs.stats import PER_QUERY_FIELDS as _PER_QUERY_STAT_FIELDS
 from repro.obs.stats import per_query_dict
 from repro.search.batched import _batched_search_core
-from repro.kernels.layout import label_rows, table_rows
+from repro.kernels.layout import is_int32_rects, label_rows, table_rows
 from repro.search.device_graph import export_device_graph, unpack_labels_device
 from repro.serve.admission import validate_query
 from repro.distributed.compat import shard_map as _shard_map
@@ -50,7 +50,7 @@ def _oracle_labels(lab, nbr, fused: bool):
     """The fused paths dispatch on the label layout; the unfused parity
     baseline needs int32 rectangles, so a packed stack is unpacked
     device-side (trace-time branch — `fused` and the layout are static)."""
-    if not fused and lab.shape[-1] != 4:
+    if not fused and not is_int32_rects(lab):
         return unpack_labels_device(lab, nbr.shape[-1])
     return lab
 
@@ -99,38 +99,36 @@ class ShardedIndex:
     def kernel_layout(self, name: str) -> np.ndarray:
         """Host array ``name`` in the layout the serving step consumes:
         vectors as ``[S, n_l, 1, W]`` rows and packed labels as
-        ``[S, n_l, 1, 2·Ep]`` label rows (``repro.kernels.layout``);
+        ``[S, n_l, 1, W]`` label rows (``repro.kernels.layout``);
         every other array as stacked."""
         a = getattr(self, name)
         if name == "vectors":
             return _per_shard(table_rows, a)
-        if name == "labels" and a.shape[-1] == 2:
+        if name == "labels" and not is_int32_rects(a):
             return _per_shard(label_rows, a)
         return a
 
-    def device(self, mesh=None) -> dict:
-        """Memoized device copies of the stacked database arrays — the
-        serving step's inputs are staged once per index build instead of
-        once per ``serve_batch`` call (the same fix as
-        ``DeviceGraph.device()``). With a ``mesh`` every array is placed
-        shard-per-device along its ``model`` axis, so each device holds its
-        own shard and nothing else; without one they go to the default
-        device."""
+    def device(self, mesh) -> dict:
+        """Memoized device copies of the stacked database arrays, each
+        placed shard-per-device along ``mesh``'s ``model`` axis, so every
+        device holds its own shard and nothing else. The serving step's
+        inputs are staged once per index build (and mesh) instead of once
+        per ``serve_batch`` call, the same fix as ``DeviceGraph.device()``.
+        """
         cache = self._cache if self._cache is not None else {}
-        key = ("device", None if mesh is None else id(mesh))
-        dev = cache.get(key)
-        if dev is None:
+        held = cache.get("device")
+        if held is None or held[0] != mesh:
             staged = cache.get("staged", {})
-            put = (jnp.asarray if mesh is None else functools.partial(
-                jax.device_put, device=NamedSharding(mesh, P("model"))))
-            dev = {
+            put = functools.partial(
+                jax.device_put, device=NamedSharding(mesh, P("model")))
+            held = (mesh, {
                 name: put(staged[name] if name in staged
                           else self.kernel_layout(name))
                 for name in _STACKED
-            }
-            cache[key] = dev
+            })
+            cache["device"] = held
             self._cache = cache
-        return dev
+        return held[1]
 
     def invalidate_device(self) -> None:
         self._cache = None
@@ -147,12 +145,17 @@ def build_sharded_index(
     Z: int = 128,
     K_p: int = 8,
     build_kwargs: dict | None = None,
+    edge_capacity: int | None = None,
 ) -> ShardedIndex:
     """Partition the database round-robin and build one UDG per shard.
 
     ``build_kwargs`` forwards extra ``build_udg`` options — pass
     ``UdgServeConfig.build_kwargs()`` to select the batched wave
     constructor with shard-capacity padding for production shard sizes.
+    ``edge_capacity`` fixes every shard's labeled degree (pass
+    ``UdgServeConfig.degree``, the deployment's padded degree): wider rows
+    keep their earliest tuples, as in ``export_device_graph``. ``None``
+    keeps the widest shard's degree.
     """
     n = vectors.shape[0]
     assert n % num_shards == 0, (n, num_shards)
@@ -162,7 +165,8 @@ def build_sharded_index(
     for ids in parts:
         g, _ = build_udg(vectors[ids], s[ids], t[ids], relation, M=M, Z=Z,
                          K_p=K_p, **(build_kwargs or {}))
-        dgs.append(export_device_graph(g, EntryTable(g)))
+        dgs.append(export_device_graph(g, EntryTable(g),
+                                       edge_capacity=edge_capacity))
     planners = [dg.planner for dg in dgs]
     E = max(dg.max_degree for dg in dgs)
     ux = max(dg.U_X.shape[0] for dg in dgs)
@@ -276,11 +280,11 @@ def segments_to_sharded_index(segidx) -> tuple:
         relation=segidx.relation.name, n_local=n_l,
         planners=planners,
     )
-    _prime_device_from_stack(sharded, segidx, E=E, lab_shape=lab.shape)
+    _prime_device_from_stack(sharded, segidx, E=E)
     return sharded, id_map
 
 
-def _prime_device_from_stack(sharded: ShardedIndex, segidx, *, E, lab_shape):
+def _prime_device_from_stack(sharded: ShardedIndex, segidx, *, E):
     """Pre-stage the sharded device bundle from the segmented tier's
     flat ``SegmentStack`` — the graph topology and label table (the two
     largest components) are DERIVED from the scheduler's stacked buffers
@@ -300,7 +304,7 @@ def _prime_device_from_stack(sharded: ShardedIndex, segidx, *, E, lab_shape):
             or ncap != sharded.n_local):
         return
     flat_lab = stack.flat("labels")
-    if (flat_lab.shape[-1] == 4) != (lab_shape[-1] == 4):
+    if is_int32_rects(flat_lab) != is_int32_rects(sharded.labels):
         return
     flat_nbr = stack.flat("nbr")
     base = (jnp.arange(S, dtype=jnp.int32) * ncap)[:, None, None]

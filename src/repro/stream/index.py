@@ -316,31 +316,22 @@ class StreamingIndex:
 
     def bulk_load(self, vecs: np.ndarray, s: np.ndarray, t: np.ndarray) -> np.ndarray:
         """Load a batch of objects with ONE rebuild: they join the live set
-        of the next epoch directly, where ``insert_batch`` would pass them
-        through the delta tier and flush-compact every ``delta_capacity``
-        inserts. Returns their external ids. The load is not logged, so it
-        refuses an index with a WAL attached (load, snapshot, then attach).
+        of the next epoch directly (a :meth:`compact` that adds them to its
+        snapshot), where ``insert_batch`` would pass them through the delta
+        tier and flush-compact every ``delta_capacity`` inserts. Returns
+        their external ids; a failed build leaves those ids unused. The
+        load is not logged, so it refuses an index with a WAL attached
+        (load, snapshot, then attach).
         """
         vecs = np.asarray(vecs, dtype=np.float32)
         with self._lock:
             if self._wal is not None:
                 raise RuntimeError("bulk_load is not logged; attach the WAL "
                                    "after loading and snapshotting")
-            job = self.begin_compaction()
             ext = self._next_id + self._id_stride * np.arange(
                 len(vecs), dtype=np.int64)
             self._next_id += self._id_stride * len(vecs)
-            job.vectors = np.concatenate([job.vectors, vecs], axis=0)
-            job.s = np.concatenate([job.s, np.asarray(s, np.float64)])
-            job.t = np.concatenate([job.t, np.asarray(t, np.float64)])
-            job.ext = np.concatenate([job.ext, ext])
-            try:
-                self.build_epoch(job)
-            except BaseException:
-                self._next_id -= self._id_stride * len(vecs)
-                self.abort_compaction()
-                raise
-            self.finish_compaction(job)
+        self.compact(load=(vecs, s, t, ext))
         return ext
 
     def delete(self, ext_id: int) -> bool:
@@ -717,9 +708,17 @@ class StreamingIndex:
             self._job_active = False
             self._pending_deletes = []
 
-    def compact(self) -> CompactionReport:
-        """Synchronous compaction: snapshot, rebuild, swap."""
+    def compact(self, *, load=None) -> CompactionReport:
+        """Synchronous compaction: snapshot, rebuild, swap. ``load`` —
+        ``(vectors, s, t, ext)`` of objects not yet in the index — joins
+        the snapshot, so they enter the new epoch's graph directly."""
         job = self.begin_compaction()
+        if load is not None:
+            vecs, s, t, ext = load
+            job.vectors = np.concatenate([job.vectors, vecs], axis=0)
+            job.s = np.concatenate([job.s, np.asarray(s, np.float64)])
+            job.t = np.concatenate([job.t, np.asarray(t, np.float64)])
+            job.ext = np.concatenate([job.ext, ext])
         try:
             self.build_epoch(job)
         except BaseException:

@@ -38,6 +38,32 @@ def test_host_mesh_rejects_shards_that_do_not_divide(shards):
         make_host_mesh(model_parallel=shards)
 
 
+@pytest.mark.parametrize("cap", [8, 64])
+def test_build_sharded_index_fixes_edge_capacity(cap):
+    """``edge_capacity`` gives every shard the same labeled degree: wider
+    rows keep their earliest tuples, narrower ones are padded; the device
+    stack carries the matching label rows."""
+    import numpy as np
+
+    from repro.data import make_dataset
+    from repro.launch.mesh import make_host_mesh
+    from repro.serve import build_sharded_index
+
+    vecs, s, t = make_dataset(256, 8, seed=3)
+    free = build_sharded_index(vecs, s, t, "overlap", 2, M=8, Z=32)
+    idx = build_sharded_index(vecs, s, t, "overlap", 2, M=8, Z=32,
+                              edge_capacity=cap)
+    assert idx.nbr.shape == (2, 128, cap)
+    assert idx.labels.shape[:3] == (2, 128, cap)
+    w = min(cap, free.nbr.shape[-1])
+    np.testing.assert_array_equal(idx.nbr[..., :w], free.nbr[..., :w])
+    np.testing.assert_array_equal(idx.labels[..., :w, :],
+                                  free.labels[..., :w, :])
+    assert (idx.nbr[..., w:] == -1).all()
+    dev = idx.device(make_host_mesh(1))
+    assert dev["labels"].shape == (2, 128, 1, -(-2 * cap // 128) * 128)
+
+
 @pytest.mark.slow
 def test_sharded_serving_recall_and_id_mapping():
     out = _run(

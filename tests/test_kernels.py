@@ -237,6 +237,7 @@ def _packed_case(n, b, m, e, d, seed=0, rank_hi=12):
     (100, 3, 2, 12, 7),     # odd D, multi-expand label rows
     (200, 4, 1, 130, 16),   # M*E not a multiple of the tile
     (257, 2, 4, 65, 32),    # wide multi-expand straddling tiles
+    (300, 2, 2, 96, 24),    # deployment degree: word 1 straddles a lane tile
 ])
 @pytest.mark.parametrize("rows", [False, True])
 def test_filter_dist_gather_packed_matches_ref(n, b, m, e, d, rows):

@@ -132,9 +132,34 @@ def test_packed_vs_int32_parity_all_relations(tiny_dataset, relation):
     assert recall_at_k(packed, qs) == recall_at_k(unfused, qs)
 
 
+@pytest.mark.parametrize("E", [1, 48, 64, 96, 128, 130])
+def test_label_rows_round_trip(E):
+    """``label_rows`` lays word 0 then word 1 of every edge into one
+    ``⌈2E/128⌉·128``-lane row, identically from numpy and jnp, and
+    ``label_words`` reads the words back bitwise."""
+    import jax.numpy as jnp
+
+    from repro.kernels.layout import label_row_bytes, label_rows, label_words
+
+    rng = np.random.default_rng(E)
+    p = rng.integers(0, 2 ** 32, size=(5, E, 2), dtype=np.uint64).astype(
+        np.uint32)
+    rows = label_rows(p)
+    W = -(-2 * E // 128) * 128
+    assert rows.shape == (5, 1, W) and rows.dtype == np.int32
+    assert label_row_bytes(E) == W * 4
+    np.testing.assert_array_equal(rows[:, 0, :E], p[..., 0].view(np.int32))
+    np.testing.assert_array_equal(rows[:, 0, E:2 * E],
+                                  p[..., 1].view(np.int32))
+    assert not rows[:, 0, 2 * E:].any()
+    np.testing.assert_array_equal(np.asarray(label_rows(jnp.asarray(p))), rows)
+    np.testing.assert_array_equal(
+        np.asarray(label_words(jnp.asarray(rows), jnp.arange(5), E)), p)
+
+
 def test_device_bundle_memoized(tiny_index):
-    """The bundle holds the kernels' row layouts: ``[n, 1, 2·Ep]`` int32
-    label rows and ``[n, 1, W]`` vector rows, each decoding bitwise to the
+    """The bundle holds the kernels' row layouts: ``[n, 1, ⌈2E/128⌉·128]``
+    int32 label rows and ``[n, 1, W]`` vector rows, each decoding bitwise to the
     export's host arrays."""
     import jax.numpy as jnp
 
@@ -145,7 +170,8 @@ def test_device_bundle_memoized(tiny_index):
     dev = dg.device()
     assert dg.device() is dev                      # memoized
     E = dg.max_degree
-    assert dev.packed and dev.labels.shape == (dg.n, 1, 2 * 128)
+    assert dev.packed
+    assert dev.labels.shape == (dg.n, 1, -(-2 * E // 128) * 128)
     assert dev.table.shape == (dg.n, 1, 128)
     assert dev.scales is None
     np.testing.assert_array_equal(np.asarray(dev.nbr), dg.nbr)

@@ -666,7 +666,9 @@ def test_sharded_device_bundle_reuses_segment_stack():
 
     sharded, id_map = segments_to_sharded_index(idx)
     assert sharded._cache is not None  # primed at build, not first use
-    dev = sharded.device()
+    from repro.launch.mesh import make_host_mesh
+
+    dev = sharded.device(make_host_mesh(1))
     np.testing.assert_array_equal(np.asarray(dev["nbr"]), sharded.nbr)
     np.testing.assert_array_equal(np.asarray(dev["labels"]),
                                   sharded.kernel_layout("labels"))

@@ -101,6 +101,22 @@ def test_packed_gather_kernel_compiles(one_chip, d, dtype, M, B):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("deg", [32, 48, 64])
+def test_packed_gather_kernel_compiles_below_one_lane_tile(one_chip, deg):
+    """Degrees whose two label words share one 128-lane row: word 1 starts
+    inside the tile and the kernel rotates it down."""
+    S = _spec(one_chip)
+    B, M, d = 8, 4, 768
+    C = M * deg
+    text = _compiled_text(
+        filter_dist_gather_packed_pallas,
+        _table(S, d, "f32"), S((N, 1, LANE), jnp.int32),
+        S((B, d), jnp.float32), S((B, M), jnp.int32), S((B, C), jnp.int32),
+        S((B, 2), jnp.int32), S((B, C), jnp.float32), S((B, C), jnp.uint32),
+        S((B, C), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("d,dtype,M,B", GATHER_CASES)
 def test_gather_kernel_compiles(one_chip, d, dtype, M, B):
     S = _spec(one_chip)
