@@ -201,8 +201,9 @@ def _serving_loop(*, n, dim, n_requests, batch_size, tiny) -> dict:
         "request_latency_p90_ms": round(lat["p90"] * 1e3, 3),
         "request_latency_p99_ms": round(lat["p99"] * 1e3, 3),
         "mean_batch_occupancy": round(occ["sum"] / max(occ["count"], 1), 2),
-        "search_iterations_total": samples.get(
-            "repro_search_iterations_total", 0.0),
+        "search_iterations_total": sum(
+            v for key, v in samples.items()
+            if key.startswith("repro_search_iterations_total{")),
         "delta_candidates_total": samples.get(
             "repro_search_delta_candidates_valid_total", 0.0),
         "export_series": len(samples),

@@ -81,7 +81,9 @@ def planned_exec_core(
 ) -> Tuple[jnp.ndarray, ...]:
     """All three strategies in one traced program + per-row plan select.
 
-    ``stats=True`` appends a merged :class:`repro.obs.SearchStats`: each
+    Returns ``(ids [B, k], dists [B, k], totals i32[2, 4])``: ``totals``
+    holds the always-on ``LOOP_TOTALS`` of the graph loop (row 0) and of
+    the wide loop (row 1). ``stats=True`` appends a merged :class:`repro.obs.SearchStats`: each
     graph instantiation sees rows planned elsewhere as masked (ep=-1 →
     zero iterations → exact-zero counters), so the two stats pytrees merge
     by addition; ``BRUTE_VALID`` rows do no traversal and stay all-zero
@@ -128,6 +130,7 @@ def _planned_exec_impl(
     )
     ids_g, d_g = out_g[0], out_g[1]
     ids_w, d_w = out_w[0], out_w[1]
+    totals = jnp.stack([out_g[2], out_w[2]])
     nrm = effective_norms(vectors, q.shape[1], scales, norms)
     ids_b, d_b = brute_topk_impl(
         vectors, nrm, q.astype(jnp.float32), bf_ids,
@@ -143,8 +146,8 @@ def _planned_exec_impl(
         jnp.where(sel == int(QueryPlan.GRAPH_WIDE), d_w, d_b),
     )
     if stats:
-        return ids, d, combine_stats(out_g[2], out_w[2])
-    return ids, d
+        return ids, d, totals, combine_stats(out_g[3], out_w[3])
+    return ids, d, totals
 
 
 def planned_exec_cache_size() -> int:
@@ -245,7 +248,7 @@ def worklist_exec_core(
         n=n_sentinel, use_ref=use_ref,
     )
     if stats:
-        st = out[2]
+        st = out[3]
 
         def scat(v):
             return jnp.zeros(B, dtype=jnp.int32).at[qid].add(
@@ -262,8 +265,6 @@ def worklist_exec_core(
             beam_occupancy=scat(st.beam_occupancy),
             hit_max_iters=scat(st.hit_max_iters) > 0,
             delta_valid=scat(st.delta_valid),
-            hop_valid=st.hop_valid,
-            hop_total=st.hop_total,
         )
         return ids, d, st_b
     return ids, d
@@ -392,5 +393,5 @@ def execute_batch(
     if return_plans:
         ret += (pb,)
     if stats:
-        ret += (stats_to_host(out[2]),)
+        ret += (stats_to_host(out[3]),)
     return ret

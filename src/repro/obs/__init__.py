@@ -6,9 +6,10 @@ The layer every serving surface reports through (see
 
   * ``repro.obs.metrics`` — counters / gauges / fixed-bucket histograms
     with p50/p90/p99 summaries, one process-default registry;
-  * ``repro.obs.stats`` — the ``SearchStats`` pytree the jitted search
-    cores optionally emit (``stats=True``), plus the host-side bridge
-    (``record_search_stats``) into the registry;
+  * ``repro.obs.stats`` — the always-on loop totals every jitted search
+    core returns and the ``SearchStats`` pytree it optionally emits
+    (``stats=True``), plus the host-side bridges (``record_loop_totals``,
+    ``record_search_stats``) into the registry;
   * ``repro.obs.export`` — Prometheus text exposition, JSON snapshots,
     file writers and a daemon-thread HTTP endpoint;
   * ``repro.obs.trace`` — ``trace_span`` / ``capture_trace`` profiling
@@ -44,6 +45,7 @@ from repro.obs.stats import (
     combine_stats,
     init_search_stats,
     per_query_dict,
+    record_loop_totals,
     record_search_stats,
     stats_to_host,
 )
@@ -66,6 +68,7 @@ __all__ = [
     "json_snapshot",
     "parse_prometheus_text",
     "per_query_dict",
+    "record_loop_totals",
     "record_search_stats",
     "resolve",
     "start_metrics_server",

@@ -230,6 +230,11 @@ class MetricsRegistry:
         self._metrics: Dict[str, _Metric] = {}
 
     def _get(self, cls, name: str, help: str, **kw) -> _Metric:
+        # the hot path reads without the lock (one dict read is atomic);
+        # creating a family, or a type clash, takes it
+        m = self._metrics.get(name)
+        if type(m) is cls:
+            return m
         with self._lock:
             m = self._metrics.get(name)
             if m is None:
@@ -251,6 +256,15 @@ class MetricsRegistry:
         buckets: Sequence[float] = LATENCY_BUCKETS_S,
     ) -> Histogram:
         return self._get(Histogram, name, help, buckets=buckets)
+
+    def inc_counters(
+        self, updates: Iterable[Tuple[str, str, float, Dict[str, str]]],
+    ) -> None:
+        """Increment several counters, each ``(name, help, value,
+        labels)``, under one acquisition of the lock."""
+        with self._lock:
+            for name, help, value, labels in updates:
+                self.counter(name, help).inc(value, **labels)
 
     def collect(self) -> List[_Metric]:
         """Stable-ordered snapshot of every registered family."""

@@ -1,14 +1,31 @@
-"""Device-side traversal counters: a stats pytree for the jitted searches.
+"""Device-side traversal counters of the jitted searches, at two depths.
 
-``SearchStats`` is an optional extra output of ``_batched_search_core``,
-``planned_exec_core`` and the streaming/sharded serving steps, computed
-*inside* the jitted loop from values the loop already carries (the live
-mask, the kernel's candidate distances, the dedup keep mask, the visited
-bitmap) — no extra gathers, no host sync per iteration. ``stats=False``
-(the default everywhere) compiles to exactly the jaxpr the search had
-before this module existed; ``stats=True`` is a second jit cache entry
-whose shapes are all fixed by (B, beam, max_iters), so it never recompiles
-across epoch swaps or plan mixes.
+**Always-on loop totals.** Every ``_batched_search_core`` returns, beside
+its ids and distances, one fixed ``i32[4]`` vector of batch totals of its
+padded loop (:data:`LOOP_TOTALS`): ``row_slots`` (the loop's trips × B),
+``row_iterations`` (Σ over rows of the trips in which the row expanded
+at least one entry), ``candidate_slots`` (trips × B × M·E: the candidate
+rows handed to the gather, useful or not) and ``kept`` (candidates that
+passed the predicate and visited tests and entered the beam merge: the
+dedup keep mask, summed). The slot counts come from the loop's static
+shapes inside the trace; the loop itself pays one ``[B]`` add and one
+scalar sum of the keep mask per iteration, so the served program carries
+the totals at every ``stats`` setting: one compiled program, no second
+one for the totals. (Counting ``kept`` after the loop instead, as the
+visited bitmap's population less the entry bits, keeps the bitmap alive
+past the loop, and at d = 128 that moved XLA's placement of the vector
+table out of VMEM: the gather kernel ran 1.5× slower on a TPU v5e.)
+:func:`record_loop_totals` folds them into the registry under a ``plan``
+label.
+
+**Per-query detail (``stats=True``).** ``SearchStats`` is an optional
+extra output of ``_batched_search_core``, ``planned_exec_core`` and the
+streaming/sharded serving steps, computed *inside* the jitted loop from
+values the loop already carries (the live mask, the kernel's candidate
+distances, the dedup keep mask, the visited bitmap) — no extra gathers,
+no host sync per iteration. ``stats=True`` is a second jit cache entry
+whose shapes are all fixed by (B, beam), so it never recompiles across
+epoch swaps or plan mixes.
 
 Counting semantics (pinned against a Python re-execution of the beam
 search in ``tests/test_obs.py``):
@@ -34,11 +51,11 @@ search in ``tests/test_obs.py``):
                         converged, or the valid set was empty and the
                         query never started);
   * ``delta_valid[b]``  streaming only: delta-tier candidates passing the
-                        filter (zeros for pure graph searches);
-  * ``hop_valid/hop_total[h]``  batch-summed valid/examined candidates at
-                        hop ``h`` — the per-hop valid-candidate fraction
-                        that shows a restrictive filter starving the beam
-                        (the failure mode patch edges exist to fix).
+                        filter (zeros for pure graph searches).
+
+The loop totals are sums of these: ``row_iterations`` = Σ ``iters``,
+``kept`` = Σ ``kept`` and ``row_slots`` = B × max ``iters`` (every trip
+has a live row), pinned in ``tests/test_obs.py``.
 """
 from __future__ import annotations
 
@@ -57,7 +74,7 @@ from repro.obs.metrics import (
 
 
 class SearchStats(NamedTuple):
-    """Per-query traversal counters (+ batch-summed per-hop tallies).
+    """Per-query traversal counters, every field ``[B]``.
 
     A NamedTuple of arrays, hence a pytree: it flows through ``jit``,
     ``shard_map`` and host conversion unchanged."""
@@ -71,27 +88,29 @@ class SearchStats(NamedTuple):
     beam_occupancy: jnp.ndarray  # [B] i32
     hit_max_iters: jnp.ndarray   # [B] bool
     delta_valid: jnp.ndarray     # [B] i32
-    hop_valid: jnp.ndarray       # [H] i32 (H = max_iters)
-    hop_total: jnp.ndarray       # [H] i32
 
-
-# [B]-shaped fields (everything except the hop tallies) — the portion the
-# sharded serving steps psum across shards and return per query.
-PER_QUERY_FIELDS = (
-    "iters", "expanded", "cand_total", "cand_valid", "kept", "visited",
-    "beam_occupancy", "hit_max_iters", "delta_valid",
+# the always-on batch totals of one padded search loop, in vector order,
+# and the registry counter each is folded into (label ``plan``)
+LOOP_TOTALS = ("row_slots", "row_iterations", "candidate_slots", "kept")
+LOOP_COUNTERS = (
+    ("repro_search_row_slots_total",
+     "row slots of a padded search loop (trips x B)"),
+    ("repro_search_iterations_total",
+     "row slots in which the row expanded an entry"),
+    ("repro_search_candidate_slots_total",
+     "candidate rows handed to the gather (trips x B x M*E)"),
+    ("repro_search_candidates_kept_total",
+     "candidates that passed both tests and entered the merge"),
 )
 
 
-def init_search_stats(B: int, max_iters: int) -> SearchStats:
-    """All-zero counters for a batch of ``B`` and an ``[max_iters]`` hop axis."""
+def init_search_stats(B: int) -> SearchStats:
+    """All-zero counters for a batch of ``B``."""
     zi = jnp.zeros(B, dtype=jnp.int32)
     return SearchStats(
         iters=zi, expanded=zi, cand_total=zi, cand_valid=zi, kept=zi,
         visited=zi, beam_occupancy=zi,
         hit_max_iters=jnp.zeros(B, dtype=bool), delta_valid=zi,
-        hop_valid=jnp.zeros(max_iters, dtype=jnp.int32),
-        hop_total=jnp.zeros(max_iters, dtype=jnp.int32),
     )
 
 
@@ -102,7 +121,6 @@ def accumulate_iteration(
     nb: jnp.ndarray,      # [B, M*E] i32 — candidate ids (-1 = padding)
     d_new: jnp.ndarray,   # [B, M*E] f32 — kernel distances (inf = filtered)
     keep: jnp.ndarray,    # [B, M*E] bool — dedup survivors
-    it: jnp.ndarray,      # scalar i32 — current hop index
 ) -> SearchStats:
     """Fold one loop iteration's masks into the counters (trace-time)."""
     exp = jnp.sum(live.astype(jnp.int32), axis=1)
@@ -115,9 +133,22 @@ def accumulate_iteration(
         cand_total=st.cand_total + tot,
         cand_valid=st.cand_valid + val,
         kept=st.kept + kp,
-        hop_valid=st.hop_valid.at[it].add(jnp.sum(val)),
-        hop_total=st.hop_total.at[it].add(jnp.sum(tot)),
     )
+
+
+def loop_totals(
+    trips: jnp.ndarray,       # scalar i32 — the loop's final iteration count
+    row_iters: jnp.ndarray,   # [B] i32 — trips in which the row expanded
+    kept: jnp.ndarray,        # scalar i32 — Σ of the keep mask over trips
+    *,
+    width: int,               # candidate slots per row and trip (M·E)
+) -> jnp.ndarray:
+    """The ``i32[4]`` :data:`LOOP_TOTALS` of one loop (trace-time); int32
+    holds them while trips·B·M·E < 2^31."""
+    row_slots = trips * row_iters.shape[0]
+    return jnp.stack(
+        [row_slots, jnp.sum(row_iters), row_slots * width, kept]
+    ).astype(jnp.int32)
 
 
 def finalize_stats(
@@ -145,13 +176,7 @@ def finalize_stats(
 def combine_stats(a: SearchStats, b: SearchStats) -> SearchStats:
     """Elementwise merge of two instantiations serving DISJOINT row sets
     (the planner's graph + wide searches: a row masked out of one search
-    contributes exact zeros there, so addition is selection). Hop tallies
-    are zero-padded to the longer iteration axis."""
-    H = max(a.hop_valid.shape[0], b.hop_valid.shape[0])
-
-    def pad(x):
-        return jnp.pad(x, (0, H - x.shape[0]))
-
+    contributes exact zeros there, so addition is selection)."""
     return SearchStats(
         iters=a.iters + b.iters,
         expanded=a.expanded + b.expanded,
@@ -162,8 +187,6 @@ def combine_stats(a: SearchStats, b: SearchStats) -> SearchStats:
         beam_occupancy=a.beam_occupancy + b.beam_occupancy,
         hit_max_iters=a.hit_max_iters | b.hit_max_iters,
         delta_valid=a.delta_valid + b.delta_valid,
-        hop_valid=pad(a.hop_valid) + pad(b.hop_valid),
-        hop_total=pad(a.hop_total) + pad(b.hop_total),
     )
 
 
@@ -173,12 +196,28 @@ def stats_to_host(st: SearchStats) -> SearchStats:
 
 
 def per_query_dict(st: SearchStats) -> dict:
-    """The [B]-shaped fields as {name: array} — the sharded steps' stats
-    output (hop tallies are single-host only)."""
+    """The fields as {name: i32 array} — the sharded steps' stats output."""
     return {
         name: getattr(st, name).astype(jnp.int32)
-        for name in PER_QUERY_FIELDS
+        for name in SearchStats._fields
     }
+
+
+def record_loop_totals(
+    totals,
+    *,
+    plans,
+    registry: Optional[MetricsRegistry] = None,
+) -> None:
+    """Fold the always-on totals of one batch into the registry, in one
+    locked update: ``totals`` is ``[P, 4]`` (host or device), one
+    :data:`LOOP_TOTALS` row per padded loop, labelled ``plans[p]``."""
+    totals = np.asarray(totals).reshape(len(plans), len(LOOP_TOTALS))
+    resolve(registry).inc_counters(
+        (name, help, float(v), {"plan": plan})
+        for row, plan in zip(totals, plans)
+        for (name, help), v in zip(LOOP_COUNTERS, row)
+    )
 
 
 def record_search_stats(
@@ -187,12 +226,15 @@ def record_search_stats(
     registry: Optional[MetricsRegistry] = None,
     n_real: Optional[int] = None,
 ) -> None:
-    """Fold one batch's device counters into the host metrics registry.
+    """Fold one batch's per-query device counters into the host metrics
+    registry.
 
     ``st`` is a ``SearchStats`` (host or device arrays) or the sharded
     steps' ``per_query_dict``. ``n_real`` truncates to the first rows when
     the batch carries sentinel padding (``RequestBatcher``) so no-op rows
-    don't dilute the per-query histograms."""
+    don't dilute the per-query histograms. The iteration and kept totals
+    are not counted here: the always-on loop totals count them
+    (:func:`record_loop_totals`)."""
     reg = resolve(registry)
     get = (st.get if isinstance(st, dict) else
            lambda name, default=None: getattr(st, name, default))
@@ -214,11 +256,9 @@ def record_search_stats(
         "repro_search_queries_total", "queries with device counters recorded"
     ).inc(int(iters.size))
     for name, v in (
-        ("repro_search_iterations_total", iters),
         ("repro_search_nodes_expanded_total", expanded),
         ("repro_search_candidates_examined_total", cand_total),
         ("repro_search_candidates_valid_total", cand_valid),
-        ("repro_search_candidates_kept_total", col("kept")),
         ("repro_search_delta_candidates_valid_total", col("delta_valid")),
     ):
         if v is not None:

@@ -24,13 +24,10 @@ from repro.obs.metrics import MetricsRegistry, resolve
 SPAN_METRIC = "repro_span_seconds"
 
 
-def _trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` or None when unavailable."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:
-        return None
+try:
+    from jax.profiler import TraceAnnotation as _TraceAnnotation
+except Exception:       # profiler unavailable: spans are timed only
+    _TraceAnnotation = None
 
 
 @contextlib.contextmanager
@@ -40,20 +37,22 @@ def trace_span(
     **labels: str,
 ) -> Iterator[None]:
     """Time a host-side span into ``repro_span_seconds{span=name,...}``,
-    annotating the profiler timeline when one is attached."""
+    annotating the profiler timeline when one is attached. The histogram
+    update happens inside the annotation, so nested spans tile their
+    parent with no bookkeeping left between them."""
     reg = resolve(registry)
-    ann = _trace_annotation(name)
-    t0 = time.perf_counter()
+    ann = _TraceAnnotation(name) if _TraceAnnotation is not None else None
     if ann is not None:
         ann.__enter__()
+    t0 = time.perf_counter()
     try:
         yield
     finally:
-        if ann is not None:
-            ann.__exit__(None, None, None)
         reg.histogram(
             SPAN_METRIC, "host-side span wall-clock duration"
         ).observe(time.perf_counter() - t0, span=name, **labels)
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 
 @contextlib.contextmanager

@@ -408,8 +408,7 @@ class SegmentedIndex:
             st = None
             if stats:
                 if acc_st is None:
-                    mi = max_iters if max_iters is not None else 2 * beam_eff
-                    acc_st = init_search_stats(B, mi * cfg.wide_beam_scale)
+                    acc_st = init_search_stats(B)
                 st = stats_to_host(acc_st)
         if rerank:
             ids, d = self._rerank_exact(q, ids, d, k)
@@ -505,7 +504,7 @@ class SegmentedIndex:
             # with NO device dispatch (pinned by the dispatch-count test)
             ids = np.full((B, fetch), -1, dtype=np.int32)
             d = np.full((B, fetch), np.inf, dtype=np.float32)
-            st = (stats_to_host(init_search_stats(B, wide_mi))
+            st = (stats_to_host(init_search_stats(B))
                   if stats else None)
             return ids, d, st
 

@@ -69,6 +69,7 @@ from repro.obs.stats import (
     accumulate_iteration,
     finalize_stats,
     init_search_stats,
+    loop_totals,
     stats_to_host,
 )
 from repro.search.device_graph import DeviceGraph
@@ -136,6 +137,9 @@ def _batched_search_core(
     norms: jnp.ndarray | None = None,    # [n] f32: cached ‖c‖² (fused path)
     stats: bool = False,  # also return a SearchStats traversal-counter pytree
 ) -> Tuple[jnp.ndarray, ...]:
+    """Returns ``(ids [B, k], dists [B, k], totals i32[4])`` — ``totals``
+    are the loop's always-on ``repro.obs.stats.LOOP_TOTALS`` — and, with
+    ``stats=True``, a :class:`repro.obs.SearchStats` last."""
     n = vectors.shape[0]
     B, D = q.shape
     E = nbr.shape[1]
@@ -198,7 +202,8 @@ def _batched_search_core(
         visited = visited.at[jnp.arange(B), ep_safe >> 5].add(ep_bit)
 
         def body(carry):
-            beam_ids_, beam_d_, beam_exp_, visited_, it = carry[:5]
+            beam_ids_, beam_d_, beam_exp_, visited_, it, rows_it, kept_ = (
+                carry[:7])
             # 1. best M unexpanded entries per query
             cand_d = jnp.where(beam_exp_, _INF, beam_d_)
             if M == 1:
@@ -243,11 +248,12 @@ def _batched_search_core(
                     jnp.uint32(0),
                 )
                 visited_ = visited_.at[rows, ids_safe >> 5].add(bits)
-                out = (beam_ids_, beam_d_, beam_exp_, visited_, it + 1)
+                out = (beam_ids_, beam_d_, beam_exp_, visited_, it + 1,
+                       rows_it + jnp.any(live, axis=1).astype(jnp.int32),
+                       kept_ + jnp.sum(keep.astype(jnp.int32)))
                 if stats:
                     out += (accumulate_iteration(
-                        carry[5], live=live, nb=nb, d_new=d_new, keep=keep,
-                        it=it,
+                        carry[7], live=live, nb=nb, d_new=d_new, keep=keep,
                     ),)
                 return out
             if labels is None:
@@ -289,10 +295,12 @@ def _batched_search_core(
                 (all_d, all_ids, all_exp), dimension=1, num_keys=1,
                 is_stable=True,
             )
-            out = (si[:, :L], sd[:, :L], se[:, :L], visited_, it + 1)
+            out = (si[:, :L], sd[:, :L], se[:, :L], visited_, it + 1,
+                   rows_it + jnp.any(live, axis=1).astype(jnp.int32),
+                   kept_ + jnp.sum(keep.astype(jnp.int32)))
             if stats:
                 out += (accumulate_iteration(
-                    carry[5], live=live, nb=nb, d_new=d_new, keep=keep, it=it,
+                    carry[7], live=live, nb=nb, d_new=d_new, keep=keep,
                 ),)
             return out
 
@@ -301,7 +309,8 @@ def _batched_search_core(
         visited = visited.at[jnp.arange(B), ep_safe].max(has_ep)
 
         def body(carry):
-            beam_ids_, beam_d_, beam_exp_, visited_, it = carry[:5]
+            beam_ids_, beam_d_, beam_exp_, visited_, it, rows_it, kept_ = (
+                carry[:7])
             # 1. best unexpanded entry per query
             cand_d = jnp.where(beam_exp_, _INF, beam_d_)
             j = jnp.argmin(cand_d, axis=1)
@@ -343,17 +352,21 @@ def _batched_search_core(
             sd, si, se = jax.lax.sort(
                 (all_d, all_ids, all_exp), dimension=1, num_keys=1, is_stable=True
             )
-            out = (si[:, :L], sd[:, :L], se[:, :L], visited_, it + 1)
+            out = (si[:, :L], sd[:, :L], se[:, :L], visited_, it + 1,
+                   rows_it + live.astype(jnp.int32),
+                   kept_ + jnp.sum(keep.astype(jnp.int32)))
             if stats:
                 out += (accumulate_iteration(
-                    carry[5], live=live[:, None], nb=nb, d_new=d_new,
-                    keep=keep, it=it,
+                    carry[7], live=live[:, None], nb=nb, d_new=d_new,
+                    keep=keep,
                 ),)
             return out
 
-    carry = (beam_ids, beam_d, beam_exp, visited, jnp.int32(0))
+    # the always-on totals' carry: trips (it), row iterations, kept
+    carry = (beam_ids, beam_d, beam_exp, visited, jnp.int32(0),
+             jnp.zeros(B, dtype=jnp.int32), jnp.int32(0))
     if stats:
-        carry += (init_search_stats(B, max_iters),)
+        carry += (init_search_stats(B),)
     if unroll_iters > 0:
         # cost-probe mode: a fixed number of python-unrolled expansions so
         # HLO cost analysis sees per-iteration work (a while body is counted
@@ -362,13 +375,14 @@ def _batched_search_core(
             carry = body(carry)
     else:
         carry = jax.lax.while_loop(cond, body, carry)
-    beam_ids, beam_d, beam_exp, visited = carry[:4]
+    beam_ids, beam_d, beam_exp, visited, trips, rows_it, kept = carry[:7]
+    out = (beam_ids[:, :k], beam_d[:, :k],
+           loop_totals(trips, rows_it, kept, width=expand * E))
     if stats:
-        st = finalize_stats(
-            carry[5], beam_d=beam_d, beam_exp=beam_exp, visited=visited
-        )
-        return beam_ids[:, :k], beam_d[:, :k], st
-    return beam_ids[:, :k], beam_d[:, :k]
+        out += (finalize_stats(
+            carry[7], beam_d=beam_d, beam_exp=beam_exp, visited=visited
+        ),)
+    return out
 
 
 def batched_udg_search(
@@ -439,7 +453,7 @@ def batched_udg_search(
     )
     ids, d = out[0], out[1]
     if stats:
-        return np.asarray(ids), np.asarray(d), stats_to_host(out[2])
+        return np.asarray(ids), np.asarray(d), stats_to_host(out[3])
     return np.asarray(ids), np.asarray(d)
 
 
@@ -471,7 +485,7 @@ def broad_batched_search(
     B = q.shape[0]
     L = beam if beam is not None else k
     states = jnp.zeros((B, 2), dtype=jnp.int32)
-    return _batched_search_core(
+    ids, d, _ = _batched_search_core(
         table,
         nbr,
         None,
@@ -486,3 +500,4 @@ def broad_batched_search(
         expand=expand,
         norms=norms,
     )
+    return ids, d

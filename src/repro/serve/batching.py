@@ -332,6 +332,13 @@ class StreamingServer:
     exactly one epoch — the swap replaces whole-epoch references under the
     index lock).
 
+    Every served step folds the search loops' always-on totals into the
+    server's registry and is timed as a ``serve_step`` span holding seven
+    disjoint phase spans, in order: ``serve_step.batch`` (admission level
+    and batch formation), the index's ``.canonicalize`` / ``.plan`` /
+    ``.upload`` / ``.dispatch`` / ``.fetch``, and ``serve_step.reply``
+    (latency histogram, epoch gauges, the result dict).
+
     ``stats=True`` asks the index for the device-side ``SearchStats`` on
     every step and folds the real (non-sentinel) rows into the metrics
     registry — a second jit cache entry, exercised once, then stable across
@@ -420,55 +427,61 @@ class StreamingServer:
         """Drain one batch; returns {req_id: (ext_ids [k], dists [k])}.
         ``force=True`` flushes a partial batch before its timeout."""
         with trace_span("serve_step", self._reg):
-            # degradation ladder: pick the execution strategy from queue
-            # pressure BEFORE draining (the batch about to form is part of
-            # the backlog being measured). Every rung reuses an
-            # already-compiled program — recompiling at peak load would be
-            # self-inflicted overload.
-            plan, planner_config = self.plan, None
-            if self.admission is not None and self.plan == "auto":
-                lvl = self.admission.level(self.batcher.pending)
-                if lvl == 1:
-                    planner_config = self._degraded_config
-                elif lvl == 2:
-                    plan = "graph"
-                if lvl:
-                    self._reg.counter(
-                        "repro_degraded_batches_total",
-                        "batches served under an overload degradation rung",
-                    ).inc(level=str(lvl))
-            batch = self.batcher.next_batch(force=force)
+            with trace_span("serve_step.batch", self._reg):
+                # degradation ladder: pick the execution strategy from
+                # queue pressure BEFORE draining (the batch about to form
+                # is part of the backlog being measured). Every rung reuses
+                # an already-compiled program — recompiling at peak load
+                # would be self-inflicted overload.
+                plan, planner_config = self.plan, None
+                if self.admission is not None and self.plan == "auto":
+                    lvl = self.admission.level(self.batcher.pending)
+                    if lvl == 1:
+                        planner_config = self._degraded_config
+                    elif lvl == 2:
+                        plan = "graph"
+                    if lvl:
+                        self._reg.counter(
+                            "repro_degraded_batches_total",
+                            "batches served under an overload degradation "
+                            "rung",
+                        ).inc(level=str(lvl))
+                batch = self.batcher.next_batch(force=force)
             if batch is None:
                 self._observe_epoch()
                 return {}
             q, s_q, t_q, req_ids, n_real = batch
             t_exec = time.monotonic()
+            # canonicalize / plan / upload / dispatch / fetch spans inside
             out = self.index.search(
                 q, s_q, t_q, k=self.k, beam=self.beam, use_ref=self.use_ref,
                 fused=self.fused, plan=plan, planner_config=planner_config,
-                return_stats=self.stats,
+                return_stats=self.stats, registry=self._reg,
             )
-            if self.admission is not None:
-                # feed the shedding forecast with real batch service times
-                self.admission.observe_batch(time.monotonic() - t_exec)
-            if self.stats:
-                ids, d, st = out
-                record_search_stats(st, registry=self._reg, n_real=n_real)
-            else:
-                ids, d = out
-            now = time.monotonic()
-            lat = self._reg.histogram(
-                "repro_request_latency_seconds",
-                "submit-to-result latency per request",
-                buckets=LATENCY_BUCKETS_S,
-            )
-            lat.observe_many(
-                now - t for t in self.batcher.last_submit_times[:n_real]
-            )
-            self._observe_epoch()
-            return {
-                rid: (ids[i], d[i]) for i, rid in enumerate(req_ids[:n_real])
-            }
+            with trace_span("serve_step.reply", self._reg):
+                if self.admission is not None:
+                    # feed the shedding forecast with real batch service
+                    # times
+                    self.admission.observe_batch(time.monotonic() - t_exec)
+                if self.stats:
+                    ids, d, st = out
+                    record_search_stats(st, registry=self._reg, n_real=n_real)
+                else:
+                    ids, d = out
+                now = time.monotonic()
+                lat = self._reg.histogram(
+                    "repro_request_latency_seconds",
+                    "submit-to-result latency per request",
+                    buckets=LATENCY_BUCKETS_S,
+                )
+                lat.observe_many(
+                    now - t for t in self.batcher.last_submit_times[:n_real]
+                )
+                self._observe_epoch()
+                return {
+                    rid: (ids[i], d[i])
+                    for i, rid in enumerate(req_ids[:n_real])
+                }
 
     def drain(self) -> Dict[int, Tuple[np.ndarray, np.ndarray]]:
         out: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}

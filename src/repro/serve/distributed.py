@@ -37,8 +37,7 @@ from repro.exec import (
     plan_queries,
 )
 from repro.exec.executor import planned_exec_core
-from repro.obs.stats import PER_QUERY_FIELDS as _PER_QUERY_STAT_FIELDS
-from repro.obs.stats import per_query_dict
+from repro.obs.stats import SearchStats, per_query_dict
 from repro.search.batched import _batched_search_core
 from repro.kernels.layout import is_int32_rects, label_rows, table_rows
 from repro.search.device_graph import export_device_graph, unpack_labels_device
@@ -474,7 +473,7 @@ def make_serving_step(
         if stats:
             pq = {
                 name: jax.lax.psum(v, "model")
-                for name, v in per_query_dict(out[2]).items()
+                for name, v in per_query_dict(out[3]).items()
             }
             return merged + (pq,)
         return merged
@@ -487,7 +486,7 @@ def make_serving_step(
     out_specs = (qspec, qspec)
     if stats:
         out_specs = out_specs + (
-            {name: qspec for name in _PER_QUERY_STAT_FIELDS},
+            {name: qspec for name in SearchStats._fields},
         )
     fn = _shard_map(shard_fn, mesh, in_specs, out_specs)
     return jax.jit(fn)
@@ -534,7 +533,7 @@ def make_planned_serving_step(
         states, ep = _canonicalize_local(UX, UY, num_y[0], ent, enty, xq, yq)
         ep_graph = jnp.where(plans == int(QueryPlan.GRAPH), ep, -1)
         ep_wide = jnp.where(plans == int(QueryPlan.GRAPH_WIDE), ep, -1)
-        ids_l, d_l = planned_exec_core(
+        ids_l, d_l, _ = planned_exec_core(
             vec, nbr, lab, q.astype(jnp.float32), states,
             ep_graph, ep_wide, bf_ids, plans,
             k=k, beam=beam, wide_beam=wide_beam,
@@ -966,7 +965,7 @@ def make_streaming_serving_step(
         merged = two_tier_merge(
             ids_l, d_l, live, ext, q32, dvec, dlab, dids, dext, dstate,
             k=k, use_ref=use_ref_kernel, fused=fused,
-            st=core[2] if stats else None,
+            st=core[3] if stats else None,
         )
         i_k, d_k = merged[0], merged[1]
         B = q.shape[0]
@@ -989,7 +988,7 @@ def make_streaming_serving_step(
     out_specs = (qspec, qspec)
     if stats:
         out_specs = out_specs + (
-            {name: qspec for name in _PER_QUERY_STAT_FIELDS},
+            {name: qspec for name in SearchStats._fields},
         )
     fn = _shard_map(shard_fn, mesh, in_specs, out_specs)
     return jax.jit(fn)

@@ -27,6 +27,7 @@ import threading
 import time
 from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -35,11 +36,14 @@ from repro.core.entry import EntryTable
 from repro.core.predicates import get_relation
 from repro.exec import (
     PlannerConfig,
+    QueryPlan,
     default_planner_config,
     mask_entry_points,
     plan_queries,
 )
-from repro.obs.stats import stats_to_host
+from repro.obs.metrics import MetricsRegistry
+from repro.obs.stats import record_loop_totals
+from repro.obs.trace import trace_span
 from repro.search.batched import prepare_states_extended
 from repro.search.device_graph import (
     RANK_LIMIT,
@@ -747,6 +751,7 @@ class StreamingIndex:
         plan: str = "auto",
         planner_config: Optional[PlannerConfig] = None,
         return_stats: bool = False,
+        registry: Optional[MetricsRegistry] = None,
     ) -> Tuple[np.ndarray, ...]:
         """Two-tier search; returns (external ids [B, k], sq dists [B, k]),
         -1 padded. A 1-D query vector is treated as a batch of one.
@@ -760,93 +765,105 @@ class StreamingIndex:
         pre-planner behavior (parity oracle); ``plan="wide"`` forces the
         widened beam. The planner state (rank-space histogram) is rebuilt
         with each compacted epoch; the delta tier is scanned brute-force
-        either way, so delta-resident objects never depend on the plan."""
+        either way, so delta-resident objects never depend on the plan.
+
+        Every call folds the padded loops' always-on totals into
+        ``registry`` (``record_loop_totals``, label ``plan`` = ``GRAPH`` /
+        ``GRAPH_WIDE``), fetched with the results in one transfer, and
+        times its phases as ``serve_step.canonicalize`` / ``.plan`` /
+        ``.upload`` / ``.dispatch`` / ``.fetch`` spans: the served step's
+        names, which a direct call outside ``StreamingServer.step``
+        records too."""
         if plan not in ("auto", "graph", "wide"):
             raise ValueError(f"plan={plan!r} not in ('auto', 'graph', 'wide')")
-        q = np.asarray(q, dtype=np.float32)
-        single = q.ndim == 1
-        if single:
-            q = q[None]
-            s_q = np.asarray([s_q], dtype=np.float64)
-            t_q = np.asarray([t_q], dtype=np.float64)
-        else:
-            s_q = np.asarray(s_q, dtype=np.float64)
-            t_q = np.asarray(t_q, dtype=np.float64)
         if k > beam:
             raise ValueError(f"k={k} > beam={beam}")
 
-        with self._lock:
-            # consistent snapshot of one epoch: the DeviceGraph's memoized
-            # .device() bundle is swapped as a unit (a fresh graph — and a
-            # fresh bundle — is published by finish_compaction); mutable
-            # masks/delta are uploaded once per mutation (the cache is
-            # invalidated by insert/delete/epoch swap) so read-heavy
-            # serving doesn't re-transfer full-capacity buffers.
-            dg = self._dg
-            didx = dg.device()
-            dev = (didx.table, didx.nbr, dg.serving_labels(fused=fused))
-            dev_norms = didx.norms
-            if self._dev_mut is None:
-                live = self._graph_live.copy()
-                ext = np.where(live, self._graph_ext, -1).astype(np.int32)
-                seg = self._delta.device_segment()
-                self._dev_mut = (
-                    jnp.asarray(live), jnp.asarray(ext),
-                    jnp.asarray(seg.vectors), jnp.asarray(seg.labels),
-                    jnp.asarray(seg.slot_ids), jnp.asarray(seg.ext_ids),
-                )
-            mut = self._dev_mut
+        with trace_span("serve_step.canonicalize", registry):
+            q = np.asarray(q, dtype=np.float32)
+            single = q.ndim == 1
+            if single:
+                q = q[None]
+                s_q = np.asarray([s_q], dtype=np.float64)
+                t_q = np.asarray([t_q], dtype=np.float64)
+            else:
+                s_q = np.asarray(s_q, dtype=np.float64)
+                t_q = np.asarray(t_q, dtype=np.float64)
+            with self._lock:
+                # consistent snapshot of one epoch: the DeviceGraph's
+                # memoized .device() bundle is swapped as a unit (a fresh
+                # graph — and a fresh bundle — is published by
+                # finish_compaction); mutable masks/delta are uploaded once
+                # per mutation (the cache is invalidated by insert/delete/
+                # epoch swap) so read-heavy serving doesn't re-transfer
+                # full-capacity buffers.
+                dg = self._dg
+                didx = dg.device()
+                dev = (didx.table, didx.nbr, dg.serving_labels(fused=fused))
+                dev_norms = didx.norms
+                if self._dev_mut is None:
+                    live = self._graph_live.copy()
+                    ext = np.where(live, self._graph_ext, -1).astype(np.int32)
+                    seg = self._delta.device_segment()
+                    self._dev_mut = (
+                        jnp.asarray(live), jnp.asarray(ext),
+                        jnp.asarray(seg.vectors), jnp.asarray(seg.labels),
+                        jnp.asarray(seg.slot_ids), jnp.asarray(seg.ext_ids),
+                    )
+                mut = self._dev_mut
+            states, ep, invalid = _graph_states(dg, s_q, t_q)
+            dstate = query_key_state(self._rel, s_q, t_q)
 
-        states, ep, invalid = _graph_states(dg, s_q, t_q)
-        dstate = query_key_state(self._rel, s_q, t_q)
         mi = max_iters if max_iters is not None else 2 * beam
-        if plan == "graph":
-            out = streaming_search_core(
-                dev[0], dev[1], dev[2], *mut,
-                jnp.asarray(q), jnp.asarray(states), jnp.asarray(ep),
-                jnp.asarray(dstate),
-                k=k, beam=beam, max_iters=mi,
-                use_ref=use_ref, fused=fused, norms=dev_norms,
-                stats=return_stats,
-            )
-        else:
-            cfg = planner_config or default_planner_config()
-            if plan == "wide":
-                # forced wide needs only the invalid mask — skip the
-                # estimator pass (and its brute-id enumeration) entirely
-                from repro.exec import QueryPlan
+        with trace_span("serve_step.plan", registry):
+            if plan == "graph":
+                loops = ("GRAPH",)
+                host = (q, states, ep, dstate)
+            else:
+                cfg = planner_config or default_planner_config()
+                loops = ("GRAPH", "GRAPH_WIDE")
+                if plan == "wide":
+                    # forced wide needs only the invalid mask — skip the
+                    # estimator pass (and its brute-id enumeration) entirely
+                    plans = np.where(
+                        invalid, np.int32(QueryPlan.BRUTE_VALID),
+                        np.int32(QueryPlan.GRAPH_WIDE),
+                    ).astype(np.int32)
+                    bf_ids = np.full(
+                        (states.shape[0], cfg.brute_max_valid), -1, np.int32
+                    )
+                else:
+                    pb = plan_queries(dg.planner, states, invalid, config=cfg)
+                    plans, bf_ids = pb.plans, pb.bf_ids
+                ep_graph, ep_wide = mask_entry_points(ep, plans)
+                host = (q, states, ep_graph, ep_wide, bf_ids, plans, dstate)
 
-                plans = np.where(
-                    invalid, np.int32(QueryPlan.BRUTE_VALID),
-                    np.int32(QueryPlan.GRAPH_WIDE),
-                ).astype(np.int32)
-                bf_ids = np.full(
-                    (states.shape[0], cfg.brute_max_valid), -1, np.int32
+        with trace_span("serve_step.upload", registry):
+            args = jax.device_put(host)
+
+        with trace_span("serve_step.dispatch", registry):
+            if plan == "graph":
+                out = streaming_search_core(
+                    dev[0], dev[1], dev[2], *mut, *args,
+                    k=k, beam=beam, max_iters=mi,
+                    use_ref=use_ref, fused=fused, norms=dev_norms,
+                    stats=return_stats,
                 )
             else:
-                pb = plan_queries(dg.planner, states, invalid, config=cfg)
-                plans, bf_ids = pb.plans, pb.bf_ids
-            ep_graph, ep_wide = mask_entry_points(ep, plans)
-            wide_beam = max(beam * cfg.wide_beam_scale, beam)
-            out = planned_streaming_search_core(
-                dev[0], dev[1], dev[2], *mut,
-                jnp.asarray(q), jnp.asarray(states),
-                jnp.asarray(ep_graph), jnp.asarray(ep_wide),
-                jnp.asarray(bf_ids), jnp.asarray(plans),
-                jnp.asarray(dstate),
-                k=k, beam=beam, wide_beam=wide_beam,
-                max_iters=mi, wide_max_iters=mi * cfg.wide_beam_scale,
-                use_ref=use_ref, fused=fused,
-                wide_expand=cfg.wide_expand if fused else 1,
-                norms=dev_norms, stats=return_stats,
-            )
-        ids = np.asarray(out[0])
-        d = np.asarray(out[1])
-        if return_stats:
-            st = stats_to_host(out[2])
-            if single:
-                return ids[0], d[0], st
-            return ids, d, st
+                wide_beam = max(beam * cfg.wide_beam_scale, beam)
+                out = planned_streaming_search_core(
+                    dev[0], dev[1], dev[2], *mut, *args,
+                    k=k, beam=beam, wide_beam=wide_beam,
+                    max_iters=mi, wide_max_iters=mi * cfg.wide_beam_scale,
+                    use_ref=use_ref, fused=fused,
+                    wide_expand=cfg.wide_expand if fused else 1,
+                    norms=dev_norms, stats=return_stats,
+                )
+
+        with trace_span("serve_step.fetch", registry):
+            ids, d, totals, *st = jax.device_get(out)
+            del out, args       # release the batch's device buffers here
+            record_loop_totals(totals, plans=loops, registry=registry)
         if single:
-            return ids[0], d[0]
-        return ids, d
+            ids, d = ids[0], d[0]
+        return (ids, d, *st)

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from repro.exec.executor import planned_exec_core
 from repro.kernels import ops
-from repro.obs.stats import SearchStats
+from repro.obs.stats import LOOP_TOTALS, SearchStats
 from repro.search.batched import _batched_search_core
 
 
@@ -127,11 +127,12 @@ def streaming_search_core(
         fused=fused, norms=norms, stats=stats,
     )
     ids_g, d_g = out[0], out[1]
-    return two_tier_merge(
+    merged = two_tier_merge(
         ids_g, d_g, live, ext_ids, q, dvec, dlab, dids, dext, dstate,
         k=k, use_ref=use_ref, fused=fused,
-        st=out[2] if stats else None,
+        st=out[3] if stats else None,
     )
+    return merged[:2] + (out[2].reshape(-1, len(LOOP_TOTALS)),) + merged[2:]
 
 
 @functools.partial(
@@ -173,6 +174,11 @@ def planned_streaming_search_core(
 ) -> Tuple[jnp.ndarray, ...]:
     """Planner-routed variant of :func:`streaming_search_core`.
 
+    Both return ``(ext ids [B, k], dists [B, k], totals i32[P, 4])`` —
+    ``totals`` the always-on ``LOOP_TOTALS`` of each padded loop, P = 1
+    here (``GRAPH``) and 2 in the planned step (``GRAPH``, ``GRAPH_WIDE``)
+    — and, with ``stats=True``, a :class:`repro.obs.SearchStats` last.
+
     The graph tier runs through the three-way planned executor (graph /
     wide / brute-valid, padding-dispatched — one compiled program for any
     plan mix); the delta scan and tombstone-masked merge are unchanged.
@@ -188,11 +194,12 @@ def planned_streaming_search_core(
         wide_expand=wide_expand, norms=norms, stats=stats,
     )
     ids_g, d_g = out[0], out[1]
-    return two_tier_merge(
+    merged = two_tier_merge(
         ids_g, d_g, live, ext_ids, q, dvec, dlab, dids, dext, dstate,
         k=k, use_ref=use_ref, fused=fused,
-        st=out[2] if stats else None,
+        st=out[3] if stats else None,
     )
+    return merged[:2] + (out[2].reshape(-1, len(LOOP_TOTALS)),) + merged[2:]
 
 
 def streaming_search_cache_size() -> int:

@@ -175,9 +175,9 @@ def test_int8_search_path_recall(setup, query_vectors):
     )
     states, ep = prepare_states(dg, qs.s_q, qs.t_q)
     vq, sc = quantize_int8(jnp.asarray(dg.vectors))
-    ids, _ = _batched_search_core(
+    ids = _batched_search_core(
         vq, jnp.asarray(dg.nbr), jnp.asarray(dg.labels_i32()),
         jnp.asarray(qs.vectors), jnp.asarray(states), jnp.asarray(ep),
         k=10, beam=64, max_iters=128, use_ref=True, scales=sc,
-    )
+    )[0]
     assert recall_at_k(np.asarray(ids), qs) >= 0.95

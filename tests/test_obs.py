@@ -32,6 +32,7 @@ from repro.obs import (
     write_prometheus,
 )
 from repro.search import batched_udg_search, export_device_graph, prepare_states
+from repro.obs.stats import LOOP_TOTALS
 from repro.search.batched import _batched_search_core
 
 
@@ -56,6 +57,36 @@ def test_counter_gauge_basics():
     assert reg.counter("x_total") is c
     with pytest.raises(TypeError):
         reg.gauge("x_total")
+
+
+def test_inc_counters_one_locked_update():
+    """Several counters, labelled or new, in one acquisition of the
+    registry's lock (the series updates inside re-enter it)."""
+    reg = MetricsRegistry()
+    reg.counter("a_total").inc(1, plan="GRAPH")
+    taken = []
+    lock = reg._lock
+
+    class Counted:
+        def __enter__(self):
+            taken.append(1)
+            return lock.__enter__()
+
+        def __exit__(self, *exc):
+            return lock.__exit__(*exc)
+
+    reg._lock = Counted()
+    reg.inc_counters([("a_total", "", 2.0, {"plan": "GRAPH"}),
+                      ("a_total", "", 1.0, {"plan": "GRAPH_WIDE"})])
+    assert len(taken) == 1
+    reg._lock = lock
+    reg.inc_counters([("b_total", "created here", 3.0, {})])
+    assert reg.counter("a_total").value(plan="GRAPH") == 3.0
+    assert reg.counter("a_total").value(plan="GRAPH_WIDE") == 1.0
+    assert reg.counter("b_total").value() == 3.0
+    reg.histogram("h_seconds")
+    with pytest.raises(TypeError):
+        reg.inc_counters([("h_seconds", "", 1.0, {})])
 
 
 def test_histogram_percentiles_exact_on_single_value():
@@ -279,10 +310,6 @@ def test_stats_exact_vs_python_oracle(obs_setup, fused):
             )
         assert bool(st.hit_max_iters[b]) == oracle[b]["hit_max_iters"], b
         assert int(st.delta_valid[b]) == 0
-    # hop tallies partition the totals
-    assert int(st.hop_total.sum()) == int(st.cand_total.sum())
-    assert int(st.hop_valid.sum()) == int(st.cand_valid.sum())
-    assert st.hop_total.shape == (max_iters,)
 
 
 def test_stats_results_identical_and_packed_parity(obs_setup):
@@ -328,35 +355,38 @@ def test_no_entry_rows_contribute_exact_zeros(obs_setup):
 
 
 def test_stats_false_jaxpr_has_no_stats_outputs(obs_setup):
-    """The guard for 'stats=False compiles to the pre-obs program': exactly
-    the two historical outputs, and no hop-axis arrays anywhere in the
-    jaxpr; stats=True appends exactly the SearchStats leaves."""
+    """The guard for 'stats=False carries only the fixed-size totals':
+    ids, distances and the ``i32[4]`` loop totals, and nothing
+    ``[B]``-per-query beyond them; stats=True appends exactly the
+    per-query SearchStats leaves, each ``[B]``."""
     vecs, s, t, dg = obs_setup
     rng = np.random.default_rng(5)
-    q = rng.standard_normal((4, vecs.shape[1])).astype(np.float32)
-    s_q = rng.uniform(s.min(), s.max(), 4)
+    B, k = 4, 4
+    q = rng.standard_normal((B, vecs.shape[1])).astype(np.float32)
+    s_q = rng.uniform(s.min(), s.max(), B)
     t_q = s_q + 0.5
     states, ep = prepare_states(dg, s_q, t_q)
     dev = dg.device()
     labels = dg.serving_labels(fused=True)
-    max_iters = 37   # distinctive: no other axis in the program is 37
     args = (dev.table, dev.nbr, labels, jnp.asarray(q),
             jnp.asarray(states), jnp.asarray(ep))
 
-    def run(stats):
-        return jax.make_jaxpr(
+    def shapes(stats):
+        jaxpr = jax.make_jaxpr(
             lambda *a: _batched_search_core(
-                *a, k=4, beam=8, max_iters=max_iters, use_ref=True,
+                *a, k=k, beam=8, max_iters=37, use_ref=True,
                 norms=dev.norms, stats=stats,
             )
         )(*args)
+        return [(str(a.dtype), a.shape) for a in jaxpr.out_avals]
 
-    off = run(False)
-    assert len(off.out_avals) == 2
-    assert f"i32[{max_iters}]" not in str(off)
-    on = run(True)
-    assert len(on.out_avals) == 2 + len(SearchStats._fields)
-    assert f"i32[{max_iters}]" in str(on)
+    off = shapes(False)
+    assert off == [("int32", (B, k)), ("float32", (B, k)),
+                   ("int32", (len(LOOP_TOTALS),))]
+    on = shapes(True)
+    assert on[:3] == off
+    assert len(on) == 3 + len(SearchStats._fields)
+    assert all(shape == (B,) for _, shape in on[3:])
 
 
 def test_planned_exec_stats_rows(obs_setup):
@@ -400,22 +430,25 @@ def test_planned_exec_stats_rows(obs_setup):
         )
 
 
-def test_combine_stats_pads_hop_axes():
-    a = SearchStats(*(jnp.ones(2, jnp.int32) for _ in range(7)),
-                    jnp.zeros(2, bool), jnp.ones(2, jnp.int32),
-                    jnp.ones(3, jnp.int32), jnp.ones(3, jnp.int32))
-    b = SearchStats(*(jnp.ones(2, jnp.int32) for _ in range(7)),
-                    jnp.ones(2, bool), jnp.ones(2, jnp.int32),
-                    jnp.ones(5, jnp.int32), jnp.ones(5, jnp.int32))
+def test_combine_stats_adds_per_query_fields():
+    """Two instantiations over disjoint rows merge field by field: counts
+    add, the iteration-cap flag ORs, and every field stays ``[B]``."""
+    a = SearchStats(*(jnp.arange(3, dtype=jnp.int32) for _ in range(7)),
+                    jnp.array([False, True, False]),
+                    jnp.ones(3, jnp.int32))
+    b = SearchStats(*(jnp.full(3, 2, jnp.int32) for _ in range(7)),
+                    jnp.array([False, False, True]),
+                    jnp.zeros(3, jnp.int32))
     m = combine_stats(a, b)
-    assert m.hop_total.shape == (5,)
-    np.testing.assert_array_equal(
-        np.asarray(m.hop_total), [2, 2, 2, 1, 1]
-    )
-    assert np.all(np.asarray(m.iters) == 2)
-    assert np.all(np.asarray(m.hit_max_iters))
+    for f in ("iters", "expanded", "cand_total", "cand_valid", "kept",
+              "visited", "beam_occupancy"):
+        np.testing.assert_array_equal(np.asarray(getattr(m, f)), [2, 3, 4])
+    np.testing.assert_array_equal(np.asarray(m.hit_max_iters),
+                                  [False, True, True])
+    np.testing.assert_array_equal(np.asarray(m.delta_valid), [1, 1, 1])
     d = per_query_dict(m)
-    assert set(d) == set(SearchStats._fields) - {"hop_valid", "hop_total"}
+    assert set(d) == set(SearchStats._fields)
+    assert all(v.shape == (3,) and v.dtype == jnp.int32 for v in d.values())
 
 
 def test_record_search_stats_folds_into_registry():
@@ -433,8 +466,12 @@ def test_record_search_stats_folds_into_registry():
     }
     # n_real=3 truncates the padded 4th row out of every series
     record_search_stats(st, registry=reg, n_real=3)
-    c = reg.counter("repro_search_iterations_total")
-    assert c.value() == 8
+    assert reg.counter("repro_search_nodes_expanded_total").value() == 8
+    assert reg.counter("repro_search_candidates_valid_total").value() == 35
+    # the iteration and kept totals come from the always-on loop totals
+    names = reg.names()
+    assert "repro_search_iterations_total" not in names
+    assert "repro_search_candidates_kept_total" not in names
     assert reg.counter("repro_search_queries_total").value() == 3
     term = reg.counter("repro_search_terminations_total")
     assert term.value(cause="beam_converged") == 2
@@ -526,3 +563,203 @@ def test_streaming_stats_no_recompile_across_epoch_swap():
     # stats=True changes no result on the streaming path either
     ids2, d2 = idx.search(qv, broad_s, broad_t, k=5, beam=16, plan="graph")
     np.testing.assert_array_equal(ids1, ids2)
+
+
+# --- always-on loop totals ----------------------------------------------------
+
+
+def _graph_batch(vecs, s, t, B, seed):
+    """Queries whose windows span a fifth to most of the time axis, so
+    that rows traverse for several iterations."""
+    rng = np.random.default_rng(seed)
+    q = rng.standard_normal((B, vecs.shape[1])).astype(np.float32)
+    s_q = rng.uniform(s.min(), s.max(), B)
+    span = float(t.max() - s.min())
+    t_q = s_q + rng.uniform(0.2, 0.8, B) * span
+    return q, s_q, t_q
+
+
+def _expected_totals(st, rows, width):
+    """``LOOP_TOTALS`` as sums of the per-query counters over ``rows``, a
+    mask over the loop's B rows: its trips are the longest row's
+    iterations, and masked rows take row slots too."""
+    iters = np.asarray(st.iters)[rows]
+    slots = int(iters.max(initial=0)) * len(rows)
+    return [slots, int(iters.sum()), slots * width,
+            int(np.asarray(st.kept)[rows].sum())]
+
+
+@pytest.mark.parametrize("layout", ["packed", "int32", "unfused"])
+def test_loop_totals_equal_per_query_sums_graph(obs_setup, layout):
+    """plan="graph": row slots = B × max iters, row iterations = Σ iters,
+    candidate slots = row slots × E and kept = Σ kept, with a cap small
+    enough to cut some rows off."""
+    vecs, s, t, dg = obs_setup
+    q, s_q, t_q = _graph_batch(vecs, s, t, 6, seed=11)
+    states, ep = prepare_states(dg, s_q, t_q)
+    dev = dg.device()
+    fused = layout != "unfused"
+    labels = dg.serving_labels(fused=fused, packed=layout == "packed")
+    ids, d, totals, st = _batched_search_core(
+        dev.table, dev.nbr, labels, jnp.asarray(q), jnp.asarray(states),
+        jnp.asarray(ep), k=4, beam=8, max_iters=4, use_ref=True,
+        fused=fused, norms=dev.norms if fused else None, stats=True,
+    )
+    assert np.asarray(st.hit_max_iters).any()
+    assert np.asarray(totals).tolist() == _expected_totals(
+        st, np.ones(6, bool), dev.nbr.shape[1])
+    ids0, d0, totals0 = _batched_search_core(
+        dev.table, dev.nbr, labels, jnp.asarray(q), jnp.asarray(states),
+        jnp.asarray(ep), k=4, beam=8, max_iters=4, use_ref=True,
+        fused=fused, norms=dev.norms if fused else None,
+    )
+    np.testing.assert_array_equal(np.asarray(totals0), np.asarray(totals))
+    np.testing.assert_array_equal(np.asarray(ids0), np.asarray(ids))
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_loop_totals_equal_per_query_sums_planned(obs_setup, monkeypatch,
+                                                   fused):
+    """plan="auto": the graph loop's totals (row 0) are the sums over the
+    rows planned GRAPH, the wide loop's (row 1) over those planned
+    GRAPH_WIDE, over all B row slots of each loop."""
+    from repro.exec import executor
+
+    vecs, s, t, dg = obs_setup
+    seen = []
+    core = executor.planned_exec_core
+
+    def spy(*a, **kw):
+        out = core(*a, **kw)
+        seen.append(np.asarray(out[2]))
+        return out
+
+    monkeypatch.setattr(executor, "planned_exec_core", spy)
+    cfg = PlannerConfig(brute_max_valid=1, wide_max_fraction=0.1)
+    q, s_q, _ = _graph_batch(vecs, s, t, 8, seed=22)
+    span = float(t.max() - s.min())
+    t_q = s_q + span * np.array([0.005, 0.05, 0.5, 0.005, 0.05, 0.5, 0.3,
+                                 0.8])
+    _, _, pb, st = execute_batch(
+        dg, q, s_q, t_q, k=4, beam=8, max_iters=6, use_ref=True,
+        fused=fused, plan="auto", config=cfg, return_plans=True,
+        stats=True,
+    )
+    totals = seen[-1]
+    assert totals.shape == (2, len(LOOP_TOTALS))
+    assert np.asarray(st.hit_max_iters).any()
+    E = dg.device().nbr.shape[1]
+    widths = (E, (cfg.wide_expand if fused else 1) * E)
+    for row, plan in enumerate((QueryPlan.GRAPH, QueryPlan.GRAPH_WIDE)):
+        rows = pb.plans == int(plan)
+        assert rows.any(), plan
+        assert totals[row].tolist() == _expected_totals(
+            st, rows, widths[row]), plan
+
+
+@pytest.fixture(scope="module")
+def stream_idx():
+    from repro.data import make_dataset
+    from repro.stream import StreamingIndex
+
+    vecs, s, t = make_dataset(220, 8, seed=23)
+    idx = StreamingIndex(
+        8, "overlap", node_capacity=256, delta_capacity=64,
+        edge_capacity=48, M=6, Z=24,
+    )
+    idx.insert_batch(vecs[:160], s[:160], t[:160])
+    idx.compact()
+    for i in range(160, 180):
+        idx.insert(vecs[i], s[i], t[i])
+    return idx, vecs, s, t
+
+
+@pytest.mark.parametrize("plan", ["auto", "graph"])
+def test_streaming_search_folds_loop_totals(stream_idx, plan):
+    """StreamingIndex.search folds each loop's totals into the registry it
+    is given, labelled by plan, with the slot counters from the loop's
+    static shapes; they sum to the stats=True per-query counters."""
+    idx, vecs, s, t = stream_idx
+    B = 8
+    q, s_q, _ = _graph_batch(vecs, s, t, B, seed=24)
+    span = float(t.max() - s.min())
+    t_q = s_q + span * np.array([0.005, 0.05, 0.5, 0.005, 0.05, 0.5, 0.3,
+                                 0.8])
+    cfg = PlannerConfig(brute_max_valid=1, wide_max_fraction=0.1)
+    reg = MetricsRegistry()
+    ids, d, st = idx.search(q, s_q, t_q, k=4, beam=8, plan=plan,
+                            planner_config=cfg, return_stats=True,
+                            registry=reg)
+    loops = ("GRAPH",) if plan == "graph" else ("GRAPH", "GRAPH_WIDE")
+    M = (1,) if plan == "graph" else (1, cfg.wide_expand)
+    E = idx._dg.nbr.shape[1]
+
+    def value(name, p):
+        return reg.counter(name).value(plan=p)
+
+    def summed(name):
+        return sum(value(name, p) for p in loops)
+
+    assert summed("repro_search_iterations_total") == int(
+        np.asarray(st.iters).sum())
+    assert summed("repro_search_candidates_kept_total") == int(
+        np.asarray(st.kept).sum())
+    slots = [value("repro_search_row_slots_total", p) for p in loops]
+    assert min(slots) > 0
+    assert max(slots) == B * int(np.asarray(st.iters).max())
+    for p, sl, m in zip(loops, slots, M):
+        assert sl % B == 0
+        assert value("repro_search_candidate_slots_total", p) == sl * m * E
+    # the totals ride with stats off too, and change no result
+    reg2 = MetricsRegistry()
+    ids2, d2 = idx.search(q, s_q, t_q, k=4, beam=8, plan=plan,
+                          planner_config=cfg, registry=reg2)
+    np.testing.assert_array_equal(ids, ids2)
+    for name in ("repro_search_iterations_total",
+                 "repro_search_row_slots_total"):
+        for p in loops:
+            assert reg2.counter(name).value(plan=p) == value(name, p)
+
+
+def test_served_programs_stay_one_across_plan_mixes_and_swaps():
+    """With the totals always on, the planned and streaming steps keep one
+    compiled program each across plan mixes and an epoch swap."""
+    from repro.data import make_dataset
+    from repro.exec import planned_exec_cache_size
+    from repro.stream import StreamingIndex, streaming_search_cache_size
+
+    vecs, s, t = make_dataset(200, 8, seed=25)
+    idx = StreamingIndex(
+        8, "overlap", node_capacity=256, delta_capacity=64,
+        edge_capacity=48, M=6, Z=24,
+    )
+    idx.insert_batch(vecs[:150], s[:150], t[:150])
+    idx.compact()
+    rng = np.random.default_rng(26)
+    B = 6
+    q = rng.standard_normal((B, 8)).astype(np.float32)
+    s_q = rng.uniform(s.min(), s.max(), B)
+    span = float(t.max() - s.min())
+    cfg = PlannerConfig(brute_max_valid=1, wide_max_fraction=0.1)
+    mixes = set()
+
+    def serve(frac):
+        for plan in ("auto", "graph"):
+            reg = MetricsRegistry()
+            idx.search(q, s_q, s_q + frac * span, k=4, beam=8, plan=plan,
+                       planner_config=cfg, registry=reg)
+            slots = reg.counter("repro_search_row_slots_total")
+            mixes.add((plan, slots.value(plan="GRAPH") > 0,
+                       slots.value(plan="GRAPH_WIDE") > 0))
+
+    serve(0.005)
+    sizes = (streaming_search_cache_size(), planned_exec_cache_size())
+    for frac in (0.05, 0.5):
+        serve(frac)
+    for i in range(150, 200):
+        idx.insert(vecs[i], s[i], t[i])
+    serve(0.05)
+    idx.compact()
+    serve(0.8)
+    assert (streaming_search_cache_size(), planned_exec_cache_size()) == sizes
+    assert len({m for m in mixes if m[0] == "auto"}) >= 2, mixes

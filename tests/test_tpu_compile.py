@@ -163,6 +163,39 @@ def test_planned_streaming_step_compiles(one_chip, on_tpu):
     assert text.count("tpu_custom_call") >= 5
 
 
+def test_served_step_keeps_the_d128_table_in_vmem(one_chip, on_tpu):
+    """At one shard of ``sift128-overlap`` (65,536 × 128 f32, 32 MB) the
+    compiler places the vector table in VMEM (memory space S(1)) for both
+    search loops' packed gather kernels, whose per-row DMAs then read it
+    from there: 1.5× faster per call on a TPU v5e than from HBM. Keeping
+    the visited bitmap alive past the loops once moved it out."""
+    import re
+
+    from repro.stream.search import planned_streaming_search_core
+
+    S = _spec(one_chip)
+    d, B, C, V = 128, 256, 1024, 256
+    text = planned_streaming_search_core.lower(
+        S((N, 1, d), jnp.float32), S((N, E), jnp.int32),
+        S((N, 1, 2 * LANE), jnp.int32), S((N,), jnp.bool_),
+        S((N,), jnp.int32), S((C, d), jnp.float32), S((C, 4), jnp.int32),
+        S((C,), jnp.int32), S((C,), jnp.int32), S((B, d), jnp.float32),
+        S((B, 2), jnp.int32), S((B,), jnp.int32), S((B,), jnp.int32),
+        S((B, V), jnp.int32), S((B,), jnp.int32), S((B, 2), jnp.int32),
+        k=10, beam=64, wide_beam=128, max_iters=128, wide_max_iters=256,
+        use_ref=None, fused=True, wide_expand=2,
+        norms=S((N,), jnp.float32)).compile().as_text()
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ", text,
+                             re.M))
+    calls = re.findall(r"%filter_dist_gather_packed_pallas\.\d+ = \S+ "
+                       r"custom-call\(([^)]*)\)", text)
+    assert len(calls) == 2
+    for operands in calls:
+        table = operands.split(",")[2].strip().lstrip("%")
+        assert shapes[table].startswith(f"f32[{N},1,{d}]"), shapes[table]
+        assert "S(1)" in shapes[table], shapes[table]
+
+
 def test_build_wave_search_compiles(one_chip, on_tpu):
     """The batched constructor's label-ignoring wave search (a logical
     ``[n, d]`` table, converted to rows in-graph) at the deployment's
