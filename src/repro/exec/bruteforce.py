@@ -60,7 +60,7 @@ def brute_topk_impl(
     labels = jnp.zeros((B, V, 4), dtype=jnp.int32)   # all-pass rectangles
     states = jnp.zeros((B, 2), dtype=jnp.int32)
     visited = jnp.zeros((B, (n + 31) // 32), dtype=jnp.uint32)
-    d = ops.filter_dist_gather(
+    d, _ = ops.filter_dist_gather(
         table, norms, q, bf_ids, labels, states, visited,
         scales=scales, use_ref=use_ref,
     )

@@ -81,7 +81,7 @@ def planned_exec_core(
 ) -> Tuple[jnp.ndarray, ...]:
     """All three strategies in one traced program + per-row plan select.
 
-    Returns ``(ids [B, k], dists [B, k], totals i32[2, 4])``: ``totals``
+    Returns ``(ids [B, k], dists [B, k], totals i32[2, 5])``: ``totals``
     holds the always-on ``LOOP_TOTALS`` of the graph loop (row 0) and of
     the wide loop (row 1). ``stats=True`` appends a merged :class:`repro.obs.SearchStats`: each
     graph instantiation sees rows planned elsewhere as masked (ep=-1 →

@@ -24,9 +24,12 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.beam_merge import beam_merge_jnp, beam_merge_pallas
 from repro.kernels.filter_dist import (
+    fetch_rows,
     filter_dist_gather_packed_pallas,
     filter_dist_gather_pallas,
     filter_dist_pallas,
+    label_passes,
+    packed_fetch_rows,
 )
 from repro.kernels.int8dist import int8_l2dist_pallas, quantize_int8
 from repro.kernels.l2dist import l2dist_pallas
@@ -72,6 +75,24 @@ def filter_dist(
     return filter_dist_pallas(q, cand, labels, state, cand_ids, interpret=_interpret())
 
 
+def _gathered(table, norms, cand_ids, visited, scales):
+    """The 4-byte per-candidate metadata the gather kernels take, gathered
+    on the XLA side: cached norms, visited words and dequant scales."""
+    n = table.shape[0]
+    safe = jnp.clip(cand_ids, 0, n - 1)
+    g_norms = norms[safe].astype(jnp.float32)
+    g_words = jnp.take_along_axis(visited, safe >> 5, axis=1)
+    if scales is not None:
+        g_scales = scales[safe].astype(jnp.float32)
+    else:
+        g_scales = jnp.ones_like(g_norms)
+    return g_norms, g_words, g_scales
+
+
+def _count(fetch):
+    return jnp.sum((fetch >= 0).astype(jnp.int32))
+
+
 def filter_dist_gather(
     table: jnp.ndarray,      # [n, D] vector table (f32 or int8) or its rows
     norms: jnp.ndarray,      # [n] f32 cached ‖c‖² of the (dequantized) rows
@@ -83,30 +104,30 @@ def filter_dist_gather(
     *,
     scales: jnp.ndarray | None = None,   # [n] f32 int8 dequant scales
     use_ref: bool | None = False,
-) -> jnp.ndarray:
-    """Gather-fused label-validity + visited test + squared distance [B, C].
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Gather-fused label-validity + visited test + squared distance —
+    ``(d [B, C], fetched)``.
 
     The candidate *vector rows* are gathered inside the Pallas kernel (HBM →
-    VMEM DMA driven by scalar-prefetched ids) — no [B, C, D] intermediate.
-    Only the 4-byte per-candidate metadata (cached norm, visited word,
-    dequant scale) is gathered here on the XLA side before the call.
+    VMEM DMA driven by scalar-prefetched ids) — no [B, C, D] intermediate —
+    and only for candidates that can still pass: not padding, not visited,
+    passing the label test (``filter_dist.fetch_rows``). ``fetched`` is the
+    i32 count of those row DMAs, the same on the oracle path. Only the
+    4-byte per-candidate metadata (cached norm, visited word, dequant
+    scale) is gathered here on the XLA side before the call.
     """
+    g_norms, g_words, g_scales = _gathered(
+        table, norms, cand_ids, visited, scales)
+    fetched = _count(fetch_rows(cand_ids, g_words, table.shape[0],
+                                label_passes(labels, state)))
     if use_reference(use_ref):
         return ref.filter_dist_gather_ref(
             table, norms, q, cand_ids, labels, state, visited, scales
-        )
-    n = table.shape[0]
-    safe = jnp.clip(cand_ids, 0, n - 1)
-    g_norms = norms[safe].astype(jnp.float32)
-    g_words = jnp.take_along_axis(visited, safe >> 5, axis=1)
-    if scales is not None:
-        g_scales = scales[safe].astype(jnp.float32)
-    else:
-        g_scales = jnp.ones_like(g_norms)
+        ), fetched
     return filter_dist_gather_pallas(
         table, q, cand_ids, labels, state, g_norms, g_words, g_scales,
         interpret=_interpret(),
-    )
+    ), fetched
 
 
 def filter_dist_gather_packed(
@@ -114,36 +135,36 @@ def filter_dist_gather_packed(
     plabels: jnp.ndarray,    # [n, E, 2] uint32 packed words, or their rows
     norms: jnp.ndarray,      # [n] f32 cached ‖c‖² of the (dequantized) rows
     q: jnp.ndarray,          # [B, D]
-    cur_ids: jnp.ndarray,    # [B, M] int32 expanded beam nodes
+    cur_ids: jnp.ndarray,    # [B, M] int32 expanded beam nodes (-1 = none)
     cand_ids: jnp.ndarray,   # [B, M*E] int32 candidate row ids (-1 = padding)
     state: jnp.ndarray,      # [B, 2] int32
     visited: jnp.ndarray,    # [B, ceil(n/32)] uint32 bit-packed visited set
     *,
     scales: jnp.ndarray | None = None,   # [n] f32 int8 dequant scales
     use_ref: bool | None = False,
-) -> jnp.ndarray:
+) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Packed-metadata superkernel: gather-fused label + visited test +
-    squared distance ``[B, M·E]`` where the label metadata is DMA'd
-    in-kernel from the packed ``[n, E, 2]`` uint32 table — no XLA-side
-    label gather at all. Per-candidate XLA-side traffic is the same
-    12 bytes of (norm, visited word, scale) as ``filter_dist_gather``."""
+    squared distance, ``(d [B, M·E], fetched)``, where the label metadata
+    is DMA'd in-kernel from the packed ``[n, E, 2]`` uint32 table — no
+    XLA-side label gather at all. Per-candidate XLA-side traffic is the
+    same 12 bytes of (norm, visited word, scale) as ``filter_dist_gather``.
+    Vector rows are fetched only for candidates that are not padding and
+    not visited, and a tile of a ``-1`` expanded node, or with no such
+    candidate, fetches nothing (``filter_dist.packed_fetch_rows``);
+    ``fetched`` counts the row DMAs, the same on the oracle path."""
+    g_norms, g_words, g_scales = _gathered(
+        table, norms, cand_ids, visited, scales)
+    fetched = _count(packed_fetch_rows(
+        cur_ids, cand_ids, g_words, table.shape[0])[1])
     if use_reference(use_ref):
         return ref.filter_dist_gather_packed_ref(
             table, plabels, norms, q, cur_ids, cand_ids, state, visited,
             scales,
-        )
-    n = table.shape[0]
-    safe = jnp.clip(cand_ids, 0, n - 1)
-    g_norms = norms[safe].astype(jnp.float32)
-    g_words = jnp.take_along_axis(visited, safe >> 5, axis=1)
-    if scales is not None:
-        g_scales = scales[safe].astype(jnp.float32)
-    else:
-        g_scales = jnp.ones_like(g_norms)
+        ), fetched
     return filter_dist_gather_packed_pallas(
         table, plabels, q, cur_ids, cand_ids, state, g_norms, g_words,
         g_scales, interpret=_interpret(),
-    )
+    ), fetched
 
 
 def beam_merge(

@@ -121,7 +121,7 @@ def filter_dist_gather_packed_ref(
     plabels: jnp.ndarray,     # [n, E, 2] uint32 packed words, or their rows
     norms: jnp.ndarray,       # [n] f32 cached ‖c‖²
     q: jnp.ndarray,           # [B, D] query vectors
-    cur_ids: jnp.ndarray,     # [B, M] int32 expanded beam nodes (label rows)
+    cur_ids: jnp.ndarray,     # [B, M] int32 expanded nodes (label rows), -1 = none
     cand_ids: jnp.ndarray,    # [B, M*E] int32 candidate row ids (-1 = padding)
     state: jnp.ndarray,       # [B, 2] int32 canonical rank state (a, c)
     visited: jnp.ndarray,     # [B, ceil(n/32)] uint32 bit-packed visited set
@@ -137,6 +137,8 @@ def filter_dist_gather_packed_ref(
     E = cand_ids.shape[1] // M
     rows = label_words(plabels, jnp.clip(cur_ids, 0, n - 1), E)  # [B, M, E, 2]
     labels = unpack_labels_jnp(rows.reshape(B, M * E, 2))
+    # a -1 expanded node marks a dead tile: all of its candidates +inf
+    cand_ids = jnp.where(jnp.repeat(cur_ids >= 0, E, axis=1), cand_ids, -1)
     return filter_dist_gather_ref(
         table, norms, q, cand_ids, labels, state, visited, scales
     )

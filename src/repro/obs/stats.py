@@ -1,15 +1,19 @@
 """Device-side traversal counters of the jitted searches, at two depths.
 
 **Always-on loop totals.** Every ``_batched_search_core`` returns, beside
-its ids and distances, one fixed ``i32[4]`` vector of batch totals of its
+its ids and distances, one fixed ``i32[5]`` vector of batch totals of its
 padded loop (:data:`LOOP_TOTALS`): ``row_slots`` (the loop's trips × B),
 ``row_iterations`` (Σ over rows of the trips in which the row expanded
 at least one entry), ``candidate_slots`` (trips × B × M·E: the candidate
-rows handed to the gather, useful or not) and ``kept`` (candidates that
+rows handed to the gather, useful or not), ``kept`` (candidates that
 passed the predicate and visited tests and entered the beam merge: the
-dedup keep mask, summed). The slot counts come from the loop's static
-shapes inside the trace; the loop itself pays one ``[B]`` add and one
-scalar sum of the keep mask per iteration, so the served program carries
+dedup keep mask, summed) and ``rows_fetched`` (the vector rows the gather
+DMA'd: candidates that were not padding, not in an idle row and not
+visited, counted from the same gated row ids the kernel receives; every
+slot in the unfused loop, whose XLA gather fetches them all). The slot
+counts come from the loop's static shapes inside the trace; the loop
+itself pays one ``[B]`` add and two scalar sums per iteration, so the
+served program carries
 the totals at every ``stats`` setting: one compiled program, no second
 one for the totals. (Counting ``kept`` after the loop instead, as the
 visited bitmap's population less the entry bits, keeps the bitmap alive
@@ -54,8 +58,9 @@ search in ``tests/test_obs.py``):
                         filter (zeros for pure graph searches).
 
 The loop totals are sums of these: ``row_iterations`` = Σ ``iters``,
-``kept`` = Σ ``kept`` and ``row_slots`` = B × max ``iters`` (every trip
-has a live row), pinned in ``tests/test_obs.py``.
+``kept`` = Σ ``kept``, ``row_slots`` = B × max ``iters`` (every trip
+has a live row) and, in the fused loops, ``kept`` ≤ ``rows_fetched`` ≤
+Σ ``cand_total``, pinned in ``tests/test_obs.py``.
 """
 from __future__ import annotations
 
@@ -91,7 +96,8 @@ class SearchStats(NamedTuple):
 
 # the always-on batch totals of one padded search loop, in vector order,
 # and the registry counter each is folded into (label ``plan``)
-LOOP_TOTALS = ("row_slots", "row_iterations", "candidate_slots", "kept")
+LOOP_TOTALS = ("row_slots", "row_iterations", "candidate_slots", "kept",
+               "rows_fetched")
 LOOP_COUNTERS = (
     ("repro_search_row_slots_total",
      "row slots of a padded search loop (trips x B)"),
@@ -101,6 +107,8 @@ LOOP_COUNTERS = (
      "candidate rows handed to the gather (trips x B x M*E)"),
     ("repro_search_candidates_kept_total",
      "candidates that passed both tests and entered the merge"),
+    ("repro_search_rows_fetched_total",
+     "candidate vector rows the gather fetched"),
 )
 
 
@@ -140,14 +148,15 @@ def loop_totals(
     trips: jnp.ndarray,       # scalar i32 — the loop's final iteration count
     row_iters: jnp.ndarray,   # [B] i32 — trips in which the row expanded
     kept: jnp.ndarray,        # scalar i32 — Σ of the keep mask over trips
+    fetched: jnp.ndarray,     # scalar i32 — Σ of the rows fetched over trips
     *,
     width: int,               # candidate slots per row and trip (M·E)
 ) -> jnp.ndarray:
-    """The ``i32[4]`` :data:`LOOP_TOTALS` of one loop (trace-time); int32
+    """The ``i32[5]`` :data:`LOOP_TOTALS` of one loop (trace-time); int32
     holds them while trips·B·M·E < 2^31."""
     row_slots = trips * row_iters.shape[0]
     return jnp.stack(
-        [row_slots, jnp.sum(row_iters), row_slots * width, kept]
+        [row_slots, jnp.sum(row_iters), row_slots * width, kept, fetched]
     ).astype(jnp.int32)
 
 
@@ -210,7 +219,7 @@ def record_loop_totals(
     registry: Optional[MetricsRegistry] = None,
 ) -> None:
     """Fold the always-on totals of one batch into the registry, in one
-    locked update: ``totals`` is ``[P, 4]`` (host or device), one
+    locked update: ``totals`` is ``[P, 5]`` (host or device), one
     :data:`LOOP_TOTALS` row per padded loop, labelled ``plans[p]``."""
     totals = np.asarray(totals).reshape(len(plans), len(LOOP_TOTALS))
     resolve(registry).inc_counters(

@@ -13,9 +13,11 @@ carries bit-packed ``[n, E, 2]`` uint32 label rectangles):
   2. read their padded neighbor ids ([B, M*E] int32 — the only per-edge
      metadata that crosses the XLA boundary);
   3. packed-metadata superkernel (``ops.filter_dist_gather_packed``): the
-     kernel DMAs the needed vector rows *and* the M expanded nodes' packed
-     label rows from the HBM-resident tables (scalar-prefetched ids,
-     double-buffered VMEM tiles), unpacks the 16-bit ranks with a
+     kernel DMAs the vector rows of the candidates that are neither
+     padding nor visited, *and* the M expanded nodes' packed label rows,
+     from the HBM-resident tables (scalar-prefetched ids, double-buffered
+     VMEM tiles; a query row with no live entry is a dead tile that
+     fetches nothing), unpacks the 16-bit ranks with a
      mask-and-shift, applies the dominance + visited tests, and computes
      ``‖c‖² − 2·q·c + ‖q‖²`` from cached per-node norms — neither the
      ``[B, E, D]`` candidate tensor nor the ``[B, M·E, 4]`` label gather
@@ -137,7 +139,7 @@ def _batched_search_core(
     norms: jnp.ndarray | None = None,    # [n] f32: cached ‖c‖² (fused path)
     stats: bool = False,  # also return a SearchStats traversal-counter pytree
 ) -> Tuple[jnp.ndarray, ...]:
-    """Returns ``(ids [B, k], dists [B, k], totals i32[4])`` — ``totals``
+    """Returns ``(ids [B, k], dists [B, k], totals i32[5])`` — ``totals``
     are the loop's always-on ``repro.obs.stats.LOOP_TOTALS`` — and, with
     ``stats=True``, a :class:`repro.obs.SearchStats` last."""
     n = vectors.shape[0]
@@ -202,8 +204,8 @@ def _batched_search_core(
         visited = visited.at[jnp.arange(B), ep_safe >> 5].add(ep_bit)
 
         def body(carry):
-            beam_ids_, beam_d_, beam_exp_, visited_, it, rows_it, kept_ = (
-                carry[:7])
+            (beam_ids_, beam_d_, beam_exp_, visited_, it, rows_it, kept_,
+             fetched_) = carry[:8]
             # 1. best M unexpanded entries per query
             cand_d = jnp.where(beam_exp_, _INF, beam_d_)
             if M == 1:
@@ -227,9 +229,10 @@ def _batched_search_core(
                 # 3. packed superkernel: in-kernel DMA of the vector rows
                 # AND the M expanded nodes' packed label rows; dominance +
                 # visited tests and cached-norm distance fused in-kernel
-                d_new = ops.filter_dist_gather_packed(
-                    vectors, labels, norms_, q, cur_safe, nb, states,
-                    visited_, scales=scales, use_ref=use_ref,
+                # a -1 expanded node (no live entry) is a dead tile
+                d_new, fetched = ops.filter_dist_gather_packed(
+                    vectors, labels, norms_, q, jnp.where(live, cur, -1),
+                    nb, states, visited_, scales=scales, use_ref=use_ref,
                 )
                 # 4. dedup + top-L merge primitive (no argsort, no full
                 # stable sort); `keep` = deduped survivors, in nb order
@@ -250,10 +253,11 @@ def _batched_search_core(
                 visited_ = visited_.at[rows, ids_safe >> 5].add(bits)
                 out = (beam_ids_, beam_d_, beam_exp_, visited_, it + 1,
                        rows_it + jnp.any(live, axis=1).astype(jnp.int32),
-                       kept_ + jnp.sum(keep.astype(jnp.int32)))
+                       kept_ + jnp.sum(keep.astype(jnp.int32)),
+                       fetched_ + fetched)
                 if stats:
                     out += (accumulate_iteration(
-                        carry[7], live=live, nb=nb, d_new=d_new, keep=keep,
+                        carry[8], live=live, nb=nb, d_new=d_new, keep=keep,
                     ),)
                 return out
             if labels is None:
@@ -261,7 +265,7 @@ def _batched_search_core(
             else:
                 lb = labels[cur_safe].reshape(B, ME, 4)
             # 3. gather-fused label + visited test + cached-norm distance
-            d_new = ops.filter_dist_gather(
+            d_new, fetched = ops.filter_dist_gather(
                 vectors, norms_, q, nb, lb, states, visited_,
                 scales=scales, use_ref=use_ref,
             )
@@ -297,10 +301,11 @@ def _batched_search_core(
             )
             out = (si[:, :L], sd[:, :L], se[:, :L], visited_, it + 1,
                    rows_it + jnp.any(live, axis=1).astype(jnp.int32),
-                   kept_ + jnp.sum(keep.astype(jnp.int32)))
+                   kept_ + jnp.sum(keep.astype(jnp.int32)),
+                   fetched_ + fetched)
             if stats:
                 out += (accumulate_iteration(
-                    carry[7], live=live, nb=nb, d_new=d_new, keep=keep,
+                    carry[8], live=live, nb=nb, d_new=d_new, keep=keep,
                 ),)
             return out
 
@@ -309,8 +314,8 @@ def _batched_search_core(
         visited = visited.at[jnp.arange(B), ep_safe].max(has_ep)
 
         def body(carry):
-            beam_ids_, beam_d_, beam_exp_, visited_, it, rows_it, kept_ = (
-                carry[:7])
+            (beam_ids_, beam_d_, beam_exp_, visited_, it, rows_it, kept_,
+             fetched_) = carry[:8]
             # 1. best unexpanded entry per query
             cand_d = jnp.where(beam_exp_, _INF, beam_d_)
             j = jnp.argmin(cand_d, axis=1)
@@ -352,19 +357,22 @@ def _batched_search_core(
             sd, si, se = jax.lax.sort(
                 (all_d, all_ids, all_exp), dimension=1, num_keys=1, is_stable=True
             )
+            # XLA gathers every candidate's row: all B x E are fetched
             out = (si[:, :L], sd[:, :L], se[:, :L], visited_, it + 1,
                    rows_it + live.astype(jnp.int32),
-                   kept_ + jnp.sum(keep.astype(jnp.int32)))
+                   kept_ + jnp.sum(keep.astype(jnp.int32)),
+                   fetched_ + B * E)
             if stats:
                 out += (accumulate_iteration(
-                    carry[7], live=live[:, None], nb=nb, d_new=d_new,
+                    carry[8], live=live[:, None], nb=nb, d_new=d_new,
                     keep=keep,
                 ),)
             return out
 
-    # the always-on totals' carry: trips (it), row iterations, kept
+    # the always-on totals' carry: trips (it), row iterations, kept,
+    # rows fetched
     carry = (beam_ids, beam_d, beam_exp, visited, jnp.int32(0),
-             jnp.zeros(B, dtype=jnp.int32), jnp.int32(0))
+             jnp.zeros(B, dtype=jnp.int32), jnp.int32(0), jnp.int32(0))
     if stats:
         carry += (init_search_stats(B),)
     if unroll_iters > 0:
@@ -375,12 +383,13 @@ def _batched_search_core(
             carry = body(carry)
     else:
         carry = jax.lax.while_loop(cond, body, carry)
-    beam_ids, beam_d, beam_exp, visited, trips, rows_it, kept = carry[:7]
+    beam_ids, beam_d, beam_exp, visited, trips, rows_it, kept, fetched = (
+        carry[:8])
     out = (beam_ids[:, :k], beam_d[:, :k],
-           loop_totals(trips, rows_it, kept, width=expand * E))
+           loop_totals(trips, rows_it, kept, fetched, width=expand * E))
     if stats:
         out += (finalize_stats(
-            carry[7], beam_d=beam_d, beam_exp=beam_exp, visited=visited
+            carry[8], beam_d=beam_d, beam_exp=beam_exp, visited=visited
         ),)
     return out
 

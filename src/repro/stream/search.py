@@ -74,7 +74,7 @@ def two_tier_merge(
         # [C, d] buffer are one tiny reduction per step, not per candidate
         dnorms = jnp.sum(dvec.astype(jnp.float32) ** 2, axis=1)
         dvis = jnp.zeros((B, (C + 31) // 32), dtype=jnp.uint32)
-        d_d = ops.filter_dist_gather(
+        d_d, _ = ops.filter_dist_gather(
             dvec, dnorms, q, slot, lab, dstate, dvis, use_ref=use_ref
         )
     else:
@@ -174,7 +174,7 @@ def planned_streaming_search_core(
 ) -> Tuple[jnp.ndarray, ...]:
     """Planner-routed variant of :func:`streaming_search_core`.
 
-    Both return ``(ext ids [B, k], dists [B, k], totals i32[P, 4])`` —
+    Both return ``(ext ids [B, k], dists [B, k], totals i32[P, 5])`` —
     ``totals`` the always-on ``LOOP_TOTALS`` of each padded loop, P = 1
     here (``GRAPH``) and 2 in the planned step (``GRAPH``, ``GRAPH_WIDE``)
     — and, with ``stats=True``, a :class:`repro.obs.SearchStats` last.

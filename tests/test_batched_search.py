@@ -43,6 +43,41 @@ def test_batched_with_pallas_kernel_matches_ref_path(setup, query_vectors):
     np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("expand", [1, 2])
+def test_idle_and_padding_rows_change_no_served_result(setup, query_vectors,
+                                                       expand):
+    """A batch whose rows go idle (empty valid sets from the start, early
+    convergence later) and whose adjacency carries -1 padding: on the
+    kernel path, where such rows are dead tiles and skipped fetches, each
+    live query gets bitwise the ids and distances it gets in a batch of
+    live queries alone, the same ids as the reference path and its
+    distances to float rounding; the idle rows come back empty."""
+    vecs, s, t, g, dg = setup
+    assert (dg.nbr < 0).any()
+    qs = generate_queries(query_vectors[:5], s, t, "overlap", 0.05, k=5,
+                          seed=12)
+    # rows 0, 3 and 6 are sentinels: s_q > t_q, no valid object
+    live = np.array([False, True, True, False, True, True, False, True])
+    q = np.zeros((8, vecs.shape[1]), np.float32)
+    s_q = np.full(8, 100.0)
+    t_q = np.full(8, -100.0)
+    q[live], s_q[live], t_q[live] = qs.vectors, qs.s_q, qs.t_q
+
+    def serve(q, s_q, t_q, use_ref):
+        return batched_udg_search(dg, q, s_q, t_q, k=5, beam=16,
+                                  expand=expand, use_ref=use_ref)
+
+    ids, d = serve(q, s_q, t_q, False)
+    ids_alone, d_alone = serve(qs.vectors, qs.s_q, qs.t_q, False)
+    assert (ids_alone >= 0).any()
+    np.testing.assert_array_equal(ids[live], ids_alone)
+    np.testing.assert_array_equal(d[live], d_alone)
+    assert np.all(ids[~live] == -1) and np.all(np.isinf(d[~live]))
+    ids_ref, d_ref = serve(q, s_q, t_q, True)
+    np.testing.assert_array_equal(ids, ids_ref)
+    np.testing.assert_allclose(d, d_ref, rtol=1e-5)
+
+
 @pytest.fixture(scope="module")
 def setup_containment(small_dataset):
     vecs, s, t = small_dataset

@@ -94,11 +94,11 @@ def test_filter_dist_gather_matches_ref(n, b, c, d, rows):
         table = table_rows(table)
     got = np.asarray(
         ops.filter_dist_gather(table, norms, q, ids, labels, state, vis,
-                               use_ref=False)
+                               use_ref=False)[0]
     )
     want = np.asarray(
         ops.filter_dist_gather(table, norms, q, ids, labels, state, vis,
-                               use_ref=True)
+                               use_ref=True)[0]
     )
     fin = np.isfinite(want)
     np.testing.assert_array_equal(np.isfinite(got), fin)
@@ -122,7 +122,7 @@ def test_filter_dist_gather_small_tile_boundaries():
     ))
     want = np.asarray(
         ops.filter_dist_gather(table, norms, q, ids, labels, state, vis,
-                               use_ref=True)
+                               use_ref=True)[0]
     )
     fin = np.isfinite(want)
     np.testing.assert_array_equal(np.isfinite(got), fin)
@@ -130,13 +130,13 @@ def test_filter_dist_gather_small_tile_boundaries():
 
 
 def test_filter_dist_gather_all_invalid_tile():
-    """A tile of nothing but -1 padding must come back all +inf (and the
-    row-0 fetches it degenerates to must not affect other tiles)."""
+    """A tile of nothing but -1 padding must come back all +inf (a dead
+    tile: it fetches no row and skips its compute)."""
     n, b, c, d = 64, 2, 16, 8
     table, norms, q, ids, labels, state, vis = _gather_case(n, b, c, d, seed=7)
     ids = jnp.full((b, c), -1, jnp.int32)
     got = np.asarray(
-        ops.filter_dist_gather(table, norms, q, ids, labels, state, vis)
+        ops.filter_dist_gather(table, norms, q, ids, labels, state, vis)[0]
     )
     assert np.all(np.isinf(got))
 
@@ -159,7 +159,7 @@ def test_filter_dist_gather_visited_bitmap_semantics():
         out = np.asarray(ops.filter_dist_gather(
             table, norms, q, ids, labels, state, jnp.asarray(vis),
             use_ref=use_ref,
-        ))
+        )[0])
         assert np.isinf(out[0, 0]) and np.isinf(out[0, 1])   # 3, 31 visited
         assert np.isfinite(out[0, 2])                        # 32 clear
         assert np.isinf(out[0, 3])                           # 44 visited
@@ -180,11 +180,11 @@ def test_filter_dist_gather_exhaustive_sweep():
     for n, b, c, d, seed in cases:
         table, norms, q, ids, labels, state, vis = _gather_case(n, b, c, d, seed)
         got = np.asarray(
-            ops.filter_dist_gather(table, norms, q, ids, labels, state, vis)
+            ops.filter_dist_gather(table, norms, q, ids, labels, state, vis)[0]
         )
         want = np.asarray(
             ops.filter_dist_gather(table, norms, q, ids, labels, state, vis,
-                                   use_ref=True)
+                                   use_ref=True)[0]
         )
         fin = np.isfinite(want)
         np.testing.assert_array_equal(np.isfinite(got), fin, err_msg=str((n, b, c, d)))
@@ -205,13 +205,101 @@ def test_filter_dist_gather_int8_scales(rows):
         tq = table_rows(tq)
     got = np.asarray(ops.filter_dist_gather(
         tq, norms, q, ids, labels, state, vis, scales=sc, use_ref=False,
-    ))
+    )[0])
     want = np.asarray(ops.filter_dist_gather(
         tq, norms, q, ids, labels, state, vis, scales=sc, use_ref=True,
-    ))
+    )[0])
     fin = np.isfinite(want)
     np.testing.assert_array_equal(np.isfinite(got), fin)
     np.testing.assert_allclose(got[fin], want[fin], rtol=1e-3, atol=1e-3)
+
+
+def _gated_table(n, b, d, int8, rng):
+    """(table, norms, scales, q): f32, or int8 with dequantized norms."""
+    table = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32))
+    if not int8:
+        return table, jnp.sum(table * table, axis=1), None, q
+    tq, sc = ops.quantize_int8(table)
+    deq = tq.astype(jnp.float32) * sc[:, None]
+    return tq, jnp.sum(deq * deq, axis=1), sc, q
+
+
+def _gated_tiles(pattern, b, te, n, rng):
+    """Candidate ids ``[b, len(pattern) // b · te]``, the visited bitmap and
+    a ``[b, C]`` label-pass mask, one tile of ``te`` per letter, in grid
+    order: ``M`` mixed (some padding, half visited, half failing the
+    labels), ``P`` padding only, ``S`` visited only, ``L`` failing the
+    labels only, ``D`` like ``M`` (its expanded node is made ``-1``)."""
+    R = len(pattern)
+    ids = rng.integers(0, n, size=(R, te)).astype(np.int32)
+    seen = np.zeros((R, te), bool)
+    passes = np.ones((R, te), bool)
+    for r, kind in enumerate(pattern):
+        if kind in "MD":
+            ids[r, rng.random(te) < 0.25] = -1
+            seen[r] = rng.random(te) < 0.5
+            passes[r] = rng.random(te) < 0.5
+        elif kind == "P":
+            ids[r] = -1
+        elif kind == "S":
+            seen[r] = True
+        elif kind == "L":
+            passes[r] = False
+    ids = ids.reshape(b, -1)
+    vis = np.zeros((b, (n + 31) // 32), np.uint32)
+    for i, j in zip(*np.nonzero(seen.reshape(b, -1) & (ids >= 0))):
+        vis[i, ids[i, j] >> 5] |= np.uint32(1) << np.uint32(ids[i, j] & 31)
+    return ids, vis, passes.reshape(b, -1)
+
+
+def _bit_set(vis, ids):
+    safe = np.maximum(ids, 0)
+    word = np.take_along_axis(vis, safe >> 5, axis=1)
+    return ((word >> (safe & 31).astype(np.uint32)) & 1) == 1
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("pattern", ["PMMMMP", "PMPMPM", "SLMSLM", "PPPPPP"])
+def test_filter_dist_gather_gated_tiles(pattern, int8):
+    """The gather kernel fetches only rows that can still pass (not
+    padding, not visited, passing the labels), with te=8 so that tiles of
+    nothing to fetch come first, last and alternate with live ones through
+    the double buffer. It equals its oracle on which lanes are finite, and
+    each finite lane is bitwise what a call that fetches every row gives.
+    ``fetched`` counts the rows that can pass."""
+    from repro.kernels.filter_dist import filter_dist_gather_pallas
+
+    rng = np.random.default_rng(len(pattern) + 7 * int8)
+    n, b, te, d = 75, 2, 8, 12
+    table, norms, scales, q = _gated_table(n, b, d, int8, rng)
+    ids, vis, passes = _gated_tiles(pattern, b, te, n, rng)
+    state = jnp.full((b, 2), 5, jnp.int32)
+    labels = np.zeros(ids.shape + (4,), np.int32)
+    labels[..., 1] = labels[..., 3] = 10                 # (0, 10, 0, 10)
+    labels[..., 0] = np.where(passes, 0, 6)              # l > a fails
+    ids, labels, vis = map(jnp.asarray, (ids, labels, vis))
+
+    def kernel(ids, vis):
+        safe = jnp.clip(ids, 0, n - 1)
+        return np.asarray(filter_dist_gather_pallas(
+            table, q, ids, labels, state, norms[safe],
+            jnp.take_along_axis(vis, safe >> 5, axis=1),
+            scales[safe] if int8 else jnp.ones(ids.shape, jnp.float32),
+            interpret=True, te=te))
+
+    want, fetched = ops.filter_dist_gather(
+        table, norms, q, ids, labels, state, vis, scales=scales,
+        use_ref=True)
+    want = np.asarray(want)
+    got = kernel(ids, vis)
+    every_row = kernel(ids, jnp.zeros_like(vis))
+    np.testing.assert_array_equal(
+        got, np.where(np.isfinite(want), every_row, np.inf))
+    np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
+    can_pass = ((np.asarray(ids) >= 0) & ~_bit_set(np.asarray(vis), ids)
+                & passes)
+    assert int(fetched) == int(can_pass.sum())
 
 
 def _packed_case(n, b, m, e, d, seed=0, rank_hi=12):
@@ -247,8 +335,8 @@ def test_filter_dist_gather_packed_matches_ref(n, b, m, e, d, rows):
     args = _packed_case(n, b, m, e, d)
     if rows:
         args = (table_rows(args[0]), label_rows(args[1])) + args[2:]
-    got = np.asarray(ops.filter_dist_gather_packed(*args, use_ref=False))
-    want = np.asarray(ops.filter_dist_gather_packed(*args, use_ref=True))
+    got = np.asarray(ops.filter_dist_gather_packed(*args, use_ref=False)[0])
+    want = np.asarray(ops.filter_dist_gather_packed(*args, use_ref=True)[0])
     fin = np.isfinite(want)
     np.testing.assert_array_equal(np.isfinite(got), fin)
     np.testing.assert_allclose(got[fin], want[fin], rtol=1e-4, atol=1e-4)
@@ -264,14 +352,92 @@ def test_filter_dist_gather_packed_matches_int32_kernel():
     table, plabels, norms, q, cur, cand, state, vis = _packed_case(
         n, b, m, e, d, seed=3)
     got = np.asarray(ops.filter_dist_gather_packed(
-        table, plabels, norms, q, cur, cand, state, vis))
+        table, plabels, norms, q, cur, cand, state, vis)[0])
     lab4 = jnp.asarray(unpack_labels(np.asarray(plabels)))
     lab_g = lab4[jnp.clip(cur, 0, n - 1)].reshape(b, m * e, 4)
     want = np.asarray(ops.filter_dist_gather(
-        table, norms, q, cand, lab_g, state, vis))
+        table, norms, q, cand, lab_g, state, vis)[0])
     fin = np.isfinite(want)
     np.testing.assert_array_equal(np.isfinite(got), fin)
     np.testing.assert_allclose(got[fin], want[fin], rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("pattern", ["DMMMMD", "DMDMDM", "MDMDMD", "PSMPSM",
+                                     "DDDDDD"])
+def test_filter_dist_gather_packed_gated_tiles(pattern, int8):
+    """The packed kernel with dead tiles (a ``-1`` expanded node, ``D``)
+    first, last, on either slot parity and everywhere, and tiles of only
+    padding (``P``) or only visited candidates (``S``), which fetch no row
+    and become dead too. It equals its oracle on which lanes are finite,
+    each finite lane is bitwise what a call that fetches every row gives,
+    and ``fetched`` counts the rows of live tiles that are neither padding
+    nor visited."""
+    from repro.search.device_graph import pack_labels
+
+    rng = np.random.default_rng(len(pattern) + 7 * int8)
+    n, b, m, e, d = 90, 3, 2, 16, 12
+    table, norms, scales, q = _gated_table(n, b, d, int8, rng)
+    ids, vis, _ = _gated_tiles(pattern, b, e, n, rng)
+    cur = rng.integers(0, n, size=(b * m,)).astype(np.int32)
+    cur[[kind == "D" for kind in pattern]] = -1
+    cur = cur.reshape(b, m)
+    lab4 = np.zeros((n, e, 4), np.int32)
+    lab4[..., 1] = lab4[..., 3] = 10
+    lab4[..., 0] = np.where(rng.random((n, e)) < 0.5, 0, 6)
+    plabels = jnp.asarray(pack_labels(lab4))
+    state = jnp.full((b, 2), 5, jnp.int32)
+    args = (table, plabels, norms, q)
+
+    def call(cur, vis, use_ref):
+        out, fetched = ops.filter_dist_gather_packed(
+            *args, jnp.asarray(cur), jnp.asarray(ids), state,
+            jnp.asarray(vis), scales=scales, use_ref=use_ref)
+        return np.asarray(out), int(fetched)
+
+    want, fetched = call(cur, vis, True)
+    got, fetched_kernel = call(cur, vis, False)
+    every_row, _ = call(np.maximum(cur, 0), np.zeros_like(vis), False)
+    assert np.all(np.isinf(want.reshape(b * m, e)[cur.reshape(-1) < 0]))
+    np.testing.assert_array_equal(
+        got, np.where(np.isfinite(want), every_row, np.inf))
+    np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
+    live = np.repeat(cur >= 0, e, axis=1)
+    assert fetched == fetched_kernel == int(
+        ((ids >= 0) & ~_bit_set(vis, ids) & live).sum())
+
+
+def test_gather_fetched_hand_counted():
+    """``fetched`` on a call small enough to count by hand: B = 2, M = 2,
+    E = 4; visited rows 3 and 9 of query 0 and row 5 of query 1."""
+    n, d = 16, 4
+    table = jnp.asarray(RNG.normal(size=(n, d)).astype(np.float32))
+    norms = jnp.sum(table * table, axis=1)
+    q = jnp.zeros((2, d), jnp.float32)
+    vis = np.zeros((2, 1), np.uint32)
+    vis[0, 0] = (1 << 3) | (1 << 9)
+    vis[1, 0] = 1 << 5
+    # per tile, the rows fetched: 4 | 1, 2 || 6 | none (a dead tile)
+    cand = jnp.asarray([[3, 4, -1, 9, 1, 2, 3, -1],
+                        [5, 5, 6, -1, 7, 8, 9, 10]], jnp.int32)
+    cur = jnp.asarray([[0, 1], [2, -1]], jnp.int32)
+    plabels = jnp.zeros((n, 4, 2), jnp.uint32)
+    state = jnp.zeros((2, 2), jnp.int32)
+    for use_ref in (True, False):
+        _, fetched = ops.filter_dist_gather_packed(
+            table, plabels, norms, q, cur, cand, state, jnp.asarray(vis),
+            use_ref=use_ref)
+        assert int(fetched) == 4
+    # the int32 kernel has no expanded nodes, and skips a candidate that
+    # fails its labels: query 1's 6 (l = 1 > a = 0). Fetched: 4, 1, 2 ||
+    # 7, 8, 9, 10
+    labels = np.zeros((2, 8, 4), np.int32)
+    labels[1, 2, 0] = 1
+    for use_ref in (True, False):
+        _, fetched = ops.filter_dist_gather(
+            table, norms, q, cand, jnp.asarray(labels), state,
+            jnp.asarray(vis), use_ref=use_ref)
+        assert int(fetched) == 7
 
 
 def test_packed_label_semantics_boundaries():
@@ -292,7 +458,7 @@ def test_packed_label_semantics_boundaries():
     vis = jnp.zeros((1, 1), jnp.uint32)
     for use_ref in (True, False):
         out = np.asarray(ops.filter_dist_gather_packed(
-            table, plabels, norms, q, cur, cand, state, vis, use_ref=use_ref))
+            table, plabels, norms, q, cur, cand, state, vis, use_ref=use_ref)[0])
         assert np.isfinite(out[0, 0]) and np.isfinite(out[0, 1])
         assert np.isinf(out[0, 2])
 

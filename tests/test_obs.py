@@ -228,7 +228,9 @@ def obs_setup(tiny_dataset):
 
 def _oracle_stats(dg, q, s_q, t_q, *, beam, max_iters):
     """Sequential per-query re-execution of the lockstep beam search,
-    counting with the documented semantics (expand=1)."""
+    counting with the documented semantics (expand=1); ``unvisited``
+    counts the real candidates not visited before their iteration, the
+    rows the packed gather fetches."""
     labels = dg.labels_i32()
     nbr = dg.nbr
     vecs = dg.vectors.astype(np.float64)
@@ -238,7 +240,8 @@ def _oracle_stats(dg, q, s_q, t_q, *, beam, max_iters):
     for b in range(B):
         a, c = int(states[b, 0]), int(states[b, 1])
         st = dict(iters=0, expanded=0, cand_total=0, cand_valid=0, kept=0,
-                  visited=0, beam_occupancy=0, hit_max_iters=False)
+                  visited=0, beam_occupancy=0, hit_max_iters=False,
+                  unvisited=0)
         if ep[b] < 0:
             out.append(st)
             continue
@@ -263,6 +266,7 @@ def _oracle_stats(dg, q, s_q, t_q, *, beam, max_iters):
                 if nb < 0:
                     continue
                 st["cand_total"] += 1
+                st["unvisited"] += nb not in visited
                 lo_x, hi_x, lo_y, hi_y = labels[cur, e]
                 if not (lo_x <= a <= hi_x and lo_y <= c <= hi_y):
                     continue
@@ -356,7 +360,7 @@ def test_no_entry_rows_contribute_exact_zeros(obs_setup):
 
 def test_stats_false_jaxpr_has_no_stats_outputs(obs_setup):
     """The guard for 'stats=False carries only the fixed-size totals':
-    ids, distances and the ``i32[4]`` loop totals, and nothing
+    ids, distances and the ``i32[5]`` loop totals, and nothing
     ``[B]``-per-query beyond them; stats=True appends exactly the
     per-query SearchStats leaves, each ``[B]``."""
     vecs, s, t, dg = obs_setup
@@ -579,23 +583,40 @@ def _graph_batch(vecs, s, t, B, seed):
     return q, s_q, t_q
 
 
-def _expected_totals(st, rows, width):
+def _expected_totals(st, rows, width, fetched):
     """``LOOP_TOTALS`` as sums of the per-query counters over ``rows``, a
     mask over the loop's B rows: its trips are the longest row's
-    iterations, and masked rows take row slots too."""
+    iterations, and masked rows take row slots too. ``fetched(slots)``
+    gives the rows fetched."""
     iters = np.asarray(st.iters)[rows]
     slots = int(iters.max(initial=0)) * len(rows)
     return [slots, int(iters.sum()), slots * width,
-            int(np.asarray(st.kept)[rows].sum())]
+            int(np.asarray(st.kept)[rows].sum()), fetched(slots)]
+
+
+def _fetched_within(totals, st, rows):
+    """A fused loop fetches at least the kept candidates' rows and at
+    most the real candidates' ones."""
+    kept = int(np.asarray(st.kept)[rows].sum())
+    return kept <= totals[4] <= int(np.asarray(st.cand_total)[rows].sum())
 
 
 @pytest.mark.parametrize("layout", ["packed", "int32", "unfused"])
 def test_loop_totals_equal_per_query_sums_graph(obs_setup, layout):
     """plan="graph": row slots = B × max iters, row iterations = Σ iters,
-    candidate slots = row slots × E and kept = Σ kept, with a cap small
+    candidate slots = row slots × E, kept = Σ kept and rows fetched = the
+    unvisited real candidates (packed), those passing the labels too
+    (int32, = Σ cand_valid) or every slot (unfused), with a cap small
     enough to cut some rows off."""
     vecs, s, t, dg = obs_setup
     q, s_q, t_q = _graph_batch(vecs, s, t, 6, seed=11)
+    E = dg.device().nbr.shape[1]
+    fetched = {
+        "packed": lambda slots: sum(o["unvisited"] for o in _oracle_stats(
+            dg, q, s_q, t_q, beam=8, max_iters=4)),
+        "int32": lambda slots: int(np.asarray(st.cand_valid).sum()),
+        "unfused": lambda slots: slots * E,
+    }[layout]
     states, ep = prepare_states(dg, s_q, t_q)
     dev = dg.device()
     fused = layout != "unfused"
@@ -607,7 +628,7 @@ def test_loop_totals_equal_per_query_sums_graph(obs_setup, layout):
     )
     assert np.asarray(st.hit_max_iters).any()
     assert np.asarray(totals).tolist() == _expected_totals(
-        st, np.ones(6, bool), dev.nbr.shape[1])
+        st, np.ones(6, bool), E, fetched)
     ids0, d0, totals0 = _batched_search_core(
         dev.table, dev.nbr, labels, jnp.asarray(q), jnp.asarray(states),
         jnp.asarray(ep), k=4, beam=8, max_iters=4, use_ref=True,
@@ -622,7 +643,9 @@ def test_loop_totals_equal_per_query_sums_planned(obs_setup, monkeypatch,
                                                    fused):
     """plan="auto": the graph loop's totals (row 0) are the sums over the
     rows planned GRAPH, the wide loop's (row 1) over those planned
-    GRAPH_WIDE, over all B row slots of each loop."""
+    GRAPH_WIDE, over all B row slots of each loop; the fused loops fetch
+    between the kept and the real candidates' rows, the unfused one
+    every slot's."""
     from repro.exec import executor
 
     vecs, s, t, dg = obs_setup
@@ -653,8 +676,12 @@ def test_loop_totals_equal_per_query_sums_planned(obs_setup, monkeypatch,
     for row, plan in enumerate((QueryPlan.GRAPH, QueryPlan.GRAPH_WIDE)):
         rows = pb.plans == int(plan)
         assert rows.any(), plan
-        assert totals[row].tolist() == _expected_totals(
-            st, rows, widths[row]), plan
+        want = _expected_totals(st, rows, widths[row], lambda sl: sl * E)
+        assert totals[row].tolist()[:4] == want[:4], plan
+        if fused:
+            assert _fetched_within(totals[row], st, rows), plan
+        else:
+            assert totals[row][4] == want[4], plan
 
 
 @pytest.fixture(scope="module")
@@ -704,6 +731,9 @@ def test_streaming_search_folds_loop_totals(stream_idx, plan):
         np.asarray(st.iters).sum())
     assert summed("repro_search_candidates_kept_total") == int(
         np.asarray(st.kept).sum())
+    fetched = summed("repro_search_rows_fetched_total")
+    assert np.asarray(st.kept).sum() <= fetched <= np.asarray(
+        st.cand_total).sum()
     slots = [value("repro_search_row_slots_total", p) for p in loops]
     assert min(slots) > 0
     assert max(slots) == B * int(np.asarray(st.iters).max())
@@ -716,7 +746,8 @@ def test_streaming_search_folds_loop_totals(stream_idx, plan):
                           planner_config=cfg, registry=reg2)
     np.testing.assert_array_equal(ids, ids2)
     for name in ("repro_search_iterations_total",
-                 "repro_search_row_slots_total"):
+                 "repro_search_row_slots_total",
+                 "repro_search_rows_fetched_total"):
         for p in loops:
             assert reg2.counter(name).value(plan=p) == value(name, p)
 

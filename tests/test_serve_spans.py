@@ -104,15 +104,21 @@ def test_loop_counter_readers(registry):
 
     row_active = harness.reader(HOME, "search.row_active_pct.closed")
     useful = harness.reader(HOME, "search.gather_useful_pct.closed")
+    fetched = harness.reader(HOME, "search.gather_fetched_pct.closed")
     run = made_up_run()
     assert row_active(run) is None and useful(run) is None
-    # graph loop: 10 trips, 25 row iterations, 300 kept, M*E = 16;
-    # wide loop: 4 trips, 12 row iterations, 100 kept, M*E = 64; B = 8
-    record_loop_totals(np.array([[80, 25, 80 * 16, 300],
-                                 [32, 12, 32 * 64, 100]]),
+    assert fetched(run) is None
+    # graph loop: 10 trips, 25 row iterations, 300 kept, 700 rows fetched,
+    # M*E = 16; wide loop: 4 trips, 12 row iterations, 100 kept, 500
+    # fetched, M*E = 64; B = 8
+    record_loop_totals(np.array([[80, 25, 80 * 16, 300, 700],
+                                 [32, 12, 32 * 64, 100, 500]]),
                        plans=("GRAPH", "GRAPH_WIDE"), registry=registry)
+    assert registry.counter("repro_search_rows_fetched_total").value(
+        plan="GRAPH") == 700
     assert row_active(run) == pytest.approx(100 * 37 / (80 + 32))
     assert useful(run) == pytest.approx(100 * 400 / (80 * 16 + 32 * 64))
+    assert fetched(run) == pytest.approx(100 * 1200 / (80 * 16 + 32 * 64))
 
 
 def test_queue_wait_reader():
