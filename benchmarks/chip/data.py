@@ -52,6 +52,19 @@ def predicate(relation: str):
 # --- vectors ----------------------------------------------------------------------
 
 
+def mixture_centres(clusters: int, dim: int, *, seed: int) -> np.ndarray:
+    """The centres of the data's mixture: the first draw of ``seed``."""
+    return np.random.default_rng(seed).normal(size=(clusters, dim))
+
+
+def from_mixture(centers: np.ndarray, count: int, spread: float,
+                 rng) -> np.ndarray:
+    """``count`` float32 vectors of the mixture: a centre each, plus noise."""
+    asg = rng.integers(0, centers.shape[0], size=count)
+    x = centers[asg] + spread * rng.normal(size=(count, centers.shape[1]))
+    return np.ascontiguousarray(x, dtype=np.float32)
+
+
 def make_vectors_and_queries(n: int, nq: int, dim: int, *, clusters: int,
                              spread: float, seed: int):
     """``n`` Gaussian-mixture data vectors (exactly those of
@@ -59,13 +72,9 @@ def make_vectors_and_queries(n: int, nq: int, dim: int, *, clusters: int,
     query vectors drawn from the same centres with a stream of their own."""
     rng = np.random.default_rng(seed)
     centers = rng.normal(size=(clusters, dim))
-    asg = rng.integers(0, clusters, size=n)
-    x = centers[asg] + spread * rng.normal(size=(n, dim))
-    qrng = np.random.default_rng([seed, 1])
-    qa = qrng.integers(0, clusters, size=nq)
-    q = centers[qa] + spread * qrng.normal(size=(nq, dim))
-    return (np.ascontiguousarray(x, dtype=np.float32),
-            np.ascontiguousarray(q, dtype=np.float32))
+    x = from_mixture(centers, n, spread, rng)
+    q = from_mixture(centers, nq, spread, np.random.default_rng([seed, 1]))
+    return x, q
 
 
 # --- intervals --------------------------------------------------------------------
@@ -98,7 +107,12 @@ def make_intervals(n: int, distribution: str, *, seed: int, T: float = T_DOMAIN)
     """``n`` closed intervals, as ``repro.data.synthetic.make_intervals``:
     endpoints rounded to float32 values, spans that rounding reversed
     clamped to zero length. Returns float64 ``(s, t)``."""
-    rng = np.random.default_rng(seed + 7919)
+    return intervals_from(np.random.default_rng(seed + 7919), n, distribution,
+                          T=T)
+
+
+def intervals_from(rng, n: int, distribution: str, *, T: float = T_DOMAIN):
+    """:func:`make_intervals` drawn from the generator ``rng``."""
     s, t = INTERVALS[distribution](rng, n, T)
     s, t = _clamp_ordered(np.asarray(s, np.float64), np.asarray(t, np.float64))
     s = s.astype(np.float32).astype(np.float64)

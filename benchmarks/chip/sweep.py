@@ -39,6 +39,9 @@ def main(argv=None) -> list:
     ap.add_argument("--seconds", type=float, default=10.0)
     args = ap.parse_args(argv)
     cell = harness.load_cell(args.workload)
+    if cell.writes is not None:
+        sys.exit(f"sweep: {args.workload} writes; the sweep drives queries "
+                 f"only")
     su = harness.set_up(cell, seed=args.seed, t_start=T_START)
     B = int(cell.config["batch"])
     order = np.random.default_rng([args.seed, 4]).permutation(len(su.pool))

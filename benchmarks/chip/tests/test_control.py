@@ -1,6 +1,7 @@
 """``correct`` comes out false for the control and for each fault a
 one-chip serving cell can have, with the harness's look for a chip
 skipped and the timed path broken underneath."""
+import json
 import time
 
 import numpy as np
@@ -10,7 +11,10 @@ from benchmarks.chip import control, harness
 from repro.serve.batching import StreamingServer
 from repro.stream.index import StreamingIndex
 
-CELLS = ["rag768.contain.mix.closed", "sift128.overlap.mix.open"]
+from conftest import ROOT
+
+CELLS = [w["name"] for w in json.loads(
+    (ROOT / "BENCHMARK.json").read_text())["workloads"]]
 
 
 def run(tree, cell):

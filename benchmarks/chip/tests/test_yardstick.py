@@ -101,7 +101,7 @@ def test_compare_reads_each_fault():
 
     def verdict(ids, d, missing=0):
         return reference.compare(ref, truth, idx, pool.q, pool.s_q, pool.t_q,
-                                 ids, d, missing=missing, n=n)
+                                 ids, d, missing=missing)
 
     v = verdict(truth.ids, d_exact)
     assert (v.missing, v.bad) == (0, 0) and v.dist_err < 1e-6
